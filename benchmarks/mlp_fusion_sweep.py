@@ -15,7 +15,7 @@ On this CPU container the Pallas rows run in interpret mode, so absolute
 times are not TPU times; the signals are (a) the aligned-vs-misaligned
 ratio within an impl (tile padding) and (b) fused-vs-unfused on equal
 shapes (one streamed x pass + no HBM round-trip for the gate/up
-activations).  A TPU host re-runs with REPRO_KERNEL_INTERPRET=0 for
+activations).  On a TPU the kernels compile and the same sweep gives
 deployment numbers.
 
 Emits harness CSV rows and, with --jsonl, records that `benchmarks.report`

@@ -185,7 +185,7 @@ def train_attention_section(rows):
            "kernel pair (forward + fused custom-VJP backward); on a CPU "
            "container it executes in interpret mode, so compare the "
            "misalign ratio within an impl, not absolute times across impls "
-           "(TPU hosts re-run with REPRO_KERNEL_INTERPRET=0).", ""]
+           "(on a TPU the kernels compile).", ""]
     out.append("| impl | shape | seq | head_dim | us/step | loss | "
                "misalign ratio |")
     out.append("|---|---|---|---|---|---|---|")
@@ -211,7 +211,7 @@ def mlp_fusion_section(rows):
            "recompute-based custom-VJP backward).  CPU container: Pallas "
            "rows run in interpret mode — compare the misalign ratio within "
            "an impl and fused-vs-unfused at equal shape, not absolute "
-           "times (TPU hosts re-run with REPRO_KERNEL_INTERPRET=0).", ""]
+           "times (on a TPU the kernels compile).", ""]
     out.append("| impl | d_ff | util | fwd us | grad us | fwd vs unfused | "
                "misalign ratio |")
     out.append("|---|---|---|---|---|---|---|")

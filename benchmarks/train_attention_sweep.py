@@ -13,8 +13,8 @@ AdamW) on a small LM — crossing:
 
 On this CPU container the flash rows run the kernels in Pallas interpret
 mode, so absolute times are not TPU times; the aligned-vs-unaligned *ratio*
-within an impl is the signal (padding + masked tail work), and on a TPU
-host (REPRO_KERNEL_INTERPRET=0) the same sweep yields deployment numbers.
+within an impl is the signal (padding + masked tail work), and on a TPU,
+where the kernels compile, the same sweep yields deployment numbers.
 
 Emits harness CSV rows and, with --jsonl, records that `benchmarks.report`
 renders into the training-attention section.

@@ -39,8 +39,8 @@ class ModelConfig:
     #             of kernels/flash_attention, used by the §Perf hillclimb)
     # "flash" = Pallas flash kernel with fused custom-VJP backward
     #           (kernels/flash_attention) — the differentiable TPU training
-    #           path; consults the autotuning cache (tuned=True) and honors
-    #           $REPRO_KERNEL_INTERPRET
+    #           path; consults the autotuning cache (tuned=True) and compiles
+    #           on a TPU backend, interprets elsewhere (kernels.backend)
     # "paged" = Pallas paged decode kernel over the serving slot pool
     attn_impl: str = "naive"
     attn_block_kv: int = 1024

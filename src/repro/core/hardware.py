@@ -46,9 +46,11 @@ class Hardware:
 
 
 # --- TPU v5e: the production target -------------------------------------------------
-# 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI (values from the task brief).
-# 2D torus: model-parallel collectives typically see ~2 usable links per direction;
-# we budget 3 links aggregate (conservative between 2 and 4).
+# Published peaks per chip (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s
+# bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s (200 GB/s) of
+# inter-chip interconnect over 4 links.  2D torus: model-parallel collectives
+# typically see ~2 usable links per direction; we budget 3 of the 4 links at
+# 50 GB/s each (conservative between 2 and 4).
 TPU_V5E = Hardware(
     name="tpu_v5e",
     peak_flops=197e12,
@@ -101,11 +103,33 @@ H100_SXM = Hardware(
 BY_NAME = {hw.name: hw for hw in (TPU_V5E, A100_40GB, V100_16GB, H100_SXM)}
 
 
+# Chips this program runs on, keyed by `jax.Device.device_kind`.
+BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
 def get_hardware(name: str = "tpu_v5e") -> Hardware:
     try:
         return BY_NAME[name]
     except KeyError as e:
         raise ValueError(f"unknown hardware {name!r}; have {sorted(BY_NAME)}") from e
+
+
+def running_hardware() -> Hardware:
+    """The chip JAX runs on.  On a TPU its `device_kind` must be in
+    `BY_DEVICE_KIND`: an unknown chip is an error, not a default.  On any
+    other backend nothing is measured, so the analytic target (TPU v5e)
+    stands in."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    try:
+        return BY_DEVICE_KIND[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware entry for device kind {dev.device_kind!r}; "
+            f"have {sorted(BY_DEVICE_KIND)}") from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -50,16 +50,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
         k = k_ref[0].astype(jnp.float32)            # (bkv, d)
         v = v_ref[0].astype(jnp.float32)            # (bkv, d)
         do = do_ref[0].astype(jnp.float32)          # (bq, d)
-        lse = lse_ref[0]                            # (bq,)
-        di = di_ref[0]                              # (bq,)
+        lse = lse_ref[0]                            # (bq, 1)
+        di = di_ref[0]                              # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = mask_block(s, qi, ki, block_q=block_q, block_kv=block_kv,
                        causal=causal, kv_len=kv_len)
-        p = jnp.exp(s - lse[:, None])               # (bq, bkv)
+        p = jnp.exp(s - lse)                        # (bq, bkv)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - di[:, None]) * scale
+        ds = p * (dp - di) * scale
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -87,18 +87,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         k = k_ref[0].astype(jnp.float32)            # (bkv, d)
         v = v_ref[0].astype(jnp.float32)            # (bkv, d)
         do = do_ref[0].astype(jnp.float32)          # (bq, d)
-        lse = lse_ref[0]                            # (bq,)
-        di = di_ref[0]                              # (bq,)
+        lse = lse_ref[0]                            # (bq, 1)
+        di = di_ref[0]                              # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = mask_block(s, qi, ki, block_q=block_q, block_kv=block_kv,
                        causal=causal, kv_len=kv_len)
-        p = jnp.exp(s - lse[:, None])               # (bq, bkv)
+        p = jnp.exp(s - lse)                        # (bq, bkv)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - di[:, None]) * scale
+        ds = p * (dp - di) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -120,7 +120,8 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, *, causal: bool = True,
     """Fused backward for `flash_attention_pallas`.
 
     q, do: (bh, sq, d); k, v: (bkv_h, skv, d); o: (bh, sq, d);
-    lse: (bh, sq) f32 from the forward's return_residuals=True.
+    lse: (bh, sq, 1) f32 from the forward's return_residuals=True (the
+    unit lane dim keeps the (block_q, 1) row blocks legal on the TPU).
     Requires sq % block_q == 0 and skv % block_kv == 0 (ops.py pads).
 
     Returns (dq, dk_heads, dv_heads) with dk/dv at query-head resolution
@@ -135,14 +136,15 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, *, causal: bool = True,
         kv_len = None
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     # di = rowsum(do * o): the softmax-jacobian diagonal term, cheap in XLA
-    di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                 keepdims=True)
 
     from jax.experimental.pallas import tpu as pltpu
     q_steps, kv_steps = sq // block_q, skv // block_kv
 
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kvspec = pl.BlockSpec((1, block_kv, d), lambda b, i, j, g=g: (b // g, j, 0))
-    rowspec = pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))
+    rowspec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, kv_steps=kv_steps, block_q=block_q,
                           block_kv=block_kv, causal=causal, scale=scale,
@@ -158,7 +160,7 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, *, causal: bool = True,
     # dkv grid transposes the block walk: kv outer, q inner
     qspec_t = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
     kvspec_t = pl.BlockSpec((1, block_kv, d), lambda b, j, i, g=g: (b // g, j, 0))
-    rowspec_t = pl.BlockSpec((1, block_q), lambda b, j, i: (b, i))
+    rowspec_t = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
     dkvspec = pl.BlockSpec((1, block_kv, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, q_steps=q_steps, block_q=block_q,

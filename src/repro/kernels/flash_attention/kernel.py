@@ -84,12 +84,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, kv_steps: int,
                                 preferred_element_type=jnp.float32) * scale
         s = mask_block(s, qi, ki, block_q=block_q, block_kv=block_kv,
                        causal=causal, kv_len=kv_len)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                        # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -102,7 +102,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, kv_steps: int,
     def _done():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output
-        o_ref[0, ...] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, ...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         if emit_lse:
             # lse = m + log(l) is the softmax log-normalizer the backward
             # recomputes p against (p = exp(s - lse)).  Fully-masked rows get
@@ -123,7 +123,9 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Requires sq % block_q == 0 and skv % block_kv == 0 (ops.py pads).
     kv_len masks key columns >= kv_len (the zero-padded tail) with NEG_INF.
     return_residuals=True additionally returns the per-row logsumexp
-    (bh, sq) f32 — the saved residual for the Pallas backward pass.
+    (bh, sq, 1) f32 — the saved residual for the Pallas backward pass.  The
+    trailing unit dim keeps its (block_q, 1) block legal on the TPU, whose
+    compiler refuses a (1, block_q) block over a 2-D (bh, sq) array.
     """
     bh, sq, d = q.shape
     bkv, skv, dk = k.shape
@@ -139,8 +141,9 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out_shape = [jax.ShapeDtypeStruct((bh, sq, d), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))]
     if return_residuals:
-        out_shape.append(jax.ShapeDtypeStruct((bh, sq), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, block_q, 1),
+                                      lambda b, i, j: (b, i, 0)))
     res = pl.pallas_call(
         functools.partial(_flash_kernel, kv_steps=kv_steps, block_q=block_q,
                           block_kv=block_kv, causal=causal, scale=scale,
@@ -154,8 +157,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=out_specs if return_residuals else out_specs[0],
         out_shape=out_shape if return_residuals else out_shape[0],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,

@@ -20,7 +20,6 @@ exact problem — and separately for the backward blocks (op
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -32,6 +31,7 @@ from ...core.quantization import round_up
 from ...quant import dequantize_kv
 from ...tuning.cache import lookup as _tuning_lookup
 from ...tuning.cache import mixed_dtype
+from ..backend import interpret_mode
 from .backward import flash_attention_bwd_pallas
 from .kernel import flash_attention_pallas
 from .paged import paged_decode_blocktable_pallas, paged_decode_pallas
@@ -102,7 +102,7 @@ def _flash_core_bwd(cfg: _FlashConfig, residuals, g):
     # forward's masked-row guard), so they contribute exactly zero gradient
     dq, dk_h, dv_h = flash_attention_bwd_pallas(
         _pad_seq(q, sq_p), _pad_seq(k, skv_p), _pad_seq(v, skv_p),
-        _pad_seq(out, sq_p), _pad_seq(lse[..., None], sq_p)[..., 0],
+        _pad_seq(out, sq_p), _pad_seq(lse, sq_p),
         _pad_seq(g, sq_p), causal=cfg.causal, block_q=bq, block_kv=bkv,
         kv_len=skv, interpret=cfg.interpret)
     bh = q.shape[0]
@@ -136,7 +136,8 @@ def _flash_jit(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128, bwd_block_q: int = 128,
-                    bwd_block_kv: int = 128, interpret: bool = True,
+                    bwd_block_kv: int = 128,
+                    interpret: Optional[bool] = None,
                     use_pallas: bool = True, tuned: bool = False,
                     hw_name: Optional[str] = None):
     """q: (b, sq, a, d); k, v: (b, skv, kv_heads, d).  Returns (b, sq, a, d).
@@ -179,21 +180,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
             tuned_hit=tuned_hit)
     return _flash_jit(q, k, v, causal=causal, block_q=block_q,
                       block_kv=block_kv, bwd_block_q=bwd_block_q,
-                      bwd_block_kv=bwd_block_kv, interpret=interpret,
+                      bwd_block_kv=bwd_block_kv,
+                      interpret=interpret_mode(interpret),
                       use_pallas=use_pallas)
 
 
 # --- paged decode (serving engine) ---------------------------------------------------
-
-# In-model kernel dispatch (models.attention attn_impl="paged") has no
-# per-call interpret kwarg to thread, so it follows this env toggle: the
-# default True matches the CPU container; a TPU deployment exports
-# REPRO_KERNEL_INTERPRET=0 to run the compiled kernel.
-ENV_INTERPRET = "REPRO_KERNEL_INTERPRET"
-
-
-def default_interpret() -> bool:
-    return os.environ.get(ENV_INTERPRET, "1") != "0"
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret",
                                              "use_pallas"))
@@ -230,7 +222,7 @@ def _paged_jit(q, k_pool, v_pool, slot_idx, lengths, k_scale, v_scale, *,
 
 def paged_decode(q, k_pool, v_pool, slot_idx, lengths, *,
                  k_scale=None, v_scale=None,
-                 block_kv: int = 128, interpret: bool = True,
+                 block_kv: int = 128, interpret: Optional[bool] = None,
                  use_pallas: bool = True, tuned: bool = False,
                  hw_name: Optional[str] = None):
     """Slot-gathering decode attention over a fixed KV pool.
@@ -267,7 +259,7 @@ def paged_decode(q, k_pool, v_pool, slot_idx, lengths, *,
             blocks={"block_kv": block_kv} if use_pallas else None,
             tuned_hit=tuned_hit)
     return _paged_jit(q, k_pool, v_pool, slot_idx, lengths, k_scale, v_scale,
-                      block_kv=block_kv, interpret=interpret,
+                      block_kv=block_kv, interpret=interpret_mode(interpret),
                       use_pallas=use_pallas)
 
 
@@ -299,7 +291,8 @@ def _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths, k_scale,
 def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
                             k_scale=None, v_scale=None,
                             block_kv: Optional[int] = None,
-                            interpret: bool = True, use_pallas: bool = True,
+                            interpret: Optional[bool] = None,
+                            use_pallas: bool = True,
                             tuned: bool = False,
                             hw_name: Optional[str] = None):
     """Block-table decode attention over a physical KV block pool.
@@ -342,4 +335,5 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
             tuned_hit=tuned_hit)
     return _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths,
                          k_scale, v_scale, block_kv=block_kv or block_size,
-                         interpret=interpret, use_pallas=use_pallas)
+                         interpret=interpret_mode(interpret),
+                         use_pallas=use_pallas)

@@ -7,9 +7,13 @@ K/V BlockSpec index maps *gather by slot index*: row b's kv blocks come from
 pool slot `slot_idx[b]` — the Pallas analogue of vLLM's paged attention at
 page size = one whole slot.
 
-Grid (b, kv_heads, kv_steps), kv innermost; VMEM scratch carries the online
-softmax state (m, l, acc) across kv steps (TPU grids are sequential per
-core).  Per-slot lengths do double duty:
+Grid (b, kv_steps), kv innermost; each kv tile is (block_kv, nkv, d) — all
+kv heads of block_kv positions, so the block's two minor dims are the pool's
+own (nkv, d), the layout the TPU compiler accepts for a (…, nkv, d) pool (a
+per-head (1, d) slice of those dims is refused).  The kernel walks the heads
+of a tile in a static loop; VMEM scratch carries the online softmax state
+(m, l, acc) per head across kv steps (TPU grids are sequential per core).
+Per-slot lengths do double duty:
   * kv blocks entirely past `lengths[b]` are skipped via pl.when — a dead
     slot (length 0) costs zero FLOPs and writes zeros;
   * the tail block is masked elementwise so slot-pool positions past the
@@ -45,7 +49,8 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  kv_steps: int, block_kv: int, scale: float):
+                  num_kv_heads: int, kv_steps: int, block_kv: int,
+                  scale: float):
     # rest is (o, m, l, acc) for the plain variant, or
     # (ks, vs, o, m, l, acc) when the pool is int8-quantized KV: the scale
     # tiles ride as extra inputs and the dequant happens per kv tile, so the
@@ -55,7 +60,7 @@ def _paged_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     else:
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
-    b_i, ki = pl.program_id(0), pl.program_id(2)
+    b_i, ki = pl.program_id(0), pl.program_id(1)
     length = len_ref[b_i]
 
     @pl.when(ki == 0)
@@ -67,31 +72,44 @@ def _paged_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     # skip blocks wholly past the live prefix (dead slot: skips everything)
     @pl.when(ki * block_kv < length)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)          # (g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (bkv, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)    # (bkv, d)
-        if ks_ref is not None:
-            k = k * ks_ref[0, :, 0][:, None]         # per-(token, head) scale
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
         kv_pos = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_kv), 1)
-        s = jnp.where(kv_pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        # one kv tile carries every kv head (the (nkv, d) minor dims are the
+        # pool's own, which keeps the block legal on the TPU); each head's
+        # (bkv, d) slab is a strided read of that tile
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)          # (g, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32)    # (bkv, d)
+            v = v_ref[0, :, h, :].astype(jnp.float32)    # (bkv, d)
+            if ks_ref is not None:                       # per-(token, head)
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(kv_pos < length, s, NEG_INF)
+            m_prev = m_ref[h]                            # (g, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(ki == kv_steps - 1)
     def _done():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)  # dead slot -> zero output
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _scratch(nkv: int, g: int, d: int):
+    """Online-softmax state (m, l, acc) for every kv head of one row."""
+    from jax.experimental.pallas import tpu as pltpu
+    return [pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, d), jnp.float32)]
 
 
 def paged_decode_pallas(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -120,35 +138,27 @@ def paged_decode_pallas(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     kv_steps = s_max // block_kv
     qh = q.reshape(b, nkv, g, d)
     from jax.experimental.pallas import tpu as pltpu
-    kv_spec = pl.BlockSpec((1, block_kv, 1, d),
-                           lambda bi, h, j, slot, lens: (slot[bi], j, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d),
-                     lambda bi, h, j, slot, lens: (bi, h, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+    kv_spec = pl.BlockSpec((1, block_kv, nkv, d),
+                           lambda bi, j, slot, lens: (slot[bi], j, 0, 0))
+    row_spec = pl.BlockSpec((1, nkv, g, d),
+                            lambda bi, j, slot, lens: (bi, 0, 0, 0))
+    in_specs = [row_spec, kv_spec, kv_spec]
     operands = [qh, k_pool, v_pool]
     if k_scale is not None:
         assert k_scale.shape == (slots, s_max, nkv), k_scale.shape
-        sc_spec = pl.BlockSpec((1, block_kv, 1),
-                               lambda bi, h, j, slot, lens: (slot[bi], j, h))
+        sc_spec = pl.BlockSpec((1, block_kv, nkv),
+                               lambda bi, j, slot, lens: (slot[bi], j, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nkv, kv_steps),
+        grid=(b, kv_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, h, j, slot, lens: (bi, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        out_specs=row_spec,
+        scratch_shapes=_scratch(nkv, g, d),
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, kv_steps=kv_steps,
+        functools.partial(_paged_kernel, num_kv_heads=nkv, kv_steps=kv_steps,
                           block_kv=block_kv, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
@@ -176,7 +186,7 @@ def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
     dequantized in VMEM after the gather-by-table DMA.
 
     block_kv (default block_size) must divide block_size; the grid runs
-    max_blocks * block_size/block_kv kv steps per (row, head) and skips
+    max_blocks * block_size/block_kv kv steps per row and skips
     steps wholly past `lengths[b]`, so table entries beyond a row's live
     blocks are never read (callers pad with any valid block id).
     Returns (b, a, d); rows with length 0 return zeros.
@@ -200,43 +210,35 @@ def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
         # scalar-prefetched table is indexed *inside the index map*, so the
         # DMA for row bi streams straight from the right physical block
         return pl.BlockSpec(
-            (1, block_kv, 1, d),
-            lambda bi, h, j, table, lens: (table[bi, j // steps_per_block],
-                                           j % steps_per_block, h, 0))
+            (1, block_kv, nkv, d),
+            lambda bi, j, table, lens: (table[bi, j // steps_per_block],
+                                        j % steps_per_block, 0, 0))
 
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d),
-                     lambda bi, h, j, table, lens: (bi, h, 0, 0)),
-        kv_spec(),
-        kv_spec(),
-    ]
+    row_spec = pl.BlockSpec((1, nkv, g, d),
+                            lambda bi, j, table, lens: (bi, 0, 0, 0))
+    in_specs = [row_spec, kv_spec(), kv_spec()]
     operands = [qh, k_blocks, v_blocks]
     if k_scale is not None:
         assert k_scale.shape == (nb, block_size, nkv), k_scale.shape
         def sc_spec():
             return pl.BlockSpec(
-                (1, block_kv, 1),
-                lambda bi, h, j, table, lens: (table[bi, j // steps_per_block],
-                                               j % steps_per_block, h))
+                (1, block_kv, nkv),
+                lambda bi, j, table, lens: (table[bi, j // steps_per_block],
+                                            j % steps_per_block, 0))
         in_specs += [sc_spec(), sc_spec()]
         operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nkv, kv_steps),
+        grid=(b, kv_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, h, j, table, lens: (bi, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        out_specs=row_spec,
+        scratch_shapes=_scratch(nkv, g, d),
     )
     # the kernel body is the slot variant's: it reasons purely in logical kv
     # positions (ki * block_kv + offset vs lengths[b]); only the index maps
     # above know the physical indirection
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, kv_steps=kv_steps,
+        functools.partial(_paged_kernel, num_kv_heads=nkv, kv_steps=kv_steps,
                           block_kv=block_kv, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),

@@ -27,6 +27,7 @@ from ... import obs
 from ...core.hardware import get_hardware
 from ...core.quantization import round_up
 from ...tuning.cache import lookup as _tuning_lookup
+from ..backend import interpret_mode
 from .backward import fused_mlp_bwd_pallas
 from .kernel import fused_mlp_pallas
 from .ref import fused_mlp_hidden_ref, is_gated
@@ -142,7 +143,8 @@ def _fused_jit(x, w_gate, w_up, *, mlp_type: str, block_m: int, block_f: int,
 def fused_mlp_hidden(x, w_gate, w_up, *, mlp_type: str = "swiglu",
                      block_m: int = 128, block_f: int = 128,
                      block_k: int = 128, bwd_block_m: int = 128,
-                     bwd_block_f: int = 128, interpret: bool = True,
+                     bwd_block_f: int = 128,
+                     interpret: Optional[bool] = None,
                      use_pallas: bool = True, tuned: bool = False,
                      hw_name: Optional[str] = None):
     """hidden = act-combine(x @ w_gate, x @ w_up).  x: (..., h) -> (..., f).
@@ -185,5 +187,6 @@ def fused_mlp_hidden(x, w_gate, w_up, *, mlp_type: str = "swiglu",
     out = _fused_jit(x.reshape(m, h), w_gate, w_up, mlp_type=mlp_type,
                      block_m=block_m, block_f=block_f, block_k=block_k,
                      bwd_block_m=bwd_block_m, bwd_block_f=bwd_block_f,
-                     interpret=interpret, use_pallas=use_pallas)
+                     interpret=interpret_mode(interpret),
+                     use_pallas=use_pallas)
     return out.reshape(*lead, f)

@@ -21,6 +21,7 @@ from ... import obs
 from ...core.hardware import get_hardware
 from ...core.quantization import round_up, tile_utilization
 from ...tuning.cache import lookup as _tuning_lookup
+from ..backend import interpret_mode
 from .kernel import matmul_pallas
 from .ref import matmul_ref
 
@@ -50,14 +51,14 @@ def _matmul_jit(a: jax.Array, b: jax.Array, *,
 
 def matmul(a: jax.Array, b: jax.Array, *,
            block_m: int = 128, block_n: int = 128, block_k: int = 128,
-           interpret: bool = True, use_pallas: bool = True,
+           interpret: Optional[bool] = None, use_pallas: bool = True,
            tuned: bool = False, hw_name: Optional[str] = None) -> jax.Array:
     """C = A @ B.  A: (..., k) — leading dims are flattened into one m axis
     and restored on the output, so a (b, s, h) activation keys the tuning
     cache as (b*s, h, n), the exact shape `autotune_matmul` writes (a
     >2-D A used to miss the cache silently).  use_pallas=False falls back
-    to the jnp oracle (the CPU-container default for model code; kernels
-    are TPU-targeted and validated in interpret mode).
+    to the jnp oracle.  interpret=None compiles the kernel on a TPU and
+    interprets it on any other backend (`kernels.backend.interpret_mode`).
 
     tuned=True overrides block_* with the autotuning cache's measured-best
     config for this (m, k, n, dtype, hw) when one exists (cache misses keep
@@ -85,7 +86,7 @@ def matmul(a: jax.Array, b: jax.Array, *,
                     "block_k": block_k} if use_pallas else None,
             tuned_hit=tuned_hit)
     out = _matmul_jit(a, b, block_m=block_m, block_n=block_n,
-                      block_k=block_k, interpret=interpret,
+                      block_k=block_k, interpret=interpret_mode(interpret),
                       use_pallas=use_pallas)
     return out if len(lead) == 1 else out.reshape(*lead, b.shape[-1])
 

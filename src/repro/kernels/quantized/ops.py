@@ -30,6 +30,7 @@ from ...core.quantization import round_up
 from ...quant import QuantizedTensor, fp8_round_trip, quantize_int8, quantize_weight
 from ...tuning.cache import lookup as _tuning_lookup
 from ...tuning.cache import mixed_dtype
+from ..backend import interpret_mode
 from ..fused_mlp.ref import is_gated
 from ..matmul.kernel import matmul_pallas
 from ..matmul.ops import _pad2
@@ -76,7 +77,7 @@ def _int8_matmul_jit(a, b_q, b_scale, *, block_m: int, block_n: int,
 
 def int8_matmul(a: jax.Array, w, *,
                 block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                interpret: bool = True, use_pallas: bool = True,
+                interpret: Optional[bool] = None, use_pallas: bool = True,
                 tuned: bool = False, hw_name: Optional[str] = None,
                 out_dtype=None) -> jax.Array:
     """C = dequant(quant(A) @ quant(W)).  A: (..., k) float; W: (k, n) float
@@ -114,7 +115,7 @@ def int8_matmul(a: jax.Array, w, *,
                     "block_k": block_k} if use_pallas else None,
             tuned_hit=tuned_hit)
     out = _int8_matmul_jit(a, b_q, b_scale, block_m=block_m, block_n=block_n,
-                           block_k=block_k, interpret=interpret,
+                           block_k=block_k, interpret=interpret_mode(interpret),
                            use_pallas=use_pallas, out_dtype=out_dtype)
     return out if len(lead) == 1 else out.reshape(*lead, b_q.shape[-1])
 
@@ -139,7 +140,7 @@ def _fp8_matmul_jit(a, b, *, block_m: int, block_n: int, block_k: int,
 def fp8_matmul(a: jax.Array, b: jax.Array, *,
                fp8_dtype: str = "float8_e4m3fn",
                block_m: int = 128, block_n: int = 128, block_k: int = 128,
-               interpret: bool = True, use_pallas: bool = True,
+               interpret: Optional[bool] = None, use_pallas: bool = True,
                tuned: bool = False, hw_name: Optional[str] = None) -> jax.Array:
     """Emulated-fp8 GEMM: round A and B through fp8 storage (e4m3 or e5m2),
     contract on the bf16-MXU-path kernel.  Cache op "fp8_matmul", mixed
@@ -168,7 +169,7 @@ def fp8_matmul(a: jax.Array, b: jax.Array, *,
                     "block_k": block_k} if use_pallas else None,
             tuned_hit=tuned_hit)
     out = _fp8_matmul_jit(a, b, block_m=block_m, block_n=block_n,
-                          block_k=block_k, interpret=interpret,
+                          block_k=block_k, interpret=interpret_mode(interpret),
                           use_pallas=use_pallas, fp8_dtype=fp8_dtype)
     return out if len(lead) == 1 else out.reshape(*lead, b.shape[-1])
 
@@ -203,7 +204,8 @@ def _int8_fused_mlp_jit(x, wg_q, wg_scale, wu_q, wu_scale, *, mlp_type: str,
 def int8_fused_mlp_hidden(x: jax.Array, w_gate, w_up, *,
                           mlp_type: str = "swiglu",
                           block_m: int = 128, block_f: int = 128,
-                          block_k: int = 128, interpret: bool = True,
+                          block_k: int = 128,
+                          interpret: Optional[bool] = None,
                           use_pallas: bool = True, tuned: bool = False,
                           hw_name: Optional[str] = None,
                           out_dtype=None) -> jax.Array:
@@ -246,6 +248,6 @@ def int8_fused_mlp_hidden(x: jax.Array, w_gate, w_up, *,
     out = _int8_fused_mlp_jit(x, wg_q, wg_scale, wu_q, wu_scale,
                               mlp_type=mlp_type, block_m=block_m,
                               block_f=block_f, block_k=block_k,
-                              interpret=interpret, use_pallas=use_pallas,
-                              out_dtype=out_dtype)
+                              interpret=interpret_mode(interpret),
+                              use_pallas=use_pallas, out_dtype=out_dtype)
     return out if len(lead) == 1 else out.reshape(*lead, wu_q.shape[-1])

@@ -6,16 +6,15 @@ sets XLA_FLAGS for 512 host devices *before* any jax initialization.
 """
 from __future__ import annotations
 
-import jax
-
 from ..configs.base import MeshConfig
+from ..parallel.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def production_mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -24,4 +23,4 @@ def production_mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU-device-count tests (requires >= data*model devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

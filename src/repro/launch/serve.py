@@ -27,6 +27,7 @@ from ..configs.registry import get_config, get_smoke_config
 from ..data.pipeline import synthetic_tokens
 from ..models import init_lm
 from ..serving.serve_step import make_decode_step, make_prefill_step
+from .compile_cache import enable_compile_cache
 
 
 def run_static(cfg, params, args) -> None:
@@ -211,6 +212,7 @@ def main(argv=None):
                     help="record every XLA compile, arm after calibration, "
                          "and FAIL on any steady-state recompile")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_lm(jax.random.PRNGKey(args.seed), cfg)

@@ -31,6 +31,7 @@ from ..models import init_lm
 from ..optim.adamw import init_opt
 from ..parallel import sharding as sh
 from ..train.train_step import make_train_step, num_microbatches
+from .compile_cache import enable_compile_cache
 
 
 def build(args):
@@ -46,6 +47,28 @@ def build(args):
                      remat=args.remat, checkpoint_every=args.checkpoint_every,
                      checkpoint_dir=args.checkpoint_dir, seed=args.seed)
     return cfg, mesh_cfg, shape, tc
+
+
+def shard_state(params, opt, cfg, mesh):
+    """Place params and AdamW moments by the FSDP/TP rules of
+    `parallel.sharding` (the moments follow their params' specs)."""
+    params = jax.device_put(
+        params, sh.to_shardings(sh.param_specs(params, cfg, mesh), mesh))
+    opt = type(opt)(
+        jax.device_put(opt.step),
+        jax.device_put(opt.m, sh.to_shardings(sh.param_specs(opt.m, cfg, mesh),
+                                              mesh)),
+        jax.device_put(opt.v, sh.to_shardings(sh.param_specs(opt.v, cfg, mesh),
+                                              mesh)))
+    return params, opt
+
+
+def jit_train_step(cfg, tc, *, n_micro: int = 1, mesh=None):
+    """The jitted train step, params and optimizer state donated; on a
+    mesh each microbatch is pinned to the data-parallel batch specs."""
+    bspec = sh.batch_specs(cfg, mesh) if mesh is not None else None
+    step_fn = make_train_step(cfg, tc, n_micro=n_micro, batch_spec=bspec)
+    return jax.jit(step_fn, donate_argnums=(0, 1))
 
 
 def main(argv=None):
@@ -68,12 +91,15 @@ def main(argv=None):
                          "(repro.models.linear); fused = Pallas fused "
                          "SwiGLU/MLP kernel + tuned matmuls")
     ap.add_argument("--microbatch", type=int, default=0, help="per-device rows; 0=no accumulation")
-    ap.add_argument("--checkpoint-every", type=int, default=50)
+    ap.add_argument("--checkpoint-every", type=int, default=50,
+                    help="steps between checkpoints; 0 = never checkpoint "
+                         "(not even at the end)")
     ap.add_argument("--checkpoint-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg, mesh_cfg, shape, tc = build(args)
 
@@ -103,26 +129,17 @@ def main(argv=None):
     params = init_lm(key, cfg)
     opt = init_opt(params, tc)
     start_step = 0
-    ck = Checkpointer(tc.checkpoint_dir, keep=3)
+    ck = (Checkpointer(tc.checkpoint_dir, keep=3)
+          if tc.checkpoint_every or args.resume else None)
     if args.resume and ck.latest_step() is not None:
         params_np, opt_np, start_step = ck.restore(params, opt)
         params = jax.tree.map(jnp.asarray, params_np)
         opt = jax.tree.map(jnp.asarray, opt_np)
         print(f"resumed from step {start_step}")
 
-    bspec = None
     if use_mesh:
-        pspecs = sh.param_specs(params, cfg, mesh)
-        params = jax.device_put(params, sh.to_shardings(pspecs, mesh))
-        ospecs_m = sh.param_specs(opt.m, cfg, mesh)
-        ospecs_v = sh.param_specs(opt.v, cfg, mesh)
-        opt = type(opt)(jax.device_put(opt.step),
-                        jax.device_put(opt.m, sh.to_shardings(ospecs_m, mesh)),
-                        jax.device_put(opt.v, sh.to_shardings(ospecs_v, mesh)))
-        bspec = sh.batch_specs(cfg, mesh)
-
-    step_fn = make_train_step(cfg, tc, n_micro=n_micro, batch_spec=bspec)
-    step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
+        params, opt = shard_state(params, opt, cfg, mesh)
+    step_fn = jit_train_step(cfg, tc, n_micro=n_micro, mesh=mesh)
 
     ctx = mesh if use_mesh else _null()
     t0 = time.time()
@@ -149,8 +166,9 @@ def main(argv=None):
                       f"tok/s {tokens_done/max(dt,1e-6):,.0f}", flush=True)
             if tc.checkpoint_every and step and step % tc.checkpoint_every == 0:
                 ck.save(step, params, opt, meta={"arch": cfg.name}, blocking=False)
-    ck.save(tc.total_steps, params, opt, meta={"arch": cfg.name})
-    ck.wait()
+    if tc.checkpoint_every:
+        ck.save(tc.total_steps, params, opt, meta={"arch": cfg.name})
+        ck.wait()
     print("done")
 
 

@@ -155,13 +155,12 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
             new_cache = {"k": kc, "v": vc}
         lengths = (ci + 1).astype(jnp.int32)
         if cfg.attn_impl == "paged":
-            from ..kernels.flash_attention.ops import (default_interpret,
-                                                       paged_decode_blocktable)
+            from ..kernels.flash_attention.ops import paged_decode_blocktable
             out = paged_decode_blocktable(
                 q[:, 0], kc if quant else kc.astype(q.dtype),
                 vc if quant else vc.astype(q.dtype),
                 block_tables, lengths, k_scale=k_scale, v_scale=v_scale,
-                tuned=True, interpret=default_interpret())[:, None]
+                tuned=True)[:, None]
         else:
             from ..kernels.flash_attention.ref import gather_block_kv
             kg = gather_block_kv(kc, block_tables)
@@ -221,23 +220,20 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
     if cfg.attn_impl == "paged" and is_decode:
         # Pallas paged decode over the slot pool (identity slot map here;
         # the kernel's gather-by-slot path is exercised by the engine tests)
-        from ..kernels.flash_attention.ops import (default_interpret,
-                                                   paged_decode)
+        from ..kernels.flash_attention.ops import paged_decode
         lengths = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
         out = paged_decode(q[:, 0], k if quant else k.astype(q.dtype),
                            v if quant else v.astype(q.dtype),
                            jnp.arange(b, dtype=jnp.int32), lengths,
-                           k_scale=k_scale, v_scale=v_scale, tuned=True,
-                           interpret=default_interpret())[:, None]
+                           k_scale=k_scale, v_scale=v_scale,
+                           tuned=True)[:, None]
     elif cfg.attn_impl == "flash" and not is_decode and cache is None:
         # Pallas flash kernel with its custom-VJP fused backward: the
         # training/prefill fast path.  Cache-backed prefill (dynamic kv_len)
         # and decode stay on the jnp paths below; MLA never routes here.
-        from ..kernels.flash_attention.ops import (default_interpret,
-                                                   flash_attention)
+        from ..kernels.flash_attention.ops import flash_attention
         out = flash_attention(q, k.astype(q.dtype), v.astype(q.dtype),
-                              causal=causal and kv_input is None,
-                              tuned=True, interpret=default_interpret())
+                              causal=causal and kv_input is None, tuned=True)
     elif cfg.attn_impl == "blocked" and not is_decode:
         from .blocked_attention import blocked_sdpa
         out = blocked_sdpa(q, k.astype(q.dtype), v.astype(q.dtype),

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.flash_attention.ops import default_interpret
 from ..kernels.fused_mlp.ops import fused_mlp_hidden
 from ..kernels.fused_mlp.ref import fused_mlp_hidden_ref
 from ..kernels.matmul.ops import matmul
@@ -68,14 +67,12 @@ def _check_impl(impl: str) -> None:
 class _LinearConfig(NamedTuple):
     """Static dispatch config threaded through the custom_vjp (hashable)."""
     tuned: bool
-    interpret: bool
     hw_name: Optional[str]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _pallas_linear(cfg: _LinearConfig, x2, w):
-    return matmul(x2, w, tuned=cfg.tuned, interpret=cfg.interpret,
-                  hw_name=cfg.hw_name)
+    return matmul(x2, w, tuned=cfg.tuned, hw_name=cfg.hw_name)
 
 
 def _pallas_linear_fwd(cfg, x2, w):
@@ -87,10 +84,8 @@ def _pallas_linear_bwd(cfg, res, g):
     # both transposed GEMMs stay on the Pallas path and key the cache with
     # their own (m, k, n): dgrad (m, n, k) and wgrad (k, m, n) tune
     # independently of the forward
-    dx = matmul(g, w.T, tuned=cfg.tuned, interpret=cfg.interpret,
-                hw_name=cfg.hw_name)
-    dw = matmul(x2.T, g, tuned=cfg.tuned, interpret=cfg.interpret,
-                hw_name=cfg.hw_name)
+    dx = matmul(g, w.T, tuned=cfg.tuned, hw_name=cfg.hw_name)
+    dw = matmul(x2.T, g, tuned=cfg.tuned, hw_name=cfg.hw_name)
     return dx.astype(x2.dtype), dw.astype(w.dtype)
 
 
@@ -101,8 +96,7 @@ _pallas_linear.defvjp(_pallas_linear_fwd, _pallas_linear_bwd)
 def _quantized_linear(cfg: _LinearConfig, x2, w):
     """Float-weight quantized linear: weight quantizes per output channel on
     the fly, activation per row inside the kernel wrapper."""
-    return int8_matmul(x2, w, tuned=cfg.tuned, interpret=cfg.interpret,
-                       hw_name=cfg.hw_name)
+    return int8_matmul(x2, w, tuned=cfg.tuned, hw_name=cfg.hw_name)
 
 
 def _quantized_linear_fwd(cfg, x2, w):
@@ -113,10 +107,8 @@ def _quantized_linear_bwd(cfg, res, g):
     x2, w = res
     # straight-through: int8 rounding treated as identity, both grad GEMMs
     # take the high-precision tuned route (their own cache keys)
-    dx = matmul(g, w.T, tuned=cfg.tuned, interpret=cfg.interpret,
-                hw_name=cfg.hw_name)
-    dw = matmul(x2.T, g, tuned=cfg.tuned, interpret=cfg.interpret,
-                hw_name=cfg.hw_name)
+    dx = matmul(g, w.T, tuned=cfg.tuned, hw_name=cfg.hw_name)
+    dw = matmul(x2.T, g, tuned=cfg.tuned, hw_name=cfg.hw_name)
     return dx.astype(x2.dtype), dw.astype(w.dtype)
 
 
@@ -128,7 +120,7 @@ def _quantized_linear_frozen(cfg: _LinearConfig, x2, wq, wscale):
     """Prequantized-weight linear (QuantizedLinear container): the int8
     payload and scales pass straight to the kernel."""
     return int8_matmul(x2, QuantizedTensor(wq, wscale, -2), tuned=cfg.tuned,
-                       interpret=cfg.interpret, hw_name=cfg.hw_name)
+                       hw_name=cfg.hw_name)
 
 
 def _quantized_frozen_fwd(cfg, x2, wq, wscale):
@@ -138,8 +130,7 @@ def _quantized_frozen_fwd(cfg, x2, wq, wscale):
 def _quantized_frozen_bwd(cfg, res, g):
     x2, wq, wscale = res
     w = (wq.astype(jnp.float32) * wscale).astype(x2.dtype)
-    dx = matmul(g, w.T, tuned=cfg.tuned, interpret=cfg.interpret,
-                hw_name=cfg.hw_name)
+    dx = matmul(g, w.T, tuned=cfg.tuned, hw_name=cfg.hw_name)
     # int8 payloads carry float0 tangents (non-differentiable by
     # construction); the scales get symbolic zeros
     return (dx.astype(x2.dtype), np.zeros(wq.shape, jax.dtypes.float0),
@@ -192,8 +183,7 @@ def linear(x, w, *, impl: str = "jnp", hw_name: Optional[str] = None):
     with jax.named_scope(f"linear_{impl}"):
         lead, k = x.shape[:-1], x.shape[-1]
         if impl == "quantized":
-            cfg = _LinearConfig(tuned=True, interpret=default_interpret(),
-                                hw_name=hw_name)
+            cfg = _LinearConfig(tuned=True, hw_name=hw_name)
             if isinstance(w, QuantizedTensor):
                 out = _quantized_linear_frozen(
                     cfg, x.reshape(-1, k), w.q, w.scale.reshape(1, -1))
@@ -203,8 +193,7 @@ def linear(x, w, *, impl: str = "jnp", hw_name: Optional[str] = None):
         w = w.astype(x.dtype)
         if impl == "jnp":
             return x @ w
-        cfg = _LinearConfig(tuned=impl in ("tuned", "fused"),
-                            interpret=default_interpret(), hw_name=hw_name)
+        cfg = _LinearConfig(tuned=impl in ("tuned", "fused"), hw_name=hw_name)
         out = _pallas_linear(cfg, x.reshape(-1, k), w)
         return out.reshape(*lead, w.shape[-1])
 
@@ -223,14 +212,12 @@ def expert_linear(x, w, *, impl: str = "jnp", hw_name: Optional[str] = None):
         if impl == "jnp":
             return jnp.einsum("emk,ekn->emn", x, w)
         if impl == "quantized":
-            qcfg = _LinearConfig(tuned=True, interpret=default_interpret(),
-                                 hw_name=hw_name)
+            qcfg = _LinearConfig(tuned=True, hw_name=hw_name)
             # per-expert dynamic quantization: every expert shares one
             # (m, k, n) cache key, like the float Pallas path below
             return jax.lax.map(
                 lambda xw: _quantized_linear(qcfg, xw[0], xw[1]), (x, w))
-        cfg = _LinearConfig(tuned=impl in ("tuned", "fused"),
-                            interpret=default_interpret(), hw_name=hw_name)
+        cfg = _LinearConfig(tuned=impl in ("tuned", "fused"), hw_name=hw_name)
         return jax.lax.map(lambda xw: _pallas_linear(cfg, xw[0], xw[1]),
                            (x, w))
 
@@ -250,22 +237,20 @@ def fused_mlp(x, p, cfg, *, impl: Optional[str] = None,
         w_gate = p["w_gate"].astype(dt) if cfg.mlp_type == "swiglu" else None
         hidden = fused_mlp_hidden(
             x, w_gate, p["w_up"].astype(dt), mlp_type=cfg.mlp_type,
-            tuned=True, interpret=default_interpret(), hw_name=hw_name)
+            tuned=True, hw_name=hw_name)
         return linear(hidden, p["w_down"], impl="tuned", hw_name=hw_name)
 
 
 class _QuantMLPConfig(NamedTuple):
     """Static dispatch config for the quantized fused-MLP custom_vjp."""
     mlp_type: str
-    interpret: bool
     hw_name: Optional[str]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _quantized_hidden(cfg: _QuantMLPConfig, x2, w_gate, w_up):
     return int8_fused_mlp_hidden(x2, w_gate, w_up, mlp_type=cfg.mlp_type,
-                                 tuned=True, interpret=cfg.interpret,
-                                 hw_name=cfg.hw_name)
+                                 tuned=True, hw_name=cfg.hw_name)
 
 
 def _quantized_hidden_fwd(cfg, x2, w_gate, w_up):
@@ -307,9 +292,9 @@ def quantized_mlp(x, p, cfg, *, hw_name: Optional[str] = None):
         if isinstance(w_up, QuantizedTensor):
             hidden = int8_fused_mlp_hidden(
                 x2, w_gate, w_up, mlp_type=cfg.mlp_type, tuned=True,
-                interpret=default_interpret(), hw_name=hw_name)
+                hw_name=hw_name)
         else:
-            qcfg = _QuantMLPConfig(cfg.mlp_type, default_interpret(), hw_name)
+            qcfg = _QuantMLPConfig(cfg.mlp_type, hw_name)
             hidden = _quantized_hidden(
                 qcfg, x2,
                 None if w_gate is None else w_gate.astype(x.dtype),
@@ -326,16 +311,13 @@ def expert_fused_hidden(x, w_gate, w_up, *, mlp_type: str,
     (e, m, f), one fused kernel per expert under `lax.map` (the MoE
     counterpart of `fused_mlp`'s hidden half)."""
     dt = x.dtype
-    interp = default_interpret()
     wu = w_up.astype(dt)
     if mlp_type == "swiglu":
         return jax.lax.map(
             lambda t: fused_mlp_hidden(t[0], t[1], t[2], mlp_type=mlp_type,
-                                       tuned=True, interpret=interp,
-                                       hw_name=hw_name),
+                                       tuned=True, hw_name=hw_name),
             (x, w_gate.astype(dt), wu))
     return jax.lax.map(
         lambda t: fused_mlp_hidden(t[0], None, t[1], mlp_type=mlp_type,
-                                   tuned=True, interpret=interp,
-                                   hw_name=hw_name),
+                                   tuned=True, hw_name=hw_name),
         (x, wu))

@@ -32,17 +32,6 @@ from .linear import expert_fused_hidden, expert_linear, linear, resolve_impl
 from .mlp import apply_mlp
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of jax.experimental in newer jax; the
-    replication-check kwarg was renamed check_rep -> check_vma with it."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def _local_block(cfg: ModelConfig, tp_axis: str):
     e, k = cfg.num_experts, cfg.top_k
 
@@ -121,10 +110,10 @@ def apply_moe_shardmap(p, x, cfg: ModelConfig):
     block = _local_block(cfg, tp_axis)
     spec_tok = P(dp, None)
     spec_exp = P(tp_axis, None, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(None, None), spec_exp, spec_exp, spec_exp, spec_tok),
-        out_specs=(spec_tok, P()),
+        out_specs=(spec_tok, P()), check_vma=False,
     )(p["router"], p.get("w_gate", p["w_up"]), p["w_up"], p["w_down"], xt)
 
     if cfg.num_shared_experts:

@@ -81,13 +81,10 @@ def _ensure_monitoring_hook() -> None:
     with _hook_lock:
         if _monitoring_hooked:
             return
-        try:
-            import jax.monitoring
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_backend_compile)
-            _monitoring_hooked = True
-        except Exception:  # pragma: no cover - old jax without monitoring
-            pass
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_backend_compile)
+        _monitoring_hooked = True
 
 
 class CompileWatch(logging.Handler):

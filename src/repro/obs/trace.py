@@ -26,10 +26,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-try:  # host->XLA-profile attribution; absent on very old jax
-    from jax.profiler import TraceAnnotation as _JaxTraceAnnotation
-except Exception:  # pragma: no cover - import guard
-    _JaxTraceAnnotation = None
+from jax.profiler import TraceAnnotation as _JaxTraceAnnotation
 
 DEFAULT_CAPACITY = 65536
 
@@ -70,7 +67,7 @@ class Span:
         tr = self._tr
         stack = tr._stack()
         stack.append(self.name)
-        if tr.annotate_device and _JaxTraceAnnotation is not None:
+        if tr.annotate_device:
             self._ann = _JaxTraceAnnotation(self.name)
             self._ann.__enter__()
         self._t0_us = tr._now_us()

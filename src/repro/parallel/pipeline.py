@@ -23,7 +23,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
@@ -79,8 +78,8 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
         return outputs
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    return shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(stage_params, x)
+    return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(stage_params, x)
 
 
 def split_layers_into_stages(stacked_params: Any, num_stages: int) -> Any:

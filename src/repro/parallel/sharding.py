@@ -16,7 +16,7 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import MeshConfig, ModelConfig
 
@@ -87,11 +87,19 @@ def constrain(x, kind: str):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def auto_mesh(shape, axes) -> Mesh:
+    """`jax.make_mesh` with every axis of type Auto: the partitioner
+    propagates shardings from the parameter/batch specs and the `constrain`
+    anchors, which is the model this module is written for (explicit axes
+    would demand an out_sharding at every gather)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_mesh(mesh_cfg: MeshConfig) -> Mesh:
     if mesh_cfg.pod > 1:
-        return jax.make_mesh((mesh_cfg.pod, mesh_cfg.data, mesh_cfg.model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((mesh_cfg.data, mesh_cfg.model), ("data", "model"))
+        return auto_mesh((mesh_cfg.pod, mesh_cfg.data, mesh_cfg.model),
+                         ("pod", "data", "model"))
+    return auto_mesh((mesh_cfg.data, mesh_cfg.model), ("data", "model"))
 
 
 def _axes(mesh: Mesh):

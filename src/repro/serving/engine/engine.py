@@ -44,7 +44,7 @@ import numpy as np
 
 from ... import obs
 from ...configs.base import ModelConfig
-from ...core.hardware import Hardware, get_hardware
+from ...core.hardware import Hardware, running_hardware
 from ...models import apply_lm, init_caches
 from ...models.layers import compute_dtype
 from .buckets import BucketPolicy, make_policy
@@ -211,7 +211,7 @@ class Engine:
             cfg = dataclasses.replace(cfg, kv_dtype=kv_dtype)
         self.params = params
         self.cfg = cfg
-        hw = hw or get_hardware()
+        hw = hw or running_hardware()
         self.hw = hw
         self.drift: Optional[obs.DriftMonitor] = None
         self.policy = policy or make_policy(

@@ -7,11 +7,11 @@ analytic model in `core.gemm_model`.  Kernel wrappers then consult the cache
 via `tuned=True`, and `core.gemm_model.MeasuredProfile` uses the same
 entries to calibrate advisor predictions.
 
-On CPU the kernels run in Pallas interpret mode: absolute times are not
-TPU times, but the *relative* ranking across block shapes still reflects
-blocking/padding work, and the full loop (search -> cache -> tuned dispatch
--> calibrated advisor) is exercised end to end.  On a TPU host, pass
-interpret=False and the cache holds real hardware timings.
+Every search takes `interpret=None` by default, which follows the backend
+(`kernels.backend.interpret_mode`): on a TPU the candidates run compiled and
+the cache holds hardware timings; on the CPU they run in Pallas interpret
+mode, whose absolute times are not TPU times, though the full loop (search
+-> cache -> tuned dispatch -> calibrated advisor) is exercised end to end.
 """
 from __future__ import annotations
 
@@ -76,7 +76,7 @@ def _dtype_name(dtype) -> str:
 def autotune_matmul(m: int, k: int, n: int, *, dtype=jnp.float32,
                     hw: Optional[Hardware] = None,
                     cache: Optional[TuningCache] = None,
-                    interpret: bool = True, iters: int = 3, warmup: int = 1,
+                    interpret: Optional[bool] = None, iters: int = 3, warmup: int = 1,
                     max_candidates: Optional[int] = None,
                     verbose: bool = False) -> TunedConfig:
     """Sweep (block_m, block_n, block_k) for an (m, k, n) matmul; persist
@@ -121,7 +121,7 @@ def autotune_matmul(m: int, k: int, n: int, *, dtype=jnp.float32,
 def autotune_fused_mlp(m: int, h: int, f: int, *, mlp_type: str = "swiglu",
                        dtype=jnp.float32, hw: Optional[Hardware] = None,
                        cache: Optional[TuningCache] = None,
-                       interpret: bool = True, iters: int = 3,
+                       interpret: Optional[bool] = None, iters: int = 3,
                        warmup: int = 1,
                        max_candidates: Optional[int] = None,
                        verbose: bool = False) -> TunedConfig:
@@ -178,7 +178,7 @@ def autotune_fused_mlp(m: int, h: int, f: int, *, mlp_type: str = "swiglu",
 def autotune_int8_matmul(m: int, k: int, n: int, *, dtype=jnp.float32,
                          hw: Optional[Hardware] = None,
                          cache: Optional[TuningCache] = None,
-                         interpret: bool = True, iters: int = 3,
+                         interpret: Optional[bool] = None, iters: int = 3,
                          warmup: int = 1,
                          max_candidates: Optional[int] = None,
                          verbose: bool = False) -> TunedConfig:
@@ -229,7 +229,7 @@ def autotune_fp8_matmul(m: int, k: int, n: int, *,
                         fp8_dtype: str = "float8_e4m3fn", dtype=jnp.float32,
                         hw: Optional[Hardware] = None,
                         cache: Optional[TuningCache] = None,
-                        interpret: bool = True, iters: int = 3,
+                        interpret: Optional[bool] = None, iters: int = 3,
                         warmup: int = 1,
                         max_candidates: Optional[int] = None,
                         verbose: bool = False) -> TunedConfig:
@@ -276,7 +276,7 @@ def autotune_int8_fused_mlp(m: int, h: int, f: int, *,
                             mlp_type: str = "swiglu", dtype=jnp.float32,
                             hw: Optional[Hardware] = None,
                             cache: Optional[TuningCache] = None,
-                            interpret: bool = True, iters: int = 3,
+                            interpret: Optional[bool] = None, iters: int = 3,
                             warmup: int = 1,
                             max_candidates: Optional[int] = None,
                             verbose: bool = False) -> TunedConfig:
@@ -331,7 +331,7 @@ def autotune_paged_decode(batch: int, slots: int, s_max: int, kv_heads: int,
                           heads: int, head_dim: int, *, dtype=jnp.float32,
                           hw: Optional[Hardware] = None,
                           cache: Optional[TuningCache] = None,
-                          interpret: bool = True, iters: int = 3,
+                          interpret: Optional[bool] = None, iters: int = 3,
                           warmup: int = 1,
                           max_candidates: Optional[int] = None,
                           verbose: bool = False) -> TunedConfig:
@@ -385,7 +385,7 @@ def autotune_paged_decode_blocktable(batch: int, num_rows: int, s_max: int,
                                      head_dim: int, *, dtype=jnp.float32,
                                      hw: Optional[Hardware] = None,
                                      cache: Optional[TuningCache] = None,
-                                     interpret: bool = True, iters: int = 3,
+                                     interpret: Optional[bool] = None, iters: int = 3,
                                      warmup: int = 1,
                                      max_candidates: Optional[int] = None,
                                      verbose: bool = False) -> TunedConfig:
@@ -475,7 +475,7 @@ def autotune_flash_attention(batch: int, seq: int, heads: int, head_dim: int,
                              causal: bool = True, dtype=jnp.float32,
                              hw: Optional[Hardware] = None,
                              cache: Optional[TuningCache] = None,
-                             interpret: bool = True, iters: int = 3,
+                             interpret: Optional[bool] = None, iters: int = 3,
                              warmup: int = 1,
                              max_candidates: Optional[int] = None,
                              verbose: bool = False) -> TunedConfig:
@@ -527,7 +527,7 @@ def autotune_flash_backward(batch: int, seq: int, heads: int, head_dim: int,
                             causal: bool = True, dtype=jnp.float32,
                             hw: Optional[Hardware] = None,
                             cache: Optional[TuningCache] = None,
-                            interpret: bool = True, iters: int = 3,
+                            interpret: Optional[bool] = None, iters: int = 3,
                             warmup: int = 1,
                             max_candidates: Optional[int] = None,
                             verbose: bool = False) -> TunedConfig:

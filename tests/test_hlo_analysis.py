@@ -73,6 +73,7 @@ class TestCollectiveCensus:
         import subprocess
         import sys
         import textwrap
+        from pathlib import Path
         # collectives need >1 device: run in a subprocess with 4 host devices
         code = textwrap.dedent("""
             import os
@@ -82,7 +83,8 @@ class TestCollectiveCensus:
             import sys
             sys.path.insert(0, "src")
             from repro.core.hlo_analysis import analyze_hlo
-            mesh = jax.make_mesh((4,), ("d",))
+            mesh = jax.make_mesh((4,), ("d",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             s = NamedSharding(mesh, P("d", None))
             x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
             def f(x):
@@ -93,5 +95,6 @@ class TestCollectiveCensus:
             print("COLL_OK", c.coll_total)
         """)
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, cwd="/root/repo", timeout=300)
+                           text=True, cwd=Path(__file__).resolve().parents[1],
+                           timeout=300)
         assert "COLL_OK" in r.stdout, r.stdout + r.stderr

@@ -3,6 +3,9 @@ loss AND in gradients, on a real multi-device mesh."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
+
+REPO_ROOT = Path(__file__).resolve().parents[1]
 
 
 def _run(code: str, timeout=560):
@@ -10,7 +13,7 @@ def _run(code: str, timeout=560):
             "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
             "import sys; sys.path.insert(0, 'src')\n" + textwrap.dedent(code))
     r = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd="/root/repo", timeout=timeout)
+                       text=True, cwd=REPO_ROOT, timeout=timeout)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     return r.stdout
 

@@ -3,6 +3,9 @@ reproduce the sequential layer stack exactly."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
+
+REPO_ROOT = Path(__file__).resolve().parents[1]
 
 
 def _run(code: str, timeout=560):
@@ -10,7 +13,7 @@ def _run(code: str, timeout=560):
             "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
             "import sys; sys.path.insert(0, 'src')\n" + textwrap.dedent(code))
     r = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd="/root/repo", timeout=timeout)
+                       text=True, cwd=REPO_ROOT, timeout=timeout)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     return r.stdout
 
@@ -21,7 +24,8 @@ def test_pipeline_matches_sequential():
         from repro.parallel.pipeline import pipeline_apply, split_layers_into_stages
 
         L, S, M, B, D = 8, 4, 6, 2, 16   # layers, stages, microbatches
-        mesh = jax.make_mesh((S, 2), ("pod", "data"))
+        mesh = jax.make_mesh((S, 2), ("pod", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (L, D, D)) * (0.5 / D ** 0.5)
         x = jax.random.normal(jax.random.fold_in(key, 1), (M, B, D))

@@ -3,6 +3,7 @@ process keeps 1 device)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -13,13 +14,15 @@ from repro.configs.registry import get_smoke_config
 from repro.models import init_lm
 from repro.parallel import sharding as sh
 
+REPO_ROOT = Path(__file__).resolve().parents[1]
+
 
 def _run(code: str, timeout=560):
     full = ("import os\n"
             "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
             "import sys; sys.path.insert(0, 'src')\n" + textwrap.dedent(code))
     r = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd="/root/repo", timeout=timeout)
+                       text=True, cwd=REPO_ROOT, timeout=timeout)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     return r.stdout
 

@@ -2,8 +2,10 @@
 predictions must line up with what the dry-run machinery measures, and the
 full train->checkpoint->resume->serve lifecycle must hold together."""
 import dataclasses
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +68,29 @@ def test_dryrun_single_cell_subprocess():
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "whisper-small", "--shape", "decode_32k"],
-        capture_output=True, text=True, cwd="/root/repo",
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, timeout=560)
+        capture_output=True, text=True, cwd=Path(__file__).resolve().parents[1],
+        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "HOME": os.environ.get("HOME", "/tmp"), "JAX_PLATFORMS": "cpu"},
+        timeout=560)
     assert '"status": "ok"' in r.stdout, r.stdout + r.stderr[-2000:]
+
+
+@pytest.mark.parametrize("every,want_steps", [(0, None), (2, [2, 3])])
+def test_train_cli_checkpoint_every(tmp_path, monkeypatch, every, want_steps):
+    """`--checkpoint-every 0` writes no checkpoint, not even the final one;
+    otherwise the periodic and the final checkpoint are both written."""
+    from repro.checkpoint.ckpt import Checkpointer
+    from repro.launch import train
+    # a cache dir named by the environment: the launcher then sets none
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    ckpt = tmp_path / "ckpt"
+    train.main(["--arch", "internlm2-1.8b", "--smoke", "--steps", "3",
+                "--global-batch", "2", "--seq-len", "32",
+                "--checkpoint-every", str(every), "--checkpoint-dir", str(ckpt)])
+    if want_steps is None:
+        assert not ckpt.exists()
+    else:
+        assert Checkpointer(str(ckpt)).all_steps() == want_steps
 
 
 def test_train_resume_lifecycle(tmp_path):

@@ -112,6 +112,8 @@ def run_engine(cfg, params, args) -> None:
     # compile warmup + one decode-step timing, so arrival patterns are
     # expressed in machine-relative units
     step_s = eng.calibrate_step_s()
+    if args.obs_dump:
+        eng.drift = obs.DriftMonitor.for_engine(eng.cfg, eng.policy, eng.hw)
     if watch is not None:
         # every program is now compiled; steady-state serving must not re-jit
         print(f"watchdog: {len(watch.records)} compiles during warmup; "

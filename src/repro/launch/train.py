@@ -151,8 +151,6 @@ def main(argv=None):
                          for k, v in make_batch(cfg, shape, step,
                                                 tc.seed).items()}
                 params, opt, metrics = step_fn(params, opt, batch)
-                if obs.enabled():
-                    jax.block_until_ready(metrics["loss"])
             if obs.enabled():
                 obs.counter("train.steps").inc()
                 obs.counter("train.tokens").inc(

@@ -1,13 +1,17 @@
 """Predicted-vs-measured drift monitor.
 
 The analytic cost model (`core.gemm_model`, calibrated by
-`MeasuredProfile` from the tuning cache) predicts a step time for every
-program the engine lowers; the span tracer measures what each program
-actually took.  This module holds both sides against each other, per
-program site (one row per prefill bucket + one for the pool decode step),
-and reports the prediction error — the exact quantity the ROADMAP's
-measured shape-search loop will optimize against: a shape whose *relative*
-drift is high is a shape where the model would mis-rank candidates.
+`MeasuredProfile` from the tuning cache) predicts a step time for a
+program; the span tracer measures what it took.  This module holds both
+sides against each other, per program site, and reports the prediction
+error — the exact quantity the ROADMAP's measured shape-search loop will
+optimize against: a shape whose *relative* drift is high is a shape where
+the model would mis-rank candidates.
+
+The engine's only site is the pool decode step, whose span ends in the
+sampler readback the engine does anyway, so its duration covers the
+device work.  A monitor is attached explicitly (`engine.drift = ...`, as
+`launch/serve.py --obs-dump` does); the engine never builds one.
 
 Two error views per site:
 
@@ -52,18 +56,14 @@ class DriftMonitor:
                    hw: Optional[Hardware] = None,
                    profile: Optional[MeasuredProfile] = None
                    ) -> "DriftMonitor":
-        """One predicted site per engine program: every prefill bucket
-        (batch 1, forward-only at the bucket length) plus the pool-wide
-        decode step (batch = num_slots against a seq_max-deep cache —
-        the upper bound the bucket policy sizes for)."""
+        """The engine's one predicted site: the pool-wide decode step
+        (batch = num_slots against a seq_max-deep cache — the upper bound
+        the bucket policy sizes for).  Prefill spans time only the
+        dispatch, so they get no site."""
         hw = hw or get_hardware()
         if profile is None:
             profile = MeasuredProfile.from_cache(None, hw.name)
         mon = cls(hw_name=hw.name)
-        for b in policy.prompt_buckets:
-            shape = ShapeConfig(f"obs_prefill_{b}", b, 1, "prefill")
-            mon.add_site(f"prefill_{b}",
-                         step_time(cfg, shape, hw, profile=profile))
         shape = ShapeConfig("obs_decode", policy.seq_max, policy.num_slots,
                             "decode")
         mon.add_site("decode_step",

@@ -10,7 +10,13 @@ interval containment per thread track.
 When `annotate_device=True` every span also enters a
 `jax.profiler.TraceAnnotation`, so a concurrent `jax.profiler.trace(...)`
 capture attributes XLA host/device activity to the same model sites
-(engine step, prefill bucket, ...) the host spans name.
+(engine step, prefill bucket, ...) the host spans name.  The annotation
+keeps the span's bare name and carries its ids as stats: a per-tracer
+sequence number `span_id` (also stored in the event's args) and whichever
+of `rid`, `step`, `bucket`, `live` the span has.  So each profiler event
+pairs one-to-one with its buffered event, and the Chrome export's
+`otherData.perf_counter_origin_s` (the buffer's `time.perf_counter` origin)
+puts the whole buffer on the profiler's clock from any one pair.
 
 The tracer is deliberately dumb and cheap: no sampling, no aggregation
 (that is `obs.metrics`), one lock around a bounded deque.  The module-level
@@ -19,6 +25,7 @@ no-op span and never touch this module's state.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -29,6 +36,8 @@ from typing import Any, Dict, List, Optional
 from jax.profiler import TraceAnnotation as _JaxTraceAnnotation
 
 DEFAULT_CAPACITY = 65536
+# span args copied onto the profiler event, beside `span_id`
+ANNOTATED_ARGS = ("rid", "step", "bucket", "live")
 
 
 class NullSpan:
@@ -51,7 +60,8 @@ NULL_SPAN = NullSpan()
 class Span:
     """One live span; records an "X" event into its tracer on exit."""
 
-    __slots__ = ("_tr", "name", "cat", "args", "_t0_us", "_ann", "dur_s")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0_us", "_ann", "dur_s",
+                 "span_id")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -62,13 +72,17 @@ class Span:
         self._t0_us = 0.0
         self._ann = None
         self.dur_s = 0.0
+        self.span_id = -1
 
     def __enter__(self):
         tr = self._tr
+        self.span_id = next(tr._ids)
         stack = tr._stack()
         stack.append(self.name)
         if tr.annotate_device:
-            self._ann = _JaxTraceAnnotation(self.name)
+            ids = {k: self.args[k] for k in ANNOTATED_ARGS if k in self.args}
+            self._ann = _JaxTraceAnnotation(self.name, span_id=self.span_id,
+                                            **ids)
             self._ann.__enter__()
         self._t0_us = tr._now_us()
         return self
@@ -86,7 +100,7 @@ class Span:
             "name": self.name, "cat": self.cat, "ph": "X",
             "ts": self._t0_us, "dur": t1 - self._t0_us,
             "pid": tr.pid, "tid": threading.get_ident(),
-            "args": dict(self.args, depth=len(stack)),
+            "args": dict(self.args, depth=len(stack), span_id=self.span_id),
         })
         return False
 
@@ -103,6 +117,7 @@ class Tracer:
         self._buf: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._ids = itertools.count()
         self._t0 = time.perf_counter()
 
     # -- internals -----------------------------------------------------------
@@ -156,7 +171,8 @@ class Tracer:
         return {
             "traceEvents": meta + self.events(),
             "displayTimeUnit": "ms",
-            "otherData": {"dropped_events": self.dropped},
+            "otherData": {"dropped_events": self.dropped,
+                          "perf_counter_origin_s": self._t0},
         }
 
     def save(self, path: str) -> None:

@@ -115,7 +115,8 @@ def compile_table(compiles: Optional[dict], trace: dict) -> List[str]:
 
 def drift_table(drift: Optional[dict]) -> List[str]:
     if not drift or not drift.get("rows"):
-        return ["(no drift data — run the engine with obs enabled)"]
+        return ["(no drift data — attach a DriftMonitor to the engine, as "
+                "`launch/serve.py --obs-dump` does)"]
     out = [f"hardware model: {drift.get('hw_name', '?')}", "",
            "| site | count | predicted ms | measured p50 ms | ratio | "
            "rel drift |", "|---|---|---|---|---|---|"]

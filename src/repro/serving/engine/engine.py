@@ -213,7 +213,7 @@ class Engine:
         self.cfg = cfg
         hw = hw or running_hardware()
         self.hw = hw
-        self.drift: Optional[obs.DriftMonitor] = None
+        self.drift: Optional[obs.DriftMonitor] = None   # attached by callers
         self.policy = policy or make_policy(
             cfg, hw, max_batch=max_batch, max_prompt=max_prompt,
             max_seq=max_prompt + max_new, grow_batch=grow_batch)
@@ -393,14 +393,14 @@ class Engine:
                     padded = np.zeros((1, bucket), np.int32)
                     padded[0, :req.prompt_len] = req.tokens
                     with obs.span("prefill", bucket=bucket, rid=req.rid,
-                                  cached_tokens=0) as psp:
+                                  cached_tokens=0):
                         logits, caches = self._prefills[bucket](
                             self.params, jnp.asarray(padded),
                             jnp.asarray(req.prompt_len, jnp.int32))
-                        if obs.enabled():
-                            jax.block_until_ready(logits)
-                    if self.drift is not None:
-                        self.drift.observe(f"prefill_{bucket}", psp.dur_s)
+                    if obs.enabled():
+                        obs.counter("engine.prefill_tokens").inc(
+                            req.prompt_len)
+                        obs.counter("engine.prefill_bucket_tokens").inc(bucket)
                     self.pool.write(slot, caches, req.prompt_len)
             except PoolExhausted as e:
                 # admission raced a COW burst / held blocks: the slot is
@@ -519,18 +519,16 @@ class Engine:
         padded[0, :len(suffix)] = suffix
         contig = pool.gather(slot)
         with obs.span("prefill", bucket=bucket, rid=req.rid,
-                      cached_tokens=p) as psp:
+                      cached_tokens=p):
             logits, contig = self._prefills[bucket](
                 self.params, jnp.asarray(padded),
                 jnp.asarray(len(suffix), jnp.int32),
                 jnp.asarray(p, jnp.int32), contig)
-            if obs.enabled():
-                jax.block_until_ready(logits)
-        if self.drift is not None and obs.enabled():
-            self.drift.observe(f"prefill_{bucket}", psp.dur_s)
         pool.scatter(slot, contig, p // pool.block_size)
         pool.commit(slot, tokens)
         if obs.enabled():
+            obs.counter("engine.prefill_tokens").inc(len(suffix))
+            obs.counter("engine.prefill_bucket_tokens").inc(bucket)
             obs.counter("kv.prefix_hit_tokens").inc(p)
             self._kv_gauges()
         cached = p if res is None else res.cached_tokens
@@ -651,9 +649,6 @@ class Engine:
         self._faults = faults
         if faults is not None:
             faults.reset()
-        if obs.enabled() and self.drift is None:
-            self.drift = obs.DriftMonitor.for_engine(self.cfg, self.policy,
-                                                     self.hw)
         self._t0 = time.perf_counter()
         done: List[Completion] = []
         valid: List[Request] = []
@@ -668,35 +663,40 @@ class Engine:
         sched = Scheduler(queue, self.pool, policy, shed=shed)
         states: Dict[int, _SlotState] = {}
 
+        # every pass of the loop runs inside top-level spans: `schedule`,
+        # one `admit` per admission, then `idle_wait` or `decode_step` and
+        # `bookkeep` (in `_step`)
         while not sched.drained:
-            admits, sheds = sched.admissions(self._now())
-            for s in sheds:
-                self._drop(s.req, s.reason, s.detail, done)
+            with obs.span("schedule"):
+                admits, sheds = sched.admissions(self._now())
+                for s in sheds:
+                    self._drop(s.req, s.reason, s.detail, done)
+                if obs.enabled():
+                    obs.gauge("engine.queue_depth").set(len(queue))
+                    self._kv_gauges()
             for req, slot in admits:
                 self._admit(req, slot, states, done)
-            if obs.enabled():
-                obs.gauge("engine.queue_depth").set(len(queue))
-                self._kv_gauges()
             if not states:
                 if admits or sheds:
                     continue    # progress was made; re-evaluate immediately
-                nxt = queue.next_arrival_s()
-                now = self._now()
-                if nxt is not None and nxt > now:
-                    time.sleep(nxt - now + 1e-4)
-                elif len(queue):
-                    # ready requests, an idle pool, and still no admission:
-                    # nothing left that could free capacity.  Give injected
-                    # holds a chance to drain, else fail the head request
-                    # rather than spin forever.
-                    if faults is not None and faults.drain_holds(self):
-                        continue
-                    req = queue.pop_ready(now)
-                    if req is not None:
-                        self._drop_or_requeue_dead_end(
-                            req, self._resume.pop(req.rid, None),
-                            "unadmittable with an idle pool "
-                            "(exceeds usable capacity)", done)
+                with obs.span("idle_wait"):
+                    nxt = queue.next_arrival_s()
+                    now = self._now()
+                    if nxt is not None and nxt > now:
+                        time.sleep(nxt - now + 1e-4)
+                    elif len(queue):
+                        # ready requests, an idle pool, and still no
+                        # admission: nothing left that could free capacity.
+                        # Give injected holds a chance to drain, else fail
+                        # the head request rather than spin forever.
+                        if faults is not None and faults.drain_holds(self):
+                            continue
+                        req = queue.pop_ready(now)
+                        if req is not None:
+                            self._drop_or_requeue_dead_end(
+                                req, self._resume.pop(req.rid, None),
+                                "unadmittable with an idle pool "
+                                "(exceeds usable capacity)", done)
                 continue
             self._step(states, done)
             if check_invariants and self.prefix_cache:
@@ -724,8 +724,8 @@ class Engine:
         raising; preempted rows ride through the step masked-dead."""
         if self._faults is not None:
             self._faults.on_step(self, self.decode_steps)
-        with obs.span("decode_step", step=self.decode_steps,
-                      live=len(states),
+        step = self.decode_steps
+        with obs.span("decode_step", step=step, live=len(states),
                       batch=self.policy.num_slots) as dsp:
             if self.prefix_cache:
                 # make each live row's write position physically writable
@@ -743,44 +743,42 @@ class Engine:
                                               states, done)
                 if not states:
                     return      # every row was preempted: nothing to decode
-                pos = np.asarray(self.pool.lengths, np.int32)
-                logits, caches = self._decode(
-                    self.params, jnp.asarray(self._last_tok[:, None]),
-                    self.pool.caches, jnp.asarray(pos),
-                    jnp.asarray(self.pool.tables()))
-            else:
-                pos = np.asarray(self.pool.lengths, np.int32)
-                logits, caches = self._decode(
-                    self.params, jnp.asarray(self._last_tok[:, None]),
-                    self.pool.caches, jnp.asarray(pos))
-            self.pool.caches = caches
+            with obs.span("decode_inputs", step=step):
+                args = (self.params, jnp.asarray(self._last_tok[:, None]),
+                        self.pool.caches,
+                        jnp.asarray(np.asarray(self.pool.lengths, np.int32)))
+                if self.prefix_cache:
+                    args += (jnp.asarray(self.pool.tables()),)
+                logits, self.pool.caches = self._decode(*args)
             with obs.span("sample", cat="sample",
                           batch=self.policy.num_slots):
                 toks = np.asarray(self._sample(
                     logits, jnp.asarray(self._temps),
                     jnp.asarray(self._seeds), jnp.asarray(self._steps)))
-        if self.drift is not None and obs.enabled():
-            self.drift.observe("decode_step", dsp.dur_s)
-        if obs.enabled():
-            obs.counter("engine.decode_steps").inc()
-            obs.counter("engine.tokens_generated").inc(len(states))
-            obs.histogram("engine.decode_step_s").observe(dsp.dur_s)
-        self.decode_steps += 1
-        t = self._now()
-        for slot in list(states):
-            st = states[slot]
-            tok = int(toks[slot])
-            self.pool.advance(slot)
-            self._last_tok[slot] = tok
-            self._steps[slot] += 1
-            st.generated.append(tok)
-            st.itl_s.append(t - st.last_t_s)
-            st.last_t_s = t
-            if self._finished(st):
-                self._complete(slot, st, states, done)
-            elif (st.req.deadline_s is not None
-                  and t > st.req.arrival_s + st.req.deadline_s):
-                self._complete(
-                    slot, st, states, done, reason="timeout",
-                    detail=f"deadline {st.req.deadline_s:.3f}s expired "
-                           f"after {len(st.generated)} tokens")
+        with obs.span("bookkeep", step=step, live=len(states)):
+            t = self._now()
+            self.decode_steps += 1
+            if obs.enabled():
+                if self.drift is not None:
+                    self.drift.observe("decode_step", dsp.dur_s)
+                obs.counter("engine.decode_steps").inc()
+                obs.counter("engine.decode_rows_live").inc(len(states))
+                obs.counter("engine.tokens_generated").inc(len(states))
+                obs.histogram("engine.decode_step_s").observe(dsp.dur_s)
+            for slot in list(states):
+                st = states[slot]
+                tok = int(toks[slot])
+                self.pool.advance(slot)
+                self._last_tok[slot] = tok
+                self._steps[slot] += 1
+                st.generated.append(tok)
+                st.itl_s.append(t - st.last_t_s)
+                st.last_t_s = t
+                if self._finished(st):
+                    self._complete(slot, st, states, done)
+                elif (st.req.deadline_s is not None
+                      and t > st.req.arrival_s + st.req.deadline_s):
+                    self._complete(
+                        slot, st, states, done, reason="timeout",
+                        detail=f"deadline {st.req.deadline_s:.3f}s expired "
+                               f"after {len(st.generated)} tokens")

@@ -16,6 +16,7 @@ from repro.obs import view
 from repro.obs.trace import NULL_SPAN, Tracer
 from repro.serving.engine import (Completion, Engine, EngineStats,
                                   synthetic_requests)
+from repro.serving.engine.request import Request
 from repro.tuning.cache import TunedConfig
 from repro.tuning.measure import wall_us
 
@@ -81,6 +82,21 @@ class TestTracer:
         assert len(evs) == 4 and tr.dropped == 2
         assert all(e["ph"] == "i" and e["s"] == "t" for e in evs)
         assert evs[0]["args"]["i"] == 2  # oldest two fell off
+
+    def test_span_ids_and_clock_origin(self):
+        obs.enable(annotate_device=False)
+        with obs.span("a", rid=3):
+            with obs.span("b"):
+                pass
+        with obs.span("c") as c:
+            pass
+        tr = obs.get_tracer()
+        ids = {e["name"]: e["args"]["span_id"] for e in tr.events()}
+        # numbered in the order the spans were entered, one per span
+        assert ids == {"a": ids["a"], "b": ids["a"] + 1, "c": ids["a"] + 2}
+        assert c.span_id == ids["c"]
+        other = tr.to_chrome()["otherData"]
+        assert other["perf_counter_origin_s"] == tr._t0
 
     def test_save_is_loadable(self, tmp_path):
         obs.enable(annotate_device=False)
@@ -246,6 +262,10 @@ class TestEngineObservability:
 
             obs.enable(annotate_device=False)
             obs.reset()  # counters below are per-run, not per-process
+            # the engine builds no drift monitor: the caller attaches one
+            assert eng.drift is None
+            eng.drift = obs.DriftMonitor.for_engine(eng.cfg, eng.policy,
+                                                    eng.hw)
             watch.arm()
             reqs = synthetic_requests(4, pattern="burst", min_prompt=4,
                                       max_prompt=16, min_new=2, max_new=8,
@@ -278,10 +298,10 @@ class TestEngineObservability:
             assert snap["histograms"]["engine.decode_step_s"]["count"] == \
                 stats.decode_steps
 
-            # drift accumulated one observation per decode step
+            # the attached monitor has one site, fed once per decode step
             rows = {r["site"]: r for r in eng.drift.report()}
+            assert list(rows) == ["decode_step"]
             assert rows["decode_step"]["count"] == stats.decode_steps
-            assert any(s.startswith("prefill_") for s in rows)
 
             # the dump round-trips through export_all and the view CLI
             dump = str(tmp_path / "dump")
@@ -308,3 +328,123 @@ class TestEngineObservability:
             watch.disarm()
         finally:
             watch.uninstall()
+
+
+LOOP_SPANS = {"schedule", "admit", "idle_wait", "decode_step", "bookkeep"}
+
+
+def _shared_prefix_requests(n, *, vocab, gap_s=0.0, seed=5):
+    """Prompts of 12-28 tokens, the first 16 shared by every other request,
+    due `gap_s` apart."""
+    rng = np.random.default_rng(seed)
+    head = rng.integers(1, vocab, 16, dtype=np.int32)
+    reqs = []
+    for i in range(n):
+        own = rng.integers(1, vocab, int(rng.integers(12, 29)), dtype=np.int32)
+        toks = np.concatenate([head, own[:12]]) if i % 2 else own
+        reqs.append(Request(rid=i, tokens=toks,
+                            max_new_tokens=int(rng.integers(2, 9)),
+                            arrival_s=i * gap_s))
+    return reqs
+
+
+def _tiny_engine(smoke_lm, prefix_cache):
+    cfg, params = smoke_lm
+    kw = dict(block_size=8, num_blocks=64) if prefix_cache else {}
+    return Engine(params, cfg, max_batch=3, max_prompt=32, max_new=8,
+                  prefix_cache=prefix_cache, **kw)
+
+
+class TestEngineLoopTracing:
+    """Tracing changes what is recorded, never what the engine does: the
+    same device syncs and host readbacks with obs on and off, top-level
+    spans that account for the run's wall time, and counters that agree
+    with what the completions and the spans say happened."""
+
+    @pytest.mark.parametrize("prefix_cache", [True, False])
+    def test_same_host_syncs_with_obs_on_and_off(self, smoke_lm,
+                                                 prefix_cache, monkeypatch):
+        from jax._src.array import ArrayImpl
+        eng = _tiny_engine(smoke_lm, prefix_cache)
+        reqs = _shared_prefix_requests(6, vocab=smoke_lm[0].vocab_size)
+        eng.run(reqs)                               # compile everything
+        counts = {"sync": 0, "readback": 0}
+        block = jax.block_until_ready
+        method = ArrayImpl.block_until_ready
+        value = ArrayImpl._value
+        buffer = ArrayImpl.__buffer__
+
+        def counting_block(x):
+            counts["sync"] += 1
+            return block(x)
+
+        def counting_method(self):
+            counts["sync"] += 1
+            return method(self)
+
+        def counting_value(self):
+            if self._npy_value is None:     # a copy from the device
+                counts["readback"] += 1
+            return value.fget(self)
+
+        def counting_buffer(self, flags):   # NumPy's zero-copy host view
+            counts["readback"] += 1
+            return buffer(self, flags)
+
+        monkeypatch.setattr(jax, "block_until_ready", counting_block)
+        monkeypatch.setattr(ArrayImpl, "block_until_ready", counting_method)
+        monkeypatch.setattr(ArrayImpl, "_value", property(counting_value))
+        monkeypatch.setattr(ArrayImpl, "__buffer__", counting_buffer)
+        seen = {}
+        for on in (False, True):
+            if on:
+                obs.enable(annotate_device=True)
+            counts.update(sync=0, readback=0)
+            done, _ = eng.run(reqs)
+            seen[on] = dict(counts)
+            assert all(c.ok for c in done)
+        assert seen[True] == seen[False]
+        assert seen[False]["readback"] > 0
+        assert eng.drift is None
+
+    def test_loop_spans_cover_the_run(self, smoke_lm):
+        eng = _tiny_engine(smoke_lm, prefix_cache=True)
+        warm = _shared_prefix_requests(6, vocab=smoke_lm[0].vocab_size)
+        eng.run(warm)
+        obs.enable(annotate_device=False)
+        # due apart, so the pool drains and the loop waits between them
+        reqs = _shared_prefix_requests(6, vocab=smoke_lm[0].vocab_size,
+                                       gap_s=0.05, seed=6)
+        done, stats = eng.run(reqs)
+        spans = [e for e in obs.get_tracer().events() if e["ph"] == "X"]
+        top = [e for e in spans if e["args"]["depth"] == 0]
+        assert {e["name"] for e in top} == LOOP_SPANS
+        covered_s = sum(e["dur"] for e in top) * 1e-6
+        assert covered_s >= 0.95 * stats.wall_s
+        # decode_step and bookkeep are siblings, once per step each;
+        # decode_inputs sits inside decode_step
+        by = lambda n: [e for e in spans if e["name"] == n]
+        assert len(by("decode_step")) == len(by("bookkeep")) == \
+            stats.decode_steps
+        assert all(e["args"]["depth"] == 1 for e in by("decode_inputs"))
+        assert len(by("decode_inputs")) == stats.decode_steps
+
+    @pytest.mark.parametrize("prefix_cache", [True, False])
+    def test_counters_match_completions_and_spans(self, smoke_lm,
+                                                  prefix_cache):
+        eng = _tiny_engine(smoke_lm, prefix_cache)
+        reqs = _shared_prefix_requests(8, vocab=smoke_lm[0].vocab_size)
+        obs.enable(annotate_device=False)
+        done, stats = eng.run(reqs)
+        assert all(c.ok for c in done) and stats.preemptions == 0
+        if prefix_cache:
+            assert sum(c.cached_tokens for c in done) > 0
+        ran = [c.prompt_len - c.cached_tokens for c in done]
+        live = [e["args"]["live"] for e in obs.get_tracer().events()
+                if e["name"] == "decode_step"]
+        counters = obs.get_metrics().snapshot()["counters"]
+        assert counters["engine.prefill_tokens"] == sum(ran)
+        assert counters["engine.prefill_bucket_tokens"] == \
+            sum(eng.policy.prompt_bucket(n) for n in ran)
+        assert counters["engine.decode_rows_live"] == sum(live)
+        assert counters["engine.decode_steps"] == len(live)

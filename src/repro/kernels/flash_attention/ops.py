@@ -34,7 +34,8 @@ from ...tuning.cache import mixed_dtype
 from ..backend import interpret_mode
 from .backward import flash_attention_bwd_pallas
 from .kernel import flash_attention_pallas
-from .paged import paged_decode_blocktable_pallas, paged_decode_pallas
+from .paged import (CHUNK_TOKENS, paged_decode_blocktable_pallas,
+                    paged_decode_pallas)
 from .ref import (attention_ref, paged_decode_blocktable_ref,
                   paged_decode_ref)
 
@@ -266,7 +267,7 @@ def paged_decode(q, k_pool, v_pool, slot_idx, lengths, *,
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret",
                                              "use_pallas"))
 def _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths, k_scale,
-                  v_scale, *, block_kv: int, interpret: bool,
+                  v_scale, *, block_kv: Optional[int], interpret: bool,
                   use_pallas: bool):
     if not use_pallas:
         if k_scale is not None:
@@ -275,17 +276,11 @@ def _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths, k_scale,
         return paged_decode_blocktable_ref(q, k_blocks.astype(q.dtype),
                                            v_blocks.astype(q.dtype),
                                            block_tables, lengths)
-    block_size = k_blocks.shape[1]
-    bkv = min(block_kv, block_size)
-    if block_size % bkv:
-        # clamp to a divisor: the kv tile must stay inside one physical
-        # block (tiles never straddle a page boundary)
-        import math
-        bkv = math.gcd(block_size, bkv)
     return paged_decode_blocktable_pallas(q, k_blocks, v_blocks,
                                           block_tables, lengths,
                                           k_scale=k_scale, v_scale=v_scale,
-                                          block_kv=bkv, interpret=interpret)
+                                          block_kv=block_kv,
+                                          interpret=interpret)
 
 
 def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
@@ -303,8 +298,12 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
     (0 = dead row -> zero output).  Returns (b, a, d).
 
     k_scale/v_scale: (num_blocks, block_size, nkv) f32 per-(token, kv_head)
-    scales for an int8 block pool; dequantized per kv tile in-kernel on the
+    scales for an int8 block pool; dequantized per chunk in-kernel on the
     Pallas path, up front on the jnp path.
+
+    block_kv is the Pallas kernel's chunk length in tokens, rounded up to
+    whole blocks (None: `paged.CHUNK_TOKENS`); each row reads only its live
+    blocks, a chunk of them per step of the kernel's loop.
 
     tuned=True overrides block_kv with the autotuning cache's measured-best
     for this block-pool shape (op "paged_decode_blocktable") when one exists
@@ -330,10 +329,10 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
         obs.record_dispatch(
             "paged_decode_blocktable",
             impl="pallas" if use_pallas else "jnp", shape=q.shape,
-            blocks={"block_kv": block_kv or block_size,
+            blocks={"block_kv": block_kv or CHUNK_TOKENS,
                     "block_size": block_size} if use_pallas else None,
             tuned_hit=tuned_hit)
     return _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths,
-                         k_scale, v_scale, block_kv=block_kv or block_size,
+                         k_scale, v_scale, block_kv=block_kv,
                          interpret=interpret_mode(interpret),
                          use_pallas=use_pallas)

@@ -26,16 +26,23 @@ regime); block_kv is the lane-side knob the autotuner sweeps
 (`tuning.search.autotune_paged_decode`).
 
 `paged_decode_blocktable_pallas` is the block-table variant (vLLM paged
-attention at a real page size): K/V live in a pool of physical blocks of
-`block_size` tokens and row b's logical kv block j comes from
-`block_table[b, j]`.  The scalar-prefetch operands carry `(block_table[b, j],
-lengths[b])`, so the BlockSpec index map gathers each kv tile from an
-arbitrary physical block; the kernel body is shared with the slot variant
-(it only sees logical kv positions).  Here *two* knobs are tile-lattice
-choices the autotuner sweeps jointly: the physical block size (the paging
-granule, a weight on copy/gather cost and sharing granularity) and block_kv
-(the kv tile per grid step, dividing the block size) — see
-`tuning.search.autotune_paged_decode_blocktable`.
+attention at a real page size): K/V live in a pool of physical blocks
+(pages) of `block_size` tokens and row b's logical page j is physical page
+`block_table[b, j]`.  Its grid is (b,), one step per row, so its work
+follows each row's live pages and not the table's width.  The table and
+lengths sit in SMEM (scalar prefetch); the pools stay in HBM
+(`memory_space=pl.ANY`).  A row loops over chunks of P pages
+(`blocktable_chunk_pages`: 256 tokens by default, `block_kv` tokens
+rounded up to whole pages when given) for ceil(lengths[b] / chunk)
+iterations -- none for a dead row, which writes zeros.  Each chunk's live
+pages are gathered by one async copy per page and pool into a
+double-buffered VMEM scratch, the next chunk's copies started before this
+chunk is computed; pages past the live prefix are never copied.  The
+scores of a chunk are one lane-dense (a, chunk * nkv) tile for all query
+heads (see `_paged_bt_kernel`), masked by head and by `lengths[b]`, with
+the online softmax in f32.  An int8 pool's scale pages travel with their
+K/V pages.  The tuner sweeps the page size jointly with the chunk length
+(`tuning.search.autotune_paged_decode_blocktable`).
 """
 from __future__ import annotations
 
@@ -167,6 +174,130 @@ def paged_decode_pallas(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return out.reshape(b, a, d)
 
 
+CHUNK_TOKENS = 256          # default chunk of the block-table kernel
+CHUNK_VMEM_BYTES = 8 << 20  # cap on its double-buffered chunk scratch
+
+
+def blocktable_chunk_pages(block_size: int, max_blocks: int,
+                           page_bytes: int,
+                           block_kv: int | None = None) -> int:
+    """Pages the block-table kernel gathers per chunk: `block_kv` tokens
+    (default CHUNK_TOKENS) rounded up to whole pages, capped at max_blocks
+    and so that two slots of every gathered pool fit CHUNK_VMEM_BYTES.
+    page_bytes is one page of all gathered pools (K, V and any scales)."""
+    want = -(-(block_kv or CHUNK_TOKENS) // block_size)
+    fit = CHUNK_VMEM_BYTES // (2 * page_bytes)
+    return max(1, min(want, max_blocks, fit))
+
+
+def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
+                     pages: int, block_size: int, quant: bool, scale: float):
+    # refs: the HBM pools (k, v[, k_scale, v_scale]), the output, the VMEM
+    # chunk buffers (one per pool) and the DMA semaphores
+    n_pools = 4 if quant else 2
+    pools_hbm = refs[:n_pools]
+    o_ref = refs[n_pools]
+    bufs = refs[n_pools + 1:2 * n_pools + 1]
+    sem = refs[2 * n_pools + 1]
+    nkv = num_kv_heads
+    chunk = pages * block_size
+    page_rows = block_size * nkv          # a page is (block_size * nkv, d)
+    b = pl.program_id(0)
+    length = len_ref[b]
+    n_pages = pl.cdiv(length, block_size)
+    n_chunks = pl.cdiv(length, chunk)
+
+    @pl.when(b == 0)
+    def _zero_buffers():
+        # pages past a row's live prefix are never copied in, so the tail of
+        # a last chunk holds what an earlier chunk left (live pages, finite)
+        # or these zeros -- never uninitialised memory, which a probability
+        # of 0 would still turn into NaN in p @ v
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+
+    def copies(i, slot, op):
+        # one DMA per live page and pool; a slot's copies of one pool all
+        # signal one semaphore, and each wait takes one page's bytes
+        from jax.experimental.pallas import tpu as pltpu
+        for p in range(pages):
+            j = i * pages + p
+
+            @pl.when(j < n_pages)
+            def _copy():
+                page = table_ref[b, j]
+                for n, (src, buf) in enumerate(zip(pools_hbm, bufs)):
+                    if n < 2:       # K/V page: rows [p, p+1) * page_rows
+                        src, dst = src.at[page], buf.at[
+                            slot, pl.ds(p * page_rows, page_rows)]
+                    else:           # scale page: one row of page_rows
+                        src, dst = src.at[pl.ds(page, 1)], buf.at[
+                            slot, pl.ds(p, 1)]
+                    getattr(pltpu.make_async_copy(src, dst, sem.at[n, slot]),
+                            op)()
+
+    @pl.when(n_chunks > 0)
+    def _prefetch_first():
+        copies(0, 0, "start")
+
+    # All query heads score the whole chunk at once: column c of the
+    # (a, chunk * nkv) score tile is token c // nkv of kv head c % nkv, and
+    # query head r keeps only the columns of its kv head r // g (the rest
+    # get probability 0).  The chunk is read in its pool layout, with no
+    # per-head strided read; the extra MXU work is nkv-fold on a tile the
+    # MXU barely fills.
+    q = q_ref[0]                                         # (a, d)
+    a = q.shape[0]
+    cols = (a, chunk * nkv)
+    head_ok = (jax.lax.broadcasted_iota(jnp.int32, cols, 1) % nkv
+               == jax.lax.broadcasted_iota(jnp.int32, cols, 0) // (a // nkv))
+    col_tok = jax.lax.broadcasted_iota(jnp.int32, cols, 1) // nkv
+
+    def scale_row(buf, slot):
+        # a chunk's per-(token, head) scales as one (1, chunk * nkv) row,
+        # in the score tile's column order
+        return jnp.concatenate([buf[slot, p:p + 1, :] for p in range(pages)],
+                               axis=1)
+
+    def body(i, carry):
+        m_prev, l_prev, acc_prev = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < n_chunks)
+        def _prefetch_next():
+            copies(i + 1, 1 - slot, "start")
+
+        copies(i, slot, "wait")
+        k = bufs[0][slot]                                # (chunk * nkv, d)
+        v = bufs[1][slot].astype(jnp.float32)
+        # q . k in the pair's common dtype (int8 keys as q's dtype, exact):
+        # bf16 x bf16 products are exact in the f32 accumulator
+        dt = q.dtype if quant else jnp.promote_types(q.dtype, k.dtype)
+        s = jax.lax.dot_general(q.astype(dt), k.astype(dt),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * scale_row(bufs[2], slot)
+        s = jnp.where(head_ok & (col_tok < length - i * chunk), s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * scale_row(bufs[3], slot)
+        acc_new = acc_prev * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    init = (jnp.full((a, 1), NEG_INF, jnp.float32),
+            jnp.zeros((a, 1), jnp.float32),
+            jnp.zeros(q.shape, jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, init)
+    l = jnp.where(l == 0.0, 1.0, l)  # dead row -> zero output
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
 def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
                                    v_blocks: jax.Array,
                                    block_tables: jax.Array,
@@ -182,66 +313,51 @@ def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
     `block_tables[b, j]`; lengths: (b,) live kv per row.
 
     k_scale/v_scale: (num_blocks, block_size, nkv) f32 per-(token, kv_head)
-    dequant scales for an int8 block pool (both or neither); tiles are
-    dequantized in VMEM after the gather-by-table DMA.
+    dequant scales for an int8 block pool (both or neither); their pages
+    are copied beside the K/V pages and applied in VMEM.
 
-    block_kv (default block_size) must divide block_size; the grid runs
-    max_blocks * block_size/block_kv kv steps per row and skips
-    steps wholly past `lengths[b]`, so table entries beyond a row's live
-    blocks are never read (callers pad with any valid block id).
+    block_kv is the chunk length in tokens (default CHUNK_TOKENS), rounded
+    up to whole pages (`blocktable_chunk_pages`).  Only the first
+    ceil(lengths[b] / block_size) table entries of a row are read, so
+    entries past them may hold any value.
     Returns (b, a, d); rows with length 0 return zeros.
     """
+    from jax.experimental.pallas import tpu as pltpu
     b, a, d = q.shape
     nb, block_size, nkv, dk = k_blocks.shape
     bt_rows, max_blocks = block_tables.shape
     assert d == dk and a % nkv == 0 and bt_rows == b
     assert (k_scale is None) == (v_scale is None)
-    block_kv = block_kv or block_size
-    assert block_size % block_kv == 0, (block_size, block_kv)
-    g = a // nkv
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    steps_per_block = block_size // block_kv
-    kv_steps = max_blocks * steps_per_block
-    qh = q.reshape(b, nkv, g, d)
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kv_spec():
-        # logical kv step j -> (physical block, tile within block): the
-        # scalar-prefetched table is indexed *inside the index map*, so the
-        # DMA for row bi streams straight from the right physical block
-        return pl.BlockSpec(
-            (1, block_kv, nkv, d),
-            lambda bi, j, table, lens: (table[bi, j // steps_per_block],
-                                        j % steps_per_block, 0, 0))
-
-    row_spec = pl.BlockSpec((1, nkv, g, d),
-                            lambda bi, j, table, lens: (bi, 0, 0, 0))
-    in_specs = [row_spec, kv_spec(), kv_spec()]
-    operands = [qh, k_blocks, v_blocks]
+    # a page as (block_size * nkv, d) rows: a bitcast of the pool wherever
+    # nkv fills the sublane tile, as it does at the engine's widths
+    pools = [k_blocks.reshape(nb, block_size * nkv, d),
+             v_blocks.reshape(nb, block_size * nkv, d)]
     if k_scale is not None:
         assert k_scale.shape == (nb, block_size, nkv), k_scale.shape
-        def sc_spec():
-            return pl.BlockSpec(
-                (1, block_kv, nkv),
-                lambda bi, j, table, lens: (table[bi, j // steps_per_block],
-                                            j % steps_per_block, 0))
-        in_specs += [sc_spec(), sc_spec()]
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        pools += [x.astype(jnp.float32).reshape(nb, block_size * nkv)
+                  for x in (k_scale, v_scale)]
+    page_bytes = sum(x[0].size * x.dtype.itemsize for x in pools)
+    pages = blocktable_chunk_pages(block_size, max_blocks, page_bytes,
+                                   block_kv)
+    rows = pages * block_size * nkv
+    row_spec = pl.BlockSpec((1, a, d), lambda bi, table, lens: (bi, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    bufs = [pltpu.VMEM((2, rows, d), x.dtype) for x in pools[:2]]
+    bufs += [pltpu.VMEM((2, pages, block_size * nkv), jnp.float32)
+             for _ in pools[2:]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv_steps),
-        in_specs=in_specs,
+        grid=(b,),
+        in_specs=[row_spec] + [hbm] * len(pools),
         out_specs=row_spec,
-        scratch_shapes=_scratch(nkv, g, d),
+        scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((len(pools), 2))],
     )
-    # the kernel body is the slot variant's: it reasons purely in logical kv
-    # positions (ki * block_kv + offset vs lengths[b]); only the index maps
-    # above know the physical indirection
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, num_kv_heads=nkv, kv_steps=kv_steps,
-                          block_kv=block_kv, scale=scale),
+    return pl.pallas_call(
+        functools.partial(_paged_bt_kernel, num_kv_heads=nkv, pages=pages,
+                          block_size=block_size, quant=k_scale is not None,
+                          scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, a, d), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
-    return out.reshape(b, a, d)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *pools)

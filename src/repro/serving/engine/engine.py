@@ -763,6 +763,12 @@ class Engine:
                     self.drift.observe("decode_step", dsp.dur_s)
                 obs.counter("engine.decode_steps").inc()
                 obs.counter("engine.decode_rows_live").inc(len(states))
+                if self.prefix_cache:
+                    # pages the paged-decode kernel reads: each live row's
+                    # KV, the token this step wrote included
+                    bs, lens = self.pool.block_size, self.pool.lengths
+                    obs.counter("engine.decode_kv_pages").inc(
+                        sum(-(-(lens[s] + 1) // bs) for s in states))
                 obs.counter("engine.tokens_generated").inc(len(states))
                 obs.histogram("engine.decode_step_s").observe(dsp.dur_s)
             for slot in list(states):

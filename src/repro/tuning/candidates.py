@@ -310,15 +310,16 @@ def paged_blocktable_candidates(seq_max: int, head_dim: int, group: int = 1,
                                 max_candidates: int | None = None
                                 ) -> List[Tuple[int, int]]:
     """(block_size, block_kv) pairs worth timing for the block-table decode
-    kernel — the paging granule and the kv tile are swept *jointly*.
+    kernel — the paging granule and the chunk are swept *jointly*.
 
     block_size candidates are power-of-two multiples of the sublane granule
     that divide seq_max (so a full sequence is a whole number of blocks and
     the pool capacity proof num_blocks = rows * seq_max/block_size holds);
-    block_kv must divide block_size (a kv tile never straddles a physical
-    block) and fit the streaming VMEM budget at block_q = group.  Larger
-    pairs first: fewer grid steps usually win, but small blocks buy sharing
-    granularity — that tension is exactly what the measurement decides.
+    block_kv is the kernel's chunk in tokens, a power-of-two number of
+    whole blocks up to seq_max, within the streaming VMEM budget at
+    block_q = group.  Larger pairs first: longer chunks amortise each loop
+    step, but small blocks buy sharing granularity — that tension is
+    exactly what the measurement decides.
     """
     hw = hw or get_hardware()
     sub = sublane_granule(hw, dtype_bytes)
@@ -327,9 +328,8 @@ def paged_blocktable_candidates(seq_max: int, head_dim: int, group: int = 1,
     cands = [
         (bs, bkv)
         for bs in sizes
-        for bkv in _steps(bs, sub, cap=bs)
-        if bs % bkv == 0
-        and flash_vmem_bytes(group, bkv, head_dim, dtype_bytes)
+        for bkv in _steps(seq_max, bs, cap=min(MAX_BLOCK, seq_max))
+        if flash_vmem_bytes(group, bkv, head_dim, dtype_bytes)
         <= hw.sram_bytes
     ]
     cands.sort(key=lambda c: (-c[0], -c[1]))

@@ -390,7 +390,9 @@ def autotune_paged_decode_blocktable(batch: int, num_rows: int, s_max: int,
                                      max_candidates: Optional[int] = None,
                                      verbose: bool = False) -> TunedConfig:
     """Jointly sweep (block_size, block_kv) for the block-table decode kernel
-    over a pool sized for `num_rows` sequences of up to `s_max` tokens.
+    over a pool sized for `num_rows` sequences of up to `s_max` tokens:
+    block_size is the physical block (page), block_kv the chunk of whole
+    pages the kernel gathers and scores per step of its per-row loop.
 
     Each block_size candidate implies its own pool geometry — num_blocks =
     num_rows * s_max/block_size physical blocks of block_size tokens — so the

@@ -448,3 +448,28 @@ class TestEngineLoopTracing:
             sum(eng.policy.prompt_bucket(n) for n in ran)
         assert counters["engine.decode_rows_live"] == sum(live)
         assert counters["engine.decode_steps"] == len(live)
+
+    def test_decode_kv_pages_counts_the_live_pages(self, smoke_lm):
+        """`engine.decode_kv_pages` is the pages the paged-decode kernel
+        reads: ceil(kv length / block_size) over the live rows of each
+        decode call, the kv length counting the token the call writes."""
+        eng = _tiny_engine(smoke_lm, prefix_cache=True)
+        reqs = _shared_prefix_requests(8, vocab=smoke_lm[0].vocab_size)
+        decode, pages = eng._decode, []
+
+        def recording_decode(*args):
+            pos = np.asarray(args[3])           # dead rows sit at 0
+            pages.append(sum(-(-(int(n) + 1) // eng.pool.block_size)
+                             for n in pos if n > 0))
+            return decode(*args)
+
+        eng._decode = recording_decode
+        obs.enable(annotate_device=False)
+        done, stats = eng.run(reqs)
+        assert all(c.ok for c in done) and stats.preemptions == 0
+        counters = obs.get_metrics().snapshot()["counters"]
+        assert len(pages) == stats.decode_steps
+        assert counters["engine.decode_kv_pages"] == sum(pages)
+        # prompts of 12-28 tokens in blocks of 8: most live rows span pages
+        assert counters["engine.decode_kv_pages"] > \
+            counters["engine.decode_rows_live"]

@@ -4,7 +4,8 @@ Locks down the block-table KV pool (kv_pool.BlockPool / PagedPool) and the
 prefix-cache engine path end to end:
 
   * block-table decode kernel vs the jnp oracle (permuted tables, dead rows,
-    block_kv sweep, non-divisor clamp);
+    chunk-length sweep, lengths at page and chunk edges, pages past each
+    row's live prefix never read);
   * the BlockPool host state machine — prefix-hit sharing, full-hit COW,
     LRU eviction, exhaustion rollback — plus a seeded random driver with
     shadow block contents that asserts COW never lets one sequence observe
@@ -40,8 +41,8 @@ def smoke_lm():
 
 class TestBlockTableKernel:
     """Pallas block-table decode vs the jnp oracle: physical indirection,
-    shared blocks across rows, dead rows, kv blocking that does and doesn't
-    divide the physical block size."""
+    shared blocks across rows, dead rows, chunk lengths (`block_kv`, in
+    tokens, rounded up to whole blocks) shorter and longer than a block."""
 
     def _inputs(self, b=5, nb=12, bs=16, nkv=2, g=3, d=32):
         q = jax.random.normal(KEY, (b, nkv * g, d)) * 0.5
@@ -61,12 +62,53 @@ class TestBlockTableKernel:
     @pytest.mark.parametrize("block_kv", [None, 8, 16, 12])
     def test_vs_ref(self, block_kv):
         q, kp, vp, tables, lengths = self._inputs()
-        # block_kv=12 doesn't divide bs=16: the wrapper clamps to gcd
+        # 8, 12 and 16 round up to a chunk of one block of 16; None is the
+        # default chunk, capped at the table's 4 blocks
         got = paged_decode_blocktable(q, kp, vp, tables, lengths,
                                       block_kv=block_kv, interpret=True)
         want = paged_decode_blocktable_ref(q, kp, vp, tables, lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=3e-5, rtol=3e-5)
+
+    # a chunk of 64 tokens is 4 blocks of 16; the table holds 12 (192 tokens)
+    @pytest.mark.parametrize("length,poison", [
+        (1, False), (16, False), (64, False), (65, False), (192, False),
+        (65, True)], ids=["one_token", "one_block", "one_chunk",
+                          "chunk_plus_one", "whole_table", "nan_past_live"])
+    def test_lengths_vs_ref(self, length, poison):
+        """Row 0 at `length`; row 1 at the same length shares row 0's first
+        block; row 2 is dead; row 3 spans two chunks.  With `poison`, every
+        block outside the rows' live prefixes -- the garbage block among
+        them -- holds NaN: the output stays finite, so no such block is
+        read."""
+        nb, bs, nkv, g, d, max_blocks = 48, 16, 2, 3, 32, 12
+        garbage = nb - 1
+        q = jax.random.normal(KEY, (4, nkv * g, d)) * 0.5
+        kp = jax.random.normal(jax.random.fold_in(KEY, 1),
+                               (nb, bs, nkv, d)) * 0.5
+        vp = jax.random.normal(jax.random.fold_in(KEY, 2),
+                               (nb, bs, nkv, d)) * 0.5
+        ids = np.random.default_rng(3).permutation(garbage)
+        tables = np.stack([ids[:12],
+                           np.concatenate([ids[:1], ids[12:23]]),
+                           np.full(12, garbage),
+                           ids[23:35]]).astype(np.int32)
+        lengths = np.asarray([length, length, 0, 100], np.int32)
+        want = paged_decode_blocktable_ref(q, kp, vp, jnp.asarray(tables),
+                                           jnp.asarray(lengths))
+        if poison:
+            live = {int(tables[r, j]) for r in range(4)
+                    for j in range(-(-int(lengths[r]) // bs))}
+            dead = np.asarray([i for i in range(nb) if i not in live])
+            kp = kp.at[dead].set(jnp.nan)
+            vp = vp.at[dead].set(jnp.nan)
+        got = np.asarray(paged_decode_blocktable(
+            q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
+            block_kv=64, interpret=True))
+        assert np.isfinite(got).all()
+        assert np.all(got[2] == 0.0)
+        np.testing.assert_allclose(got, np.asarray(want), atol=3e-5,
+                                   rtol=3e-5)
 
     def test_dead_row_is_zero(self):
         q, kp, vp, tables, lengths = self._inputs()

@@ -115,8 +115,14 @@ class TestQuantizedPagedDecode:
                                    atol=3e-5, rtol=3e-5)
         assert np.all(np.asarray(got)[1] == 0.0)  # dead slot stays zero
 
-    @pytest.mark.parametrize("use_pallas", [True, False])
-    def test_blocktable_variant_vs_dequantized_ref(self, use_pallas):
+    # block_kv=64 is a chunk of two blocks: length 100 crosses a chunk
+    # boundary and 128 fills two chunks; None takes the whole table at once
+    @pytest.mark.parametrize("use_pallas,block_kv", [
+        pytest.param(True, None, id="True"),
+        pytest.param(False, None, id="False"),
+        pytest.param(True, 64, id="True-chunk_boundary")])
+    def test_blocktable_variant_vs_dequantized_ref(self, use_pallas,
+                                                   block_kv):
         nb, bs, nkv, d, b, max_blocks = 12, 32, 2, 32, 3, 4
         kq, ks, vq, vs, kd, vd = _quant_pools(nb, bs, nkv, d)
         q = jax.random.normal(jax.random.fold_in(KEY, 3), (b, nkv * 2, d))
@@ -124,8 +130,8 @@ class TestQuantizedPagedDecode:
                              jnp.int32)
         lengths = jnp.asarray([100, 20, 128], jnp.int32)
         got = paged_decode_blocktable(q, kq, vq, tables, lengths, k_scale=ks,
-                                      v_scale=vs, interpret=True,
-                                      use_pallas=use_pallas)
+                                      v_scale=vs, block_kv=block_kv,
+                                      interpret=True, use_pallas=use_pallas)
         want = paged_decode_blocktable_ref(q, kd, vd, tables, lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=3e-5, rtol=3e-5)

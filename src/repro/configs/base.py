@@ -30,6 +30,16 @@ class ModelConfig:
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm
     pos_emb: str = "rotary"  # rotary | learned | none
     rope_theta: float = 10000.0
+    # YaRN context extension of the rotary frequencies (deepseek-v3's
+    # rope_scaling); factor 0 = plain rotary.  models.layers.yarn_inv_freq
+    # gives the frequencies, yarn_softmax_scale the mscale^2 score factor.
+    rope_yarn_factor: float = 0.0
+    rope_yarn_orig_len: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-5
     tie_embeddings: bool = False
 
     # attention variant ----------------------------------------------------
@@ -81,8 +91,24 @@ class ModelConfig:
     moe_every: int = 1  # MoE layer every k-th layer (llama4: 2)
     first_dense_layers: int = 0  # deepseek-v3: first 3 layers dense
     moe_capacity_factor: float = 1.25
+    # router: "softmax" = softmax top-k renormalised; "sigmoid" = deepseek-v3
+    # (sigmoid scores, a selection-only bias, group-limited top-k over
+    # `router_groups` groups keeping `router_topk_groups`, weights
+    # normalised over the chosen k and scaled by `routed_scale`)
+    router_score: str = "softmax"
+    router_groups: int = 1
+    router_topk_groups: int = 1
+    routed_scale: float = 1.0
+    # expert share: this device holds routed experts [first_held_expert,
+    # first_held_expert + experts_held) of num_experts (0 = all of them).
+    # The router still scores all num_experts; the layer computes the part
+    # of the output its held experts give, plus the shared expert.
+    experts_held: int = 0
+    first_held_expert: int = 0
     # "auto" = XLA-chosen collectives (models/moe.py);
-    # "shard_map" = explicit EP schedule: local dispatch + one psum combine
+    # "shard_map" = explicit EP schedule: local dispatch + one psum combine;
+    # "dropless" = no capacity: the rows routed to the held experts go
+    # through one grouped matrix product (the serving engine's choice)
     moe_dispatch: str = "auto"
 
     # SSM / Mamba2 ----------------------------------------------------------
@@ -149,6 +175,10 @@ class ModelConfig:
         if layer < self.first_dense_layers:
             return False
         return (layer - self.first_dense_layers) % self.moe_every == 0
+
+    @property
+    def num_held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def num_moe_layers(self) -> int:
@@ -237,7 +267,7 @@ class ModelConfig:
             return fam_mix
         fam_attn = self._attn_params()
         if self.is_moe_layer(layer):
-            e = self.num_experts * self._mlp_params(self.moe_d_ff)
+            e = self.num_held_experts * self._mlp_params(self.moe_d_ff)
             e += self.num_shared_experts * self._mlp_params(self.moe_d_ff)
             e += h * self.num_experts  # router
             return fam_attn + e
@@ -249,7 +279,8 @@ class ModelConfig:
             return self.param_count()
         n = self.param_count()
         per_expert = self._mlp_params(self.moe_d_ff)
-        inactive = self.num_moe_layers * (self.num_experts - self.top_k) * per_expert
+        inactive = self.num_moe_layers * max(self.num_held_experts - self.top_k, 0) \
+            * per_expert
         return n - inactive
 
 

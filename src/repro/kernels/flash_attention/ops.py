@@ -35,9 +35,9 @@ from ..backend import interpret_mode
 from .backward import flash_attention_bwd_pallas
 from .kernel import flash_attention_pallas
 from .paged import (CHUNK_TOKENS, paged_decode_blocktable_pallas,
-                    paged_decode_pallas)
+                    paged_decode_pallas, paged_mla_decode_pallas)
 from .ref import (attention_ref, paged_decode_blocktable_ref,
-                  paged_decode_ref)
+                  paged_decode_ref, paged_mla_decode_ref)
 
 
 def _fold(x):
@@ -336,3 +336,50 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
                          k_scale, v_scale, block_kv=block_kv,
                          interpret=interpret_mode(interpret),
                          use_pallas=use_pallas)
+
+
+@functools.partial(jax.jit, static_argnames=("v_dim", "scale", "block_kv",
+                                             "interpret", "use_pallas"))
+def _paged_mla_jit(q, latent_blocks, block_tables, lengths, layer, *,
+                   v_dim: int, scale: float, block_kv: Optional[int],
+                   interpret: bool, use_pallas: bool):
+    if not use_pallas:
+        return paged_mla_decode_ref(q, latent_blocks[layer].astype(q.dtype),
+                                    block_tables, lengths, v_dim=v_dim,
+                                    scale=scale)
+    return paged_mla_decode_pallas(q, latent_blocks.astype(q.dtype),
+                                   block_tables, lengths, layer, v_dim=v_dim,
+                                   scale=scale, block_kv=block_kv,
+                                   interpret=interpret)
+
+
+def paged_mla_decode(q, latent_blocks, block_tables, lengths, *, v_dim: int,
+                     scale: float, block_kv: Optional[int] = None,
+                     interpret: Optional[bool] = None,
+                     use_pallas: bool = True):
+    """Absorbed MLA decode attention over a latent block pool.
+
+    q: (b, a, c) one latent-space query per head and row (c = kv_lora +
+    rope, padded to whole lanes); latent_blocks: (num_blocks, block_size,
+    c), or `models.layers.LayerOf` a stack of layers' pools (read in
+    place); block_tables: (b, max_blocks); lengths: (b,) live entries (0 =
+    dead row -> zeros).  Returns (b, a, v_dim), the heads' outputs in the
+    latent space.  The Pallas kernel (`paged.paged_mla_decode_pallas`)
+    reads each row's live pages only; block_kv is its chunk in tokens,
+    rounded up to whole pages.
+    """
+    from ...models.layers import LayerOf
+    stack, layer = ((latent_blocks.stack, latent_blocks.index)
+                    if isinstance(latent_blocks, LayerOf)
+                    else (latent_blocks[None], 0))
+    if obs.enabled():
+        obs.record_dispatch(
+            "paged_mla_decode", impl="pallas" if use_pallas else "jnp",
+            shape=q.shape,
+            blocks={"block_kv": block_kv or CHUNK_TOKENS,
+                    "block_size": stack.shape[2]} if use_pallas else None)
+    return _paged_mla_jit(q, stack, block_tables, lengths,
+                          jnp.asarray(layer, jnp.int32), v_dim=v_dim,
+                          scale=float(scale), block_kv=block_kv,
+                          interpret=interpret_mode(interpret),
+                          use_pallas=use_pallas)

@@ -43,6 +43,11 @@ heads (see `_paged_bt_kernel`), masked by head and by `lengths[b]`, with
 the online softmax in f32.  An int8 pool's scale pages travel with their
 K/V pages.  The tuner sweeps the page size jointly with the chunk length
 (`tuning.search.autotune_paged_decode_blocktable`).
+
+`paged_mla_decode_pallas` is the MLA twin over a latent block pool: the
+same page loop (`_live_chunks`), one pool of (block_size, kv_lora + rope)
+pages, and all query heads of a row in one (a, chunk) score tile against
+the one latent head.
 """
 from __future__ import annotations
 
@@ -190,6 +195,96 @@ def blocktable_chunk_pages(block_size: int, max_blocks: int,
     return max(1, min(want, max_blocks, fit))
 
 
+def _live_chunks(table_ref, pools_hbm, bufs, sem, *, row, length: int,
+                 pages: int, block_size: int, body, init):
+    """The page loop the block-table kernels share: run `body(i, slot,
+    carry)` for each chunk i of `pages` pages of `row`'s live prefix, with
+    the chunk's pages of every pool already gathered into `bufs[n][slot]`.
+
+    One async copy per live page and pool, double-buffered: chunk i + 1's
+    copies start before chunk i is computed; pages past the live prefix are
+    never copied, and a dead row (length 0) runs no iteration.  A pool is
+    either a (num_blocks, rows, d) page pool, whose page lands as `rows`
+    rows of its (2, pages * rows, d) buffer, or a (num_blocks, rows) row of
+    per-token scales, landing as row p of its (2, pages, rows) buffer."""
+    from jax.experimental.pallas import tpu as pltpu
+    n_pages = pl.cdiv(length, block_size)
+    n_chunks = pl.cdiv(length, pages * block_size)
+
+    @pl.when(row == 0)
+    def _zero_buffers():
+        # pages past a row's live prefix are never copied in, so the tail of
+        # a last chunk holds what an earlier chunk left (live pages, finite)
+        # or these zeros -- never uninitialised memory, which a probability
+        # of 0 would still turn into NaN in p @ v
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+
+    def copies(i, slot, op):
+        # a slot's copies of one pool all signal one semaphore, and each
+        # wait takes one page's bytes
+        for p in range(pages):
+            j = i * pages + p
+
+            @pl.when(j < n_pages)
+            def _copy():
+                page = table_ref[row, j]
+                for n, (src, buf) in enumerate(zip(pools_hbm, bufs)):
+                    if len(src.shape) == 3:     # page rows
+                        r = src.shape[1]
+                        src, dst = src.at[page], buf.at[slot, pl.ds(p * r, r)]
+                    else:                       # one row of scales
+                        src, dst = src.at[pl.ds(page, 1)], buf.at[
+                            slot, pl.ds(p, 1)]
+                    getattr(pltpu.make_async_copy(src, dst, sem.at[n, slot]),
+                            op)()
+
+    @pl.when(n_chunks > 0)
+    def _prefetch_first():
+        copies(0, 0, "start")
+
+    def step(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_chunks)
+        def _prefetch_next():
+            copies(i + 1, 1 - slot, "start")
+
+        copies(i, slot, "wait")
+        return body(i, slot, carry)
+
+    return jax.lax.fori_loop(0, n_chunks, step, init)
+
+
+def _online_softmax(s, carry, v, pv_scale=None):
+    """One chunk of the online softmax: scores s (rows, cols) f32, values v
+    (cols, dv).  carry is (m, l, acc); pv_scale multiplies the
+    probabilities before p @ v (int8 value scales)."""
+    m_prev, l_prev, acc_prev = carry
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    if pv_scale is not None:
+        p = p * pv_scale
+    acc_new = acc_prev * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _softmax_init(rows: int, dv: int):
+    return (jnp.full((rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, dv), jnp.float32))
+
+
+def _finish(o_ref, carry):
+    _, l, acc = carry
+    l = jnp.where(l == 0.0, 1.0, l)  # dead row -> zero output
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
 def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
                      pages: int, block_size: int, quant: bool, scale: float):
     # refs: the HBM pools (k, v[, k_scale, v_scale]), the output, the VMEM
@@ -201,44 +296,8 @@ def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
     sem = refs[2 * n_pools + 1]
     nkv = num_kv_heads
     chunk = pages * block_size
-    page_rows = block_size * nkv          # a page is (block_size * nkv, d)
     b = pl.program_id(0)
     length = len_ref[b]
-    n_pages = pl.cdiv(length, block_size)
-    n_chunks = pl.cdiv(length, chunk)
-
-    @pl.when(b == 0)
-    def _zero_buffers():
-        # pages past a row's live prefix are never copied in, so the tail of
-        # a last chunk holds what an earlier chunk left (live pages, finite)
-        # or these zeros -- never uninitialised memory, which a probability
-        # of 0 would still turn into NaN in p @ v
-        for buf in bufs:
-            buf[...] = jnp.zeros_like(buf)
-
-    def copies(i, slot, op):
-        # one DMA per live page and pool; a slot's copies of one pool all
-        # signal one semaphore, and each wait takes one page's bytes
-        from jax.experimental.pallas import tpu as pltpu
-        for p in range(pages):
-            j = i * pages + p
-
-            @pl.when(j < n_pages)
-            def _copy():
-                page = table_ref[b, j]
-                for n, (src, buf) in enumerate(zip(pools_hbm, bufs)):
-                    if n < 2:       # K/V page: rows [p, p+1) * page_rows
-                        src, dst = src.at[page], buf.at[
-                            slot, pl.ds(p * page_rows, page_rows)]
-                    else:           # scale page: one row of page_rows
-                        src, dst = src.at[pl.ds(page, 1)], buf.at[
-                            slot, pl.ds(p, 1)]
-                    getattr(pltpu.make_async_copy(src, dst, sem.at[n, slot]),
-                            op)()
-
-    @pl.when(n_chunks > 0)
-    def _prefetch_first():
-        copies(0, 0, "start")
 
     # All query heads score the whole chunk at once: column c of the
     # (a, chunk * nkv) score tile is token c // nkv of kv head c % nkv, and
@@ -259,15 +318,7 @@ def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
         return jnp.concatenate([buf[slot, p:p + 1, :] for p in range(pages)],
                                axis=1)
 
-    def body(i, carry):
-        m_prev, l_prev, acc_prev = carry
-        slot = i % 2
-
-        @pl.when(i + 1 < n_chunks)
-        def _prefetch_next():
-            copies(i + 1, 1 - slot, "start")
-
-        copies(i, slot, "wait")
+    def body(i, slot, carry):
         k = bufs[0][slot]                                # (chunk * nkv, d)
         v = bufs[1][slot].astype(jnp.float32)
         # q . k in the pair's common dtype (int8 keys as q's dtype, exact):
@@ -279,23 +330,12 @@ def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
         if quant:
             s = s * scale_row(bufs[2], slot)
         s = jnp.where(head_ok & (col_tok < length - i * chunk), s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        if quant:
-            p = p * scale_row(bufs[3], slot)
-        acc_new = acc_prev * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        return _online_softmax(s, carry, v,
+                               scale_row(bufs[3], slot) if quant else None)
 
-    init = (jnp.full((a, 1), NEG_INF, jnp.float32),
-            jnp.zeros((a, 1), jnp.float32),
-            jnp.zeros(q.shape, jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, init)
-    l = jnp.where(l == 0.0, 1.0, l)  # dead row -> zero output
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    _finish(o_ref, _live_chunks(
+        table_ref, pools_hbm, bufs, sem, row=b, length=length, pages=pages,
+        block_size=block_size, body=body, init=_softmax_init(a, q.shape[1])))
 
 
 def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
@@ -361,3 +401,84 @@ def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, a, d), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *pools)
+
+
+def _paged_mla_kernel(table_ref, len_ref, layer_ref, q_ref, pool_hbm, o_ref,
+                      buf, sem, *, pages: int, block_size: int, v_dim: int,
+                      scale: float):
+    # every query head of the row against one latent "head": the page is
+    # (block_size, c), c = kv_lora + rope padded to whole lanes; v is its
+    # first v_dim columns
+    chunk = pages * block_size
+    b = pl.program_id(0)
+    length = len_ref[b]
+    q = q_ref[0]                                         # (a, c)
+    a = q.shape[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (a, chunk), 1)
+
+    def body(i, slot, carry):
+        kv = buf[slot]                                   # (chunk, c)
+        s = jax.lax.dot_general(q, kv.astype(q.dtype),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(col < length - i * chunk, s, NEG_INF)
+        return _online_softmax(s, carry, kv[:, :v_dim].astype(q.dtype))
+
+    _finish(o_ref, _live_chunks(
+        table_ref, (pool_hbm.at[layer_ref[0]],), (buf,), sem, row=b,
+        length=length,
+        pages=pages, block_size=block_size, body=body,
+        init=_softmax_init(a, v_dim)))
+
+
+def paged_mla_decode_pallas(q: jax.Array, latent_blocks: jax.Array,
+                            block_tables: jax.Array, lengths: jax.Array,
+                            layer: jax.Array | int = 0, *,
+                            v_dim: int, scale: float,
+                            block_kv: int | None = None,
+                            interpret: bool = False) -> jax.Array:
+    """MLA decode in the absorbed form over a latent block pool.
+
+    q: (b, a, c) one token per row, each head's query already taken into
+    the latent space (q_nope W_uk ++ q_rope ++ zeros); latent_blocks:
+    (num_blocks, block_size, c) pages of normalised c_kv ++ roped k_rope ++
+    zeros, c a multiple of 128 (a page is then whole lane tiles, which the
+    page copies need), or (layers, num_blocks, block_size, c) with `layer`
+    naming the one to read (the pool is read in place); block_tables: (b,
+    max_blocks) int32; lengths: (b,).  Scores are q . latent for every head
+    at once (one (a, chunk) tile), and the output is p . latent[:, :v_dim],
+    still in the latent space (the caller applies W_uv).  The page loop is the GQA kernel's
+    (`_live_chunks`): one grid step per row, its live pages only.
+    Returns (b, a, v_dim); rows with length 0 return zeros.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+    b, a, c = q.shape
+    if latent_blocks.ndim == 3:
+        latent_blocks = latent_blocks[None]
+    _, nb, block_size, ck = latent_blocks.shape
+    bt_rows, max_blocks = block_tables.shape
+    assert c == ck and bt_rows == b and v_dim <= c
+    page_bytes = block_size * c * latent_blocks.dtype.itemsize
+    pages = blocktable_chunk_pages(block_size, max_blocks, page_bytes,
+                                   block_kv)
+    row_spec = pl.BlockSpec((1, a, c),
+                            lambda bi, table, lens, layer: (bi, 0, 0))
+    out_spec = pl.BlockSpec((1, a, v_dim),
+                            lambda bi, table, lens, layer: (bi, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=out_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * block_size, c), latent_blocks.dtype),
+            pltpu.SemaphoreType.DMA((1, 2))],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_mla_kernel, pages=pages,
+                          block_size=block_size, v_dim=v_dim, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, a, v_dim), q.dtype),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, latent_blocks)

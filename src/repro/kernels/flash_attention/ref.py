@@ -61,3 +61,27 @@ def paged_decode_blocktable_ref(q, k_blocks, v_blocks, block_tables, lengths,
     v = gather_block_kv(v_blocks, block_tables)
     return paged_decode_ref(q, k, v, jnp.arange(b, dtype=jnp.int32), lengths,
                             scale=scale)
+
+
+def mla_decode_ref(q, latent, lengths, *, v_dim: int, scale: float):
+    """Absorbed MLA decode over contiguous per-row latents.  q: (b, a, c)
+    latent-space queries; latent: (b, s, c); lengths: (b,) live entries
+    (0 = dead row, returns zeros).  Returns (b, a, v_dim): p . latent[...,
+    :v_dim] per head."""
+    s = jnp.einsum("bac,bsc->bas", q.astype(jnp.float32),
+                   latent.astype(jnp.float32)) * scale
+    live = jnp.arange(latent.shape[1])[None, :] < lengths[:, None]
+    s = jnp.where(live[:, None, :], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bas,bsc->bac", w,
+                     latent[..., :v_dim].astype(jnp.float32))
+    out = jnp.where((lengths > 0)[:, None, None], out, 0.0)
+    return out.astype(q.dtype)
+
+
+def paged_mla_decode_ref(q, latent_blocks, block_tables, lengths, *,
+                         v_dim: int, scale: float):
+    """Oracle for the MLA block-table kernel: gather each row's latent
+    pages into the logical layout, then `mla_decode_ref`."""
+    return mla_decode_ref(q, gather_block_kv(latent_blocks, block_tables),
+                          lengths, v_dim=v_dim, scale=scale)

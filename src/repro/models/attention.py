@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .layers import apply_rotary, dense_init
+from .layers import (LayerOf, apply_rotary, dense_init, norm_apply, norm_init,
+                     rope_inv_freq, rope_magnitude, yarn_softmax_scale)
 from .linear import linear, resolve_impl
 
 NEG_INF = -1e30
@@ -258,59 +259,189 @@ def init_mla(key, cfg: ModelConfig):
     ks = jax.random.split(key, 6)
     return {
         "wq_down": dense_init(ks[0], h, qr),
+        "q_norm": norm_init(qr),
         "wq_up": dense_init(ks[1], qr, a * (nope + rope)),
         "wkv_down": dense_init(ks[2], h, kvr + rope),
+        "kv_norm": norm_init(kvr),
         "wk_up": dense_init(ks[3], kvr, a * nope),
         "wv_up": dense_init(ks[4], kvr, a * vd),
         "wo": dense_init(ks[5], a * vd, h, scale=1.0 / (2 * cfg.num_layers) ** 0.5),
     }
 
 
-def apply_mla(p, x, cfg: ModelConfig, *, positions, cache=None, cache_index=None):
-    """MLA with a latent-KV cache.  cache: dict(latent=(b, s_max, kvr+rope)).
+def mla_cache_width(cfg: ModelConfig) -> int:
+    """Values cached per token: c_kv ++ k_rope, zero-padded to whole
+    128-lane tiles (576 -> 640 for deepseek-v3).  The device stores a
+    576-wide minor dim padded to 640 anyway; naming the pad lets the decode
+    kernel copy whole pages, which a 576-wide slice of the padded pool is
+    not."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // 128) * 128
 
-    Train/prefill: decompressed path (naive).  The latent (c_kv ++ k_rope) is
-    what gets cached; decode recomputes k/v from the cached latent (the
-    weight-absorbed schedule is an optimization we model in core/, the
-    computation here is mathematically identical).
-    """
-    b, s, h = x.shape
-    a = cfg.num_heads
-    nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    kvr = cfg.kv_lora_rank
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk_nope + qk_rope), times YaRN's mscale^2 where it applies."""
+    return yarn_softmax_scale(cfg) / (cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5
+
+
+def _mla_rope(x, positions, cfg: ModelConfig):
+    return apply_rotary(x, positions, cfg.rope_theta,
+                        inv_freq=rope_inv_freq(cfg, cfg.qk_rope_dim),
+                        magnitude=rope_magnitude(cfg))
+
+
+def _mla_latent_q(p, x, cfg: ModelConfig, positions):
+    """Queries (q_nope (b, s, a, nope), roped q_rope (b, s, a, rope)) and
+    the latent to cache, normalised c_kv ++ roped k_rope ++ zeros
+    (b, s, `mla_cache_width`)."""
+    b, s, _ = x.shape
+    a, nope, kvr = cfg.num_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
     impl = resolve_impl(cfg)
-    q = linear(linear(x, p["wq_down"], impl=impl), p["wq_up"], impl=impl)
-    q = q.reshape(b, s, a, nope + rope)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rotary(q_rope, positions, cfg.rope_theta)
+    cq = norm_apply(p["q_norm"], linear(x, p["wq_down"], impl=impl),
+                    eps=cfg.norm_eps)
+    q = linear(cq, p["wq_up"], impl=impl).reshape(b, s, a, -1)
+    q_nope, q_rope = q[..., :nope], _mla_rope(q[..., nope:], positions, cfg)
+    latent = linear(x, p["wkv_down"], impl=impl)
+    c_kv = norm_apply(p["kv_norm"], latent[..., :kvr], eps=cfg.norm_eps)
+    k_rope = _mla_rope(latent[..., None, kvr:], positions, cfg)[..., 0, :]
+    pad = jnp.zeros(k_rope.shape[:-1] + (mla_cache_width(cfg) - kvr
+                                         - cfg.qk_rope_dim,), k_rope.dtype)
+    return q_nope, q_rope, jnp.concatenate([c_kv, k_rope, pad], axis=-1)
 
-    latent = linear(x, p["wkv_down"], impl=impl)  # (b, s, kvr+rope)
-    c_kv, k_rope_flat = latent[..., :kvr], latent[..., kvr:]
-    k_rope = apply_rotary(k_rope_flat[..., None, :], positions, cfg.rope_theta)
 
-    kv_len = None
-    new_cache = None
-    if cache is not None:
-        lat_all = jnp.concatenate([c_kv, k_rope[..., 0, :]], axis=-1)
-        stored = jax.lax.dynamic_update_slice_in_dim(
-            cache["latent"], lat_all.astype(cache["latent"].dtype), cache_index, axis=1)
-        new_cache = {"latent": stored}
-        c_kv = stored[..., :kvr].astype(x.dtype)
-        k_rope = stored[..., None, kvr:].astype(x.dtype)
-        kv_len = cache_index + s
+def _mla_out(p, o, cfg: ModelConfig):
+    """Heads' outputs (b, s, a, v_head) -> (b, s, h) through wo."""
+    b, s = o.shape[:2]
+    return linear(o.reshape(b, s, -1), p["wo"], impl=resolve_impl(cfg))
 
-    skv = c_kv.shape[1]
+
+def _mla_decode(p, q_nope, q_rope, cfg: ModelConfig, *, latent=None,
+                pool=None, block_tables=None, lengths):
+    """One-token decode in the absorbed form: the cached latent is never
+    decompressed.  q_lat = q_nope W_uk per head (kv_lora wide), scores
+    [q_lat; q_rope] . [c_kv; k_rope], out_lat = p . c_kv, o = out_lat W_uv.
+    Keys come from contiguous per-row `latent` (b, S, c) or from a latent
+    block `pool` through `block_tables` (the Pallas kernel when
+    cfg.attn_impl == "paged")."""
+    from ..kernels.flash_attention.ref import mla_decode_ref
+    a, nope, kvr = cfg.num_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    dt = q_nope.dtype
+    wk = p["wk_up"].astype(dt).reshape(kvr, a, nope)
+    q_lat = jnp.einsum("ban,can->bac", q_nope[:, 0], wk)
+    pad = jnp.zeros(q_lat.shape[:2] + (mla_cache_width(cfg) - kvr
+                                       - cfg.qk_rope_dim,), dt)
+    q = jnp.concatenate([q_lat, q_rope[:, 0], pad], axis=-1)   # (b, a, c)
+    scale = mla_softmax_scale(cfg)
+    if block_tables is None:
+        out_lat = mla_decode_ref(q, latent.astype(dt), lengths, v_dim=kvr,
+                                 scale=scale)
+    else:
+        from ..kernels.flash_attention.ops import paged_mla_decode
+        out_lat = paged_mla_decode(q, pool, block_tables, lengths, v_dim=kvr,
+                                   scale=scale,
+                                   use_pallas=cfg.attn_impl == "paged")
+    wv = p["wv_up"].astype(dt).reshape(kvr, a, cfg.v_head_dim)
+    return _mla_out(p, jnp.einsum("bac,cav->bav", out_lat, wv)[:, None], cfg)
+
+
+def _query_chunk(s: int, a: int, skv: int) -> int:
+    """Queries per chunk of the prefill attention: the largest divisor of s
+    whose (a, chunk, skv) f32 score tile stays within 32M elements."""
+    want = max(1, (1 << 25) // max(a * skv, 1))
+    return max(c for c in range(1, min(s, want) + 1) if s % c == 0)
+
+
+def _mla_prefill(p, q_nope, q_rope, lat, cfg: ModelConfig, *, q_pos,
+                 kv_len=None):
+    """Decompressed attention for prefill and training: k_nope = c_kv W_uk,
+    v = c_kv W_uv over every key, the queries taken a chunk at a time so
+    no (heads, s, s_kv) score tensor is built.  lat: (b, S, c) keys;
+    q_pos: (s,) query positions (causal against key positions); kv_len:
+    live keys (None = all)."""
+    b, s, a, nope = q_nope.shape
+    skv, kvr = lat.shape[1], cfg.kv_lora_rank
+    impl = resolve_impl(cfg)
+    dt = q_nope.dtype
+    c_kv = lat[..., :kvr].astype(dt)
+    k_rope = lat[..., kvr:kvr + cfg.qk_rope_dim].astype(dt)
     k_nope = linear(c_kv, p["wk_up"], impl=impl).reshape(b, skv, a, nope)
-    v = linear(c_kv, p["wv_up"], impl=impl).reshape(b, skv, a, vd)
+    v = linear(c_kv, p["wv_up"], impl=impl).reshape(b, skv, a, -1)
+    scale = mla_softmax_scale(cfg)
+    kv_pos = jnp.arange(skv)
+    live = kv_pos < (skv if kv_len is None else kv_len)
+    c = _query_chunk(s, a, skv)
 
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k_full = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, skv, a, rope))], axis=-1)
-    out = _sdpa(q_full, k_full, v, causal=True,
-                q_pos=positions[0] if positions.ndim > 1 else positions,
-                kv_len=kv_len,
-                seq_sharded=(cache is not None and s == 1))
-    out = linear(out.reshape(b, s, a * vd), p["wo"], impl=impl)
-    return out, new_cache
+    def chunk(args):
+        qn, qr, qp = args                       # (b, c, a, *), (c,)
+        sc = (jnp.einsum("bqan,bkan->baqk", qn, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqar,bkr->baqk", qr, k_rope,
+                           preferred_element_type=jnp.float32)) * scale
+        ok = (kv_pos[None, :] <= qp[:, None]) & live[None, :]
+        w = jax.nn.softmax(jnp.where(ok, sc, NEG_INF), axis=-1)
+        return jnp.einsum("baqk,bkav->bqav", w.astype(dt), v)
+
+    def split(x):
+        return jnp.moveaxis(x.reshape(b, s // c, c, *x.shape[2:]), 1, 0)
+
+    out = jax.lax.map(chunk, (split(q_nope), split(q_rope),
+                              q_pos.reshape(s // c, c)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, a, -1)
+
+
+def apply_mla(p, x, cfg: ModelConfig, *, positions, cache=None,
+              cache_index=None, block_tables=None):
+    """MLA with a latent cache of normalised c_kv ++ roped k_rope per token
+    (zero-padded to `mla_cache_width`).
+
+    cache: dict(latent=(b, s_max, width)) with a scalar cache_index (a
+    write at [ci, ci+s)), or a (b,) cache_index for one-token decode with
+    per-row write positions; with block_tables the cache is a latent block
+    pool (num_blocks, block_size, width) and row b's logical block j is
+    physical block block_tables[b, j].  One-token decode runs the absorbed
+    form (`_mla_decode`); prefill and training the decompressed form with
+    query chunks (`_mla_prefill`).
+    """
+    b, s, _ = x.shape
+    q_nope, q_rope, lat = _mla_latent_q(p, x, cfg, positions)
+    if cache is None:
+        q_pos = positions if positions.ndim == 1 else positions[0]
+        return _mla_out(p, _mla_prefill(p, q_nope, q_rope, lat, cfg,
+                                        q_pos=q_pos), cfg), None
+    pool = cache["latent"]
+    ci = jnp.asarray(cache_index)
+    if block_tables is not None:
+        assert s == 1 and ci.ndim == 1, \
+            "block_tables requires single-token decode with vector cache_index"
+        # the pool may be `LayerOf` the stack of every layer's pool: the
+        # token is written in place and the kernel reads the layer in place
+        stack, layer = (pool if isinstance(pool, LayerOf)
+                        else LayerOf(pool[None], 0))
+        blk = stack.shape[2]
+        phys = block_tables[jnp.arange(b), ci // blk]
+        stack = stack.at[layer, phys, ci % blk].set(
+            lat[:, 0].astype(stack.dtype))
+        pool = LayerOf(stack, layer) if isinstance(pool, LayerOf) \
+            else stack[0]
+        out = _mla_decode(p, q_nope, q_rope, cfg, pool=pool,
+                          block_tables=block_tables,
+                          lengths=(ci + 1).astype(jnp.int32))
+        return out, {"latent": pool}
+    if ci.ndim:  # per-row write positions (serving-engine slot pool)
+        assert s == 1, "vector cache_index requires single-token decode"
+        write = (jnp.arange(pool.shape[1]) == ci[:, None])[..., None]
+        pool = jnp.where(write, lat.astype(pool.dtype), pool)
+    else:
+        pool = jax.lax.dynamic_update_slice_in_dim(
+            pool, lat.astype(pool.dtype), ci, axis=1)
+    if s == 1:
+        lengths = jnp.broadcast_to(ci + 1, (b,)).astype(jnp.int32)
+        out = _mla_decode(p, q_nope, q_rope, cfg, latent=pool,
+                          lengths=lengths)
+    else:
+        q_pos = positions if positions.ndim == 1 else positions[0]
+        out = _mla_out(p, _mla_prefill(p, q_nope, q_rope, pool, cfg,
+                                       q_pos=q_pos, kv_len=ci + s), cfg)
+    return out, {"latent": pool}
 
 
 def init_attention(key, cfg: ModelConfig):
@@ -321,7 +452,5 @@ def apply_attention(p, x, cfg: ModelConfig, **kw):
     if cfg.attn_type == "mla":
         kw.pop("kv_input", None)
         kw.pop("causal", None)
-        assert kw.pop("block_tables", None) is None, \
-            "block-table KV paging is not supported for MLA"
         return apply_mla(p, x, cfg, **kw)
     return apply_gqa(p, x, cfg, **kw)

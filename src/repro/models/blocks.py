@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import apply_attention, init_attention
-from .layers import norm_apply, norm_init
+from .attention import apply_attention, init_attention, mla_cache_width
+from .layers import LayerOf, norm_apply, norm_init
 from .mlp import apply_mlp, init_mlp
-from .moe import apply_moe, init_moe
+from .moe import (EXPERT_KEYS, apply_moe, apply_moe_dropless, init_moe,
+                  merge_moe_stats, no_moe_stats)
 from .ssm import apply_ssm, decode_ssm, init_ssm, init_ssm_cache
 
 
@@ -119,7 +120,7 @@ def kv_cache_dtype(cfg: ModelConfig, dtype):
 
 def _kv_cache_shape(cfg: ModelConfig, batch: int, s_max: int):
     if cfg.attn_type == "mla":
-        return {"latent": (batch, s_max, cfg.kv_lora_rank + cfg.qk_rope_dim)}
+        return {"latent": (batch, s_max, mla_cache_width(cfg))}
     return {"k": (batch, s_max, cfg.num_kv_heads, cfg.head_dim),
             "v": (batch, s_max, cfg.num_kv_heads, cfg.head_dim)}
 
@@ -158,7 +159,7 @@ def init_cache_segment(cfg: ModelConfig, kind: str, n: int, batch: int,
 def _apply_attn_block(p, x, cfg, positions, cache, cache_index,
                       block_tables=None):
     h, new_cache = apply_attention(
-        p["attn"], norm_apply(p["norm1"], x, cfg.norm_type), cfg,
+        p["attn"], norm_apply(p["norm1"], x, cfg.norm_type, cfg.norm_eps), cfg,
         positions=positions, cache=cache, cache_index=cache_index,
         block_tables=block_tables)
     return h, new_cache
@@ -166,9 +167,13 @@ def _apply_attn_block(p, x, cfg, positions, cache, cache_index,
 
 def _apply_core(p, x, cfg: ModelConfig, kind: str, *, positions,
                 cache=None, cache_index=None, shared=None, decode=False,
-                block_tables=None):
-    """One layer.  Returns (x, new_cache, aux_loss)."""
+                block_tables=None, token_mask=None):
+    """One layer.  Returns (x, new_cache, aux_loss, moe_stats); moe_stats
+    (`moe.MOE_STATS` int32) is counted by the drop-free MoE path, zeros
+    elsewhere.  token_mask (b, s): rows that hold a token (drop-free MoE
+    routes no other row)."""
     aux = jnp.zeros((), jnp.float32)
+    stats = no_moe_stats()
     if kind in ("dense", "moe"):
         if cfg.seq_parallel and x.shape[1] > 1:
             from ..parallel.sharding import constrain
@@ -177,35 +182,40 @@ def _apply_core(p, x, cfg: ModelConfig, kind: str, *, positions,
                                                 cache_index, block_tables)
         if cfg.parallel_layers:
             # y = x + Attn(N(x)) + MLP(N(x))   (§VI-C1; same first norm)
-            mix_in = norm_apply(p["norm1"], x, cfg.norm_type)
+            mix_in = norm_apply(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
         else:
             x = x + attn_out
-            mix_in = norm_apply(p["norm2"], x, cfg.norm_type)
+            mix_in = norm_apply(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
         if kind == "moe":
             if cfg.moe_dispatch == "shard_map":
                 from .moe_shardmap import apply_moe_shardmap
                 mix_out, aux = apply_moe_shardmap(p["moe"], mix_in, cfg)
+            elif cfg.moe_dispatch == "dropless":
+                mix_out, stats = apply_moe_dropless(p["moe"], mix_in, cfg,
+                                                    token_mask)
             else:
                 mix_out, aux = apply_moe(p["moe"], mix_in, cfg)
         else:
             mix_out = apply_mlp(p["mlp"], mix_in, cfg)
         x = x + mix_out + (attn_out if cfg.parallel_layers else 0)
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     if kind == "pair":
-        x, c1, a1 = _apply_core(p["moe_blk"], x, cfg, "moe", positions=positions,
-                                cache=None if cache is None else cache["moe_blk"],
-                                cache_index=cache_index, decode=decode,
-                                block_tables=block_tables)
-        x, c2, a2 = _apply_core(p["dense_blk"], x, cfg, "dense", positions=positions,
-                                cache=None if cache is None else cache["dense_blk"],
-                                cache_index=cache_index, decode=decode,
-                                block_tables=block_tables)
+        x, c1, a1, stats = _apply_core(
+            p["moe_blk"], x, cfg, "moe", positions=positions,
+            cache=None if cache is None else cache["moe_blk"],
+            cache_index=cache_index, decode=decode,
+            block_tables=block_tables, token_mask=token_mask)
+        x, c2, a2, _ = _apply_core(
+            p["dense_blk"], x, cfg, "dense", positions=positions,
+            cache=None if cache is None else cache["dense_blk"],
+            cache_index=cache_index, decode=decode,
+            block_tables=block_tables)
         nc = None if cache is None else {"moe_blk": c1, "dense_blk": c2}
-        return x, nc, a1 + a2
+        return x, nc, a1 + a2, stats
 
     if kind == "ssm":
-        xin = norm_apply(p["norm1"], x, cfg.norm_type)
+        xin = norm_apply(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
         if decode:
             y, new_c = decode_ssm(p["ssm"], xin, cfg, cache)
         else:
@@ -214,7 +224,7 @@ def _apply_core(p, x, cfg: ModelConfig, kind: str, *, positions,
             new_c = None if cache is None else jax.tree.map(
                 lambda old, new: new.astype(old.dtype),
                 cache, {"state": st, **tails})
-        return x + y, new_c, aux
+        return x + y, new_c, aux, stats
 
     if kind == "hybrid_super":
         assert block_tables is None, \
@@ -224,7 +234,7 @@ def _apply_core(p, x, cfg: ModelConfig, kind: str, *, positions,
         for i in range(k):
             pi = jax.tree.map(lambda t: t[i], p["layers"])
             ci = None if cache is None else jax.tree.map(lambda t: t[i], cache["ssm"])
-            xin = norm_apply(pi["norm1"], x, cfg.norm_type)
+            xin = norm_apply(pi["norm1"], x, cfg.norm_type, cfg.norm_eps)
             if decode:
                 y, nc = decode_ssm(pi["ssm"], xin, cfg, ci)
             else:
@@ -239,15 +249,17 @@ def _apply_core(p, x, cfg: ModelConfig, kind: str, *, positions,
         # shared attention+MLP block (weights tied across all applications)
         sc = None if cache is None else cache["shared_attn"]
         attn_out, new_kv = apply_attention(
-            shared["attn"], norm_apply(shared["norm"], x, cfg.norm_type), cfg,
+            shared["attn"],
+            norm_apply(shared["norm"], x, cfg.norm_type, cfg.norm_eps), cfg,
             positions=positions, cache=sc, cache_index=cache_index)
         x = x + attn_out
-        x = x + apply_mlp(shared["mlp"],
-                          norm_apply(shared["norm2"], x, cfg.norm_type), cfg)
+        x = x + apply_mlp(
+            shared["mlp"],
+            norm_apply(shared["norm2"], x, cfg.norm_type, cfg.norm_eps), cfg)
         if cache is None:
-            return x, None, aux
+            return x, None, aux, stats
         stacked_ssm = jax.tree.map(lambda *ts: jnp.stack(ts), *new_ssm)
-        return x, {"ssm": stacked_ssm, "shared_attn": new_kv}, aux
+        return x, {"ssm": stacked_ssm, "shared_attn": new_kv}, aux, stats
 
     raise ValueError(kind)
 
@@ -256,28 +268,49 @@ def _apply_core(p, x, cfg: ModelConfig, kind: str, *, positions,
 
 def apply_stack(segments_params, cfg: ModelConfig, x, *, positions,
                 caches=None, cache_index=None, decode=False, shared=None,
-                remat: str = "none", block_tables=None):
+                remat: str = "none", block_tables=None, token_mask=None,
+                return_stats: bool = False):
     """Run all segments.  segments_params: list of (kind, stacked_params).
 
     caches: list aligned with segments (or None).
     block_tables: (b, max_blocks) block-pool indirection, shared by every
     layer (scan-closure captured — all layers' kv leaves use one table).
-    Returns (x, new_caches, total_aux).
+    token_mask: (b, s) rows holding a token (drop-free MoE).
+    Returns (x, new_caches, total_aux), and the layers' merged MoE stats
+    last with return_stats.
     """
     total_aux = jnp.zeros((), jnp.float32)
+    stats = no_moe_stats()
     new_caches = [] if caches is not None else None
+    # the MLA latent block pool rides whole in the scan's carry, and the
+    # drop-free MoE layers' expert weights stay whole outside it: their
+    # kernels read layer i in place (`LayerOf`), where the scan's slices
+    # would copy each layer's pool and weights out and the pool back in
+    carry_pool = block_tables is not None and cfg.attn_type == "mla"
 
     for si, (kind, sp) in enumerate(segments_params):
         seg_cache = None if caches is None else caches[si]
+        whole = {}
+        if kind == "moe" and cfg.moe_dispatch == "dropless":
+            whole = {k: v for k, v in sp["moe"].items() if k in EXPERT_KEYS}
+            sp = {**sp, "moe": {k: v for k, v in sp["moe"].items()
+                                if k not in EXPERT_KEYS}}
 
-        def body(carry, xs, _kind=kind):
-            h, aux = carry
-            p_l, c_l = xs
-            h, nc, a = _apply_core(p_l, h, cfg, _kind, positions=positions,
-                                   cache=c_l, cache_index=cache_index,
-                                   shared=shared, decode=decode,
-                                   block_tables=block_tables)
-            return (h, aux + a), nc
+        def body(carry, xs, _kind=kind, _whole=whole):
+            h, aux, st, pool = carry
+            p_l, c_l, i = xs
+            if _whole:
+                p_l = {**p_l, "moe": {**p_l["moe"], **{
+                    k: LayerOf(v, i) for k, v in _whole.items()}}}
+            if pool is not None:
+                c_l = {"latent": LayerOf(pool, i)}
+            h, nc, a, s_l = _apply_core(
+                p_l, h, cfg, _kind, positions=positions, cache=c_l,
+                cache_index=cache_index, shared=shared, decode=decode,
+                block_tables=block_tables, token_mask=token_mask)
+            if pool is not None:
+                pool, nc = nc["latent"].stack, None
+            return (h, aux + a, merge_moe_stats(st, s_l), pool), nc
 
         if remat == "full":
             body = jax.checkpoint(body, prevent_cse=False)
@@ -286,8 +319,14 @@ def apply_stack(segments_params, cfg: ModelConfig, x, *, positions,
                 body, prevent_cse=False,
                 policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
-        xs = (sp, seg_cache)
-        (x, total_aux), seg_new_cache = jax.lax.scan(body, (x, total_aux), xs)
+        n = jax.tree.leaves(sp)[0].shape[0]
+        pool = seg_cache["latent"] if carry_pool else None
+        xs = (sp, None if carry_pool else seg_cache, jnp.arange(n))
+        (x, total_aux, stats, pool), seg_new_cache = jax.lax.scan(
+            body, (x, total_aux, stats, pool), xs)
         if new_caches is not None:
-            new_caches.append(seg_new_cache)
+            new_caches.append({"latent": pool} if carry_pool
+                              else seg_new_cache)
+    if return_stats:
+        return x, new_caches, total_aux, stats
     return x, new_caches, total_aux

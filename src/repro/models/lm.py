@@ -83,17 +83,17 @@ def apply_encoder(params, frames, cfg: ModelConfig, remat: str = "none"):
 
     def body(carry, p_l):
         h, _ = carry
-        hn = norm_apply(p_l["norm1"], h, cfg.norm_type)
+        hn = norm_apply(p_l["norm1"], h, cfg.norm_type, cfg.norm_eps)
         a, _ = apply_attention(p_l["attn"], hn, cfg, positions=positions, causal=False)
         h = h + a
         from .mlp import apply_mlp
-        h = h + apply_mlp(p_l["mlp"], norm_apply(p_l["norm2"], h, cfg.norm_type), cfg)
+        h = h + apply_mlp(p_l["mlp"], norm_apply(p_l["norm2"], h, cfg.norm_type, cfg.norm_eps), cfg)
         return (h, jnp.zeros(())), None
 
     if remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
     (x, _), _ = jax.lax.scan(body, (x, jnp.zeros(())), enc["seg0"])
-    return norm_apply(enc["final_norm"], x, cfg.norm_type)
+    return norm_apply(enc["final_norm"], x, cfg.norm_type, cfg.norm_eps)
 
 
 # --- main forward --------------------------------------------------------------------
@@ -101,7 +101,8 @@ def apply_encoder(params, frames, cfg: ModelConfig, remat: str = "none"):
 def apply_lm(params, tokens, cfg: ModelConfig, *,
              positions=None, caches=None, cache_index=None, decode=False,
              remat: str = "none", patch_embeds=None, encoder_frames=None,
-             enc_out=None, return_hidden: bool = False, block_tables=None):
+             enc_out=None, return_hidden: bool = False, block_tables=None,
+             token_mask=None, return_stats: bool = False):
     """tokens: (b, s) int32.  Returns (logits, new_caches, aux, [hidden]).
 
     patch_embeds: (b, n_patches, h) VLM stub — prepended to the token stream.
@@ -110,6 +111,10 @@ def apply_lm(params, tokens, cfg: ModelConfig, *,
     block_tables: (b, max_blocks) — caches are a physical KV *block pool*
     (kv leaves (n, num_blocks, block_size, kv, hd)) and row b's logical
     block j lives at block_tables[b, j]; single-token decode only.
+    token_mask: (b, s) rows that hold a token; the drop-free MoE path
+    (cfg.moe_dispatch == "dropless") routes no other row.
+    return_stats: also return the MoE stats (`moe.MOE_STATS` int32,
+    zeros without drop-free MoE) after aux.
     """
     from ..parallel.sharding import constrain
     dt = compute_dtype(cfg.dtype)
@@ -138,17 +143,18 @@ def apply_lm(params, tokens, cfg: ModelConfig, *,
         enc_out = apply_encoder(params, encoder_frames, cfg, remat)
 
     segs = [(kind, params[f"seg{i}"]) for i, (kind, n) in enumerate(stack_plan(cfg))]
-    x, new_caches, aux = apply_stack(
+    x, new_caches, aux, stats = apply_stack(
         segs, cfg, x, positions=positions, caches=caches,
         cache_index=cache_index, decode=decode,
-        shared=params.get("shared"), remat=remat, block_tables=block_tables)
+        shared=params.get("shared"), remat=remat, block_tables=block_tables,
+        token_mask=token_mask, return_stats=True)
 
     # whisper cross-attention: applied as a post-pass per decoder layer would
     # interleave; for the stub we apply the stacked cross-attn blocks after the
     # self-attn stack (documented simplification — same GEMM inventory).
     if cfg.is_encoder_decoder:
         def xbody(h, p_l):
-            hn = norm_apply(p_l["norm"], h, cfg.norm_type)
+            hn = norm_apply(p_l["norm"], h, cfg.norm_type, cfg.norm_eps)
             a, _ = apply_attention(p_l["attn"], hn, cfg,
                                    positions=positions, causal=False,
                                    kv_input=enc_out)
@@ -156,16 +162,19 @@ def apply_lm(params, tokens, cfg: ModelConfig, *,
         x, _ = jax.lax.scan(xbody, x, params["xattn"])
 
     hidden = x
-    x = constrain(norm_apply(params["final_norm"], x, cfg.norm_type), "btd")
+    x = constrain(norm_apply(params["final_norm"], x, cfg.norm_type, cfg.norm_eps), "btd")
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = constrain(linear(x, head, impl=resolve_impl(cfg)), "btv")
     if cfg.padded_vocab_size != cfg.vocab_size:
         # mask the padded vocabulary tail (paper §VI-B vocab padding)
         pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
         logits = jnp.where(pad_mask, logits, jnp.asarray(-1e30, logits.dtype))
+    out = (logits, new_caches, aux)
+    if return_stats:
+        out += (stats,)
     if return_hidden:
-        return logits, new_caches, aux, hidden
-    return logits, new_caches, aux
+        out += (hidden,)
+    return out
 
 
 # --- loss ----------------------------------------------------------------------------
@@ -209,9 +218,9 @@ def lm_loss(params, batch, cfg: ModelConfig, remat: str = "none"):
         mtp_in = jnp.concatenate([hidden.astype(dt), emb_next], axis=-1)
         mtp_in = linear(mtp_in, params["mtp"]["proj"], impl=resolve_impl(cfg))
         pos = jnp.arange(mtp_in.shape[1])
-        h2, _, _ = _apply_core(params["mtp"]["block"], mtp_in, cfg, "dense",
-                               positions=pos)
-        h2 = norm_apply(params["mtp"]["norm"], h2, cfg.norm_type)
+        h2, _, _, _ = _apply_core(params["mtp"]["block"], mtp_in, cfg,
+                                  "dense", positions=pos)
+        h2 = norm_apply(params["mtp"]["norm"], h2, cfg.norm_type, cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
         mtp_logits = linear(h2, head, impl=resolve_impl(cfg))
         mtp = softmax_xent(mtp_logits[:, :-2], batch["labels"][:, 2:])

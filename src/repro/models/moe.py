@@ -10,6 +10,15 @@ Dispatch is sort-based (GShard-style but without the T×E×C one-hot): tokens
 are sorted by assigned expert, positioned within their expert's capacity
 window, and scattered into an (E, C, h) buffer.  Under EP sharding of the
 leading expert axis XLA lowers the scatter/gather to all-to-all traffic.
+
+`apply_moe_dropless` is the serving path: no capacity, so a token's output
+never depends on its batch neighbours.  The (token, expert) pairs routed to
+the held experts (all of them, or an expert share: `cfg.experts_held`) are
+sorted by expert into one grouped matrix product per weight
+(`kernels.grouped_matmul`), whose work follows the routed rows.
+
+Routers (`route`): softmax top-k renormalised, or deepseek-v3's sigmoid
+scores with a selection-only bias, group-limited top-k and a routed scale.
 """
 from __future__ import annotations
 
@@ -23,20 +32,61 @@ from .mlp import apply_mlp, init_mlp
 
 
 def init_moe(key, cfg: ModelConfig):
+    """Router over all `num_experts`; weights of the held experts only."""
     h, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    eh = cfg.num_held_experts
     ks = jax.random.split(key, 5)
     out_scale = 1.0 / (2 * cfg.num_layers) ** 0.5
     p = {
         "router": dense_init(ks[0], h, e, scale=0.5),
-        "w_up": jax.vmap(lambda k: dense_init(k, h, f))(jax.random.split(ks[1], e)),
+        "w_up": jax.vmap(lambda k: dense_init(k, h, f))(jax.random.split(ks[1], eh)),
         "w_down": jax.vmap(lambda k: dense_init(k, f, h, scale=out_scale))(
-            jax.random.split(ks[2], e)),
+            jax.random.split(ks[2], eh)),
     }
     if cfg.mlp_type == "swiglu":
-        p["w_gate"] = jax.vmap(lambda k: dense_init(k, h, f))(jax.random.split(ks[3], e))
+        p["w_gate"] = jax.vmap(lambda k: dense_init(k, h, f))(jax.random.split(ks[3], eh))
     if cfg.num_shared_experts:
         p["shared"] = init_mlp(ks[4], cfg, d_ff=cfg.moe_d_ff * cfg.num_shared_experts)
+    if cfg.router_score == "sigmoid":
+        # selection-only bias (deepseek-v3's e_score_correction_bias)
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
     return p
+
+
+def route(p, xt, cfg: ModelConfig):
+    """xt: (t, h) -> (weights (t, k) f32, expert ids (t, k) int32, probs
+    (t, e) f32 for the load-balance loss).  Experts are scored over all
+    `num_experts`, held or not."""
+    e, k = cfg.num_experts, cfg.top_k
+    if cfg.router_score == "softmax":
+        logits = linear(xt, p["router"], impl=resolve_impl(cfg)).astype(
+            jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, k)
+        gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+        return gate * cfg.routed_scale, idx, probs
+    if cfg.router_score != "sigmoid":
+        raise ValueError(f"unknown router_score {cfg.router_score!r}")
+    # deepseek-v3 scores its experts in float32: a bf16 logit moves the
+    # choice among near-equal experts
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    sel = scores + p["router_bias"].astype(jnp.float32)
+    g = cfg.router_groups
+    if g > 1:
+        t = sel.shape[0]
+        # a group's score is the sum of its two best experts; only the
+        # best `router_topk_groups` groups stay selectable
+        best2 = jax.lax.top_k(sel.reshape(t, g, e // g), 2)[0].sum(-1)
+        _, gidx = jax.lax.top_k(best2, cfg.router_topk_groups)
+        keep = jnp.zeros((t, g), bool).at[
+            jnp.arange(t)[:, None], gidx].set(True)
+        sel = jnp.where(jnp.repeat(keep, e // g, axis=1), sel, -jnp.inf)
+    _, idx = jax.lax.top_k(sel, k)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
+    gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
+    return gate * cfg.routed_scale, idx, scores / scores.sum(-1, keepdims=True)
 
 
 def _capacity(tokens: int, cfg: ModelConfig) -> int:
@@ -53,10 +103,11 @@ def apply_moe(p, x, cfg: ModelConfig):
     cap = _capacity(t, cfg)
     impl = resolve_impl(cfg)
 
-    logits = linear(xt, p["router"], impl=impl).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, k)  # (t, k)
-    gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)  # renormalize
+    if cfg.num_held_experts != e:
+        raise NotImplementedError(
+            "the capacity path holds every expert; an expert share "
+            "(experts_held) runs moe_dispatch='dropless'")
+    gate, idx, probs = route(p, xt, cfg)  # (t, k)
 
     # load-balance auxiliary loss (Switch/GShard form)
     frac_tokens = jnp.mean(jax.nn.one_hot(idx, e, dtype=jnp.float32), axis=(0, 1))
@@ -110,3 +161,66 @@ def apply_moe(p, x, cfg: ModelConfig):
     if cfg.num_shared_experts:
         y = y + apply_mlp(p["shared"], xt, cfg)
     return y.reshape(b, s, h), aux
+
+
+MOE_STATS = 4   # routed_held, routed_total, held_rows_max, held_active
+EXPERT_KEYS = ("w_gate", "w_up", "w_down")     # (held experts, in, out)
+
+
+def no_moe_stats():
+    return jnp.zeros((MOE_STATS,), jnp.int32)
+
+
+def merge_moe_stats(a, b):
+    """Stats of two layers (or steps) together: pair and expert counts
+    add, the largest expert's rows take the max."""
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]),
+                      a[3] + b[3]])
+
+
+def apply_moe_dropless(p, x, cfg: ModelConfig, token_mask=None):
+    """Drop-free MoE over the held experts.  x: (b, s, h); token_mask:
+    (b, s) bool, the rows that hold a token (dead decode rows and prefill
+    padding route nowhere).  The expert weights may be `LayerOf` a stack of
+    layers (the grouped kernel reads the layer in place).  Returns (y,
+    stats) with stats int32 (MOE_STATS,): (token, expert) pairs on the held
+    experts, pairs over all experts, and the most rows any held expert
+    got."""
+    from ..kernels.grouped_matmul.ops import grouped_matmul
+    b, s, h = x.shape
+    t, k = b * s, cfg.top_k
+    held, first = cfg.num_held_experts, cfg.first_held_expert
+    xt = x.reshape(t, h)
+    live = (jnp.ones((t,), bool) if token_mask is None
+            else token_mask.reshape(t))
+    with jax.named_scope("moe_router"):
+        gate, idx, _ = route(p, xt, cfg)
+    with jax.named_scope("moe_dispatch"):
+        local = idx - first
+        mine = (local >= 0) & (local < held) & live[:, None]
+        group = jnp.where(mine, local, held).reshape(-1)    # held = no expert
+        order = jnp.argsort(group, stable=True)
+        tok = order // k
+        sizes = jnp.bincount(group, length=held + 1).astype(jnp.int32)
+        rows = xt[tok]                                      # (t * k, h)
+    with jax.named_scope("moe_experts"):
+        if cfg.mlp_type == "swiglu":
+            hdn = jax.nn.silu(grouped_matmul(rows, p["w_gate"], sizes)) \
+                * grouped_matmul(rows, p["w_up"], sizes)
+        else:
+            hdn = jax.nn.gelu(grouped_matmul(rows, p["w_up"], sizes))
+        out = grouped_matmul(hdn, p["w_down"], sizes)
+    with jax.named_scope("moe_combine"):
+        # back to (token, choice) order; the weighted sum over a token's
+        # choices accumulates in f32.  The kernel never writes the rest
+        # group's rows, so they are masked, not weighted by zero
+        back = out[jnp.argsort(order)].reshape(t, k, h)
+        back = jnp.where(mine[..., None], back, jnp.zeros((), back.dtype))
+        y = jnp.einsum("tk,tkh->th", jnp.where(mine, gate, 0.0), back,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    if cfg.num_shared_experts:
+        y = y + apply_mlp(p["shared"], xt, cfg)
+    stats = jnp.stack([sizes[:held].sum(), live.sum() * k,
+                       sizes[:held].max(),
+                       (sizes[:held] > 0).sum()]).astype(jnp.int32)
+    return y.reshape(b, s, h), stats

@@ -28,27 +28,25 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from .linear import expert_fused_hidden, expert_linear, linear, resolve_impl
+from .linear import expert_fused_hidden, expert_linear, resolve_impl
 from .mlp import apply_mlp
+from .moe import route
 
 
 def _local_block(cfg: ModelConfig, tp_axis: str):
     e, k = cfg.num_experts, cfg.top_k
 
     def block(router, w_gate, w_up, w_down, xt):
-        """Per-shard block.  router: (h, E) replicated; w_*: (E_loc, h, f)
-        this shard's experts; xt: (t_loc, h) this data shard's tokens
-        (replicated over the model axis)."""
+        """Per-shard block.  router: {router (h, E)[, router_bias (E,)]}
+        replicated; w_*: (E_loc, h, f) this shard's experts; xt: (t_loc, h)
+        this data shard's tokens (replicated over the model axis)."""
         t_loc, h = xt.shape
         e_loc = w_up.shape[0]
         m = jax.lax.axis_index(tp_axis)
         lo = m * e_loc
         impl = resolve_impl(cfg)
 
-        logits = linear(xt, router, impl=impl).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, idx = jax.lax.top_k(probs, k)                  # (t_loc, k)
-        gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+        gate, idx, probs = route(router, xt, cfg)           # (t_loc, k)
 
         # load-balance aux (identical on every model shard: same tokens)
         frac_tokens = jnp.mean(jax.nn.one_hot(idx, e, dtype=jnp.float32),
@@ -112,9 +110,10 @@ def apply_moe_shardmap(p, x, cfg: ModelConfig):
     spec_exp = P(tp_axis, None, None)
     y, aux = jax.shard_map(
         block, mesh=mesh,
-        in_specs=(P(None, None), spec_exp, spec_exp, spec_exp, spec_tok),
+        in_specs=(P(), spec_exp, spec_exp, spec_exp, spec_tok),
         out_specs=(spec_tok, P()), check_vma=False,
-    )(p["router"], p.get("w_gate", p["w_up"]), p["w_up"], p["w_down"], xt)
+    )({k: p[k] for k in ("router", "router_bias") if k in p},
+      p.get("w_gate", p["w_up"]), p["w_up"], p["w_down"], xt)
 
     if cfg.num_shared_experts:
         y = y + apply_mlp(p["shared"], xt, cfg)

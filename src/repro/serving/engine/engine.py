@@ -58,51 +58,73 @@ def _check_supported(cfg: ModelConfig) -> None:
         raise NotImplementedError(
             f"engine v1 serves attention-based decoders (dense/moe); "
             f"got family={cfg.family!r}")
-    if cfg.attn_type != "gqa":
-        raise NotImplementedError("engine v1 requires attn_type='gqa' "
-                                  "(MLA latent caches: future work)")
+    if cfg.attn_type not in ("gqa", "mla"):
+        raise NotImplementedError(
+            f"engine v1 serves GQA and MLA attention; got "
+            f"attn_type={cfg.attn_type!r}")
     if cfg.pos_emb != "rotary":
         raise NotImplementedError("engine v1 requires rotary positions")
     if cfg.is_encoder_decoder or cfg.num_patches:
         raise NotImplementedError("engine v1 serves text-only decoders")
 
 
+# The programs' last argument and output is a device-resident count of the
+# drop-free MoE layers' routing (`models.moe.MOE_STATS` per program kind),
+# added to on every call and read once when `Engine.run` ends: no step
+# waits on it.
+MOE_COUNTERS = ("moe.routed_held", "moe.routed_total", "moe.held_rows_max",
+                "moe.held_active", "moe.prefill_routed_held",
+                "moe.prefill_routed_total", "moe.prefill_held_rows_max",
+                "moe.prefill_held_active")
+_DECODE, _PREFILL = slice(0, 4), slice(4, 8)
+
+
+def _prompt_mask(tokens, true_len):
+    return jnp.arange(tokens.shape[1])[None, :] < true_len
+
+
 def _make_prefill(cfg: ModelConfig, s_max: int):
-    """(params, tokens (1, bucket), true_len) -> (logits (1, v), caches).
+    """(params, tokens (1, bucket), true_len, counts) -> (logits (1, v),
+    caches, counts).
 
     Logits are gathered at the last *real* prompt position; cache entries
     past true_len hold pad garbage that per-slot lengths mask downstream.
     """
 
-    def prefill(params, tokens, true_len):
+    def prefill(params, tokens, true_len, counts):
         caches = init_caches(cfg, 1, s_max, compute_dtype(cfg.dtype))
-        logits, caches, _ = apply_lm(params, tokens, cfg, caches=caches,
-                                     cache_index=0)
+        logits, caches, _, st = apply_lm(
+            params, tokens, cfg, caches=caches, cache_index=0,
+            token_mask=_prompt_mask(tokens, true_len), return_stats=True)
         last = jax.lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=1)
-        return last[:, 0], caches
+        return last[:, 0], caches, counts.at[_PREFILL].add(st)
 
-    return jax.jit(prefill)
+    return jax.jit(prefill, donate_argnums=(3,))
 
 
 def _make_decode(cfg: ModelConfig):
-    """(params, tok (slots, 1), caches, pos (slots,)) -> (logits, caches).
+    """(params, tok (slots, 1), caches, pos (slots,), counts) -> (logits,
+    caches, counts).
 
-    pos is the per-slot write position (== live kv length); the KV pool is
-    donated so every step updates the cache buffers in place.
+    pos is the per-slot write position (== live kv length, 0 for a dead
+    slot); the KV pool is donated so every step updates the cache buffers
+    in place.
     """
 
-    def decode(params, tok, caches, pos):
-        logits, caches, _ = apply_lm(params, tok, cfg, caches=caches,
-                                     cache_index=pos, decode=True)
-        return logits[:, -1], caches
+    def decode(params, tok, caches, pos, counts):
+        logits, caches, _, st = apply_lm(
+            params, tok, cfg, caches=caches, cache_index=pos, decode=True,
+            token_mask=(pos > 0)[:, None], return_stats=True)
+        return logits[:, -1], caches, counts.at[_DECODE].add(st)
 
-    return jax.jit(decode, donate_argnums=(2,))
+    return jax.jit(decode, donate_argnums=(2, 4))
 
 
 def _make_prefix_prefill(cfg: ModelConfig):
     """Cache-backed suffix prefill for the paged engine.
 
-    (params, tokens (1, bucket), true_len, start, contig) -> (logits, contig)
+    (params, tokens (1, bucket), true_len, start, contig, counts)
+        -> (logits, contig, counts)
 
     `contig` is the row's gathered contiguous (1, seq_max) cache view:
     positions [0, start) hold live prefix-cache KV, and the suffix tokens are
@@ -111,26 +133,28 @@ def _make_prefix_prefill(cfg: ModelConfig):
     The view is donated (updated in place, then scattered back to blocks).
     """
 
-    def prefill(params, tokens, true_len, start, caches):
-        logits, caches, _ = apply_lm(params, tokens, cfg, caches=caches,
-                                     cache_index=start)
+    def prefill(params, tokens, true_len, start, caches, counts):
+        logits, caches, _, st = apply_lm(
+            params, tokens, cfg, caches=caches, cache_index=start,
+            token_mask=_prompt_mask(tokens, true_len), return_stats=True)
         last = jax.lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=1)
-        return last[:, 0], caches
+        return last[:, 0], caches, counts.at[_PREFILL].add(st)
 
-    return jax.jit(prefill, donate_argnums=(4,))
+    return jax.jit(prefill, donate_argnums=(4, 5))
 
 
 def _make_decode_bt(cfg: ModelConfig):
     """Block-table decode: like `_make_decode` but the caches are a physical
     block pool and each row's KV is gathered through (tables, pos)."""
 
-    def decode(params, tok, caches, pos, tables):
-        logits, caches, _ = apply_lm(params, tok, cfg, caches=caches,
-                                     cache_index=pos, decode=True,
-                                     block_tables=tables)
-        return logits[:, -1], caches
+    def decode(params, tok, caches, pos, tables, counts):
+        logits, caches, _, st = apply_lm(
+            params, tok, cfg, caches=caches, cache_index=pos, decode=True,
+            block_tables=tables, token_mask=(pos > 0)[:, None],
+            return_stats=True)
+        return logits[:, -1], caches, counts.at[_DECODE].add(st)
 
-    return jax.jit(decode, donate_argnums=(2,))
+    return jax.jit(decode, donate_argnums=(2, 5))
 
 
 def _make_sampler():
@@ -200,6 +224,10 @@ class Engine:
         _check_supported(cfg)
         if use_paged_kernel:
             cfg = dataclasses.replace(cfg, attn_impl="paged")
+        if cfg.num_experts:
+            # served tokens never depend on their batch neighbours: no
+            # capacity, no drops (training keeps the capacity path)
+            cfg = dataclasses.replace(cfg, moe_dispatch="dropless")
         from ...models.blocks import KV_DTYPES
         if kv_dtype not in KV_DTYPES:
             raise ValueError(
@@ -227,18 +255,19 @@ class Engine:
                                   num_blocks=num_blocks)
             # every admission is a cache-backed *suffix* prefill (a cold
             # prompt is a suffix at start=0); bucketed on the suffix length
-            pf = _make_prefix_prefill(cfg)
+            pf = self._counting(_make_prefix_prefill(cfg))
             self._prefills = {b: pf for b in self.policy.prompt_buckets}
-            self._decode = _make_decode_bt(cfg)
+            self._decode = self._counting(_make_decode_bt(cfg))
         else:
             assert num_blocks is None, \
                 "num_blocks applies to the prefix_cache (block-table) pool"
             self.pool = SlotPool(cfg, self.policy.num_slots,
                                  self.policy.seq_max,
                                  compute_dtype(cfg.dtype))
-            self._prefills = {b: _make_prefill(cfg, self.policy.seq_max)
-                              for b in self.policy.prompt_buckets}
-            self._decode = _make_decode(cfg)
+            self._prefills = {
+                b: self._counting(_make_prefill(cfg, self.policy.seq_max))
+                for b in self.policy.prompt_buckets}
+            self._decode = self._counting(_make_decode(cfg))
         self._sample = _make_sampler()
         # per-slot device-facing state (dead slots: token 0, temp 0)
         n = self.policy.num_slots
@@ -256,6 +285,27 @@ class Engine:
         self._admit_counter = 0
         self._queue: Optional[RequestQueue] = None
         self._faults = None
+        self._counts = jnp.zeros(len(MOE_COUNTERS), jnp.int32)
+        self.moe_counts: Dict[str, int] = {}    # the last run's, when MoE
+
+    def _counting(self, program):
+        """The program as the engine calls it, `(..) -> (logits, caches)`:
+        the routing counts it adds stay on the device (`MOE_COUNTERS`)."""
+        def call(*args):
+            logits, caches, self._counts = program(*args, self._counts)
+            return logits, caches
+        return call
+
+    def _publish_counts(self) -> None:
+        """Read the run's MoE routing counts (one readback, after the last
+        step's tokens were read) and add them to the obs counters."""
+        if self.cfg.moe_dispatch != "dropless":
+            return
+        self.moe_counts = dict(zip(MOE_COUNTERS,
+                                   np.asarray(self._counts).tolist()))
+        if obs.enabled():
+            for name, n in self.moe_counts.items():
+                obs.counter(name).inc(n)
 
     def _pick_block_size(self, hw: Hardware) -> int:
         """Physical KV block size: a tile-lattice choice, taken from the
@@ -649,6 +699,7 @@ class Engine:
         self._faults = faults
         if faults is not None:
             faults.reset()
+        self._counts = jnp.zeros(len(MOE_COUNTERS), jnp.int32)
         self._t0 = time.perf_counter()
         done: List[Completion] = []
         valid: List[Request] = []
@@ -709,6 +760,7 @@ class Engine:
         self._faults = None
         self._queue = None
         wall = self._now()
+        self._publish_counts()
         done.sort(key=lambda c: c.rid)
         return done, EngineStats.collect(done, wall,
                                          decode_steps=self.decode_steps,
@@ -765,9 +817,11 @@ class Engine:
                 obs.counter("engine.decode_rows_live").inc(len(states))
                 if self.prefix_cache:
                     # pages the paged-decode kernel reads: each live row's
-                    # KV, the token this step wrote included
+                    # KV (MLA: latent), the token this step wrote included
                     bs, lens = self.pool.block_size, self.pool.lengths
-                    obs.counter("engine.decode_kv_pages").inc(
+                    obs.counter("engine.decode_latent_pages"
+                                if self.cfg.attn_type == "mla"
+                                else "engine.decode_kv_pages").inc(
                         sum(-(-(lens[s] + 1) // bs) for s in states))
                 obs.counter("engine.tokens_generated").inc(len(states))
                 obs.histogram("engine.decode_step_s").observe(dsp.dur_s)

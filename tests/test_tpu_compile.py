@@ -1,6 +1,9 @@
 """Compile the main-path Pallas kernels for a described TPU v5e, at
 internlm2-1.8b widths (16 query heads, 8 KV heads, head_dim 128, d_model
-2048, d_ff 8192, bf16).
+2048, d_ff 8192, bf16), and the deepseek-v3-671b.ep32 serving cell's two
+kernels at its shapes (the paged MLA decode kernel over a 7-layer latent
+pool of 96 rows x 4096 tokens; the grouped expert product of 8 held
+experts, 7168 x 2048, at decode and prefill rows).
 
 No chip is attached: the TPU compiler runs against a `v5e:2x2` topology
 description and raises whatever the chip's compiler would raise (illegal
@@ -22,7 +25,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ops import (flash_attention, paged_decode,
-                                               paged_decode_blocktable)
+                                               paged_decode_blocktable,
+                                               paged_mla_decode)
+from repro.kernels.grouped_matmul.ops import grouped_matmul
 from repro.kernels.fused_mlp.ops import fused_mlp_hidden
 from repro.kernels.matmul.ops import matmul
 from repro.kernels.quantized.ops import int8_matmul
@@ -152,3 +157,38 @@ def test_int8_matmul(shape):
         return int8_matmul(a, w, interpret=False)
     assert "tpu_custom_call" in _compiled_text(
         fn, shape((2048, D_MODEL)), shape((D_MODEL, D_FF)))
+
+
+# deepseek-v3-671b.ep32
+V3_HEADS, V3_LATENT, V3_KV_LORA = 128, 640, 512
+V3_D, V3_EXPERT_FF, V3_HELD = 7168, 2048, 8
+
+
+def test_paged_mla_decode(shape):
+    from repro.models.layers import LayerOf
+    rows, block_size, max_blocks = 96, 16, 256
+    pool = shape((7, rows * max_blocks + 1, block_size, V3_LATENT))
+
+    def fn(q, pool, tables, lens, layer):
+        return paged_mla_decode(q, LayerOf(pool, layer), tables, lens,
+                                v_dim=V3_KV_LORA, scale=0.135,
+                                interpret=False)
+    text = _compiled_text(fn, shape((rows, V3_HEADS, V3_LATENT)), pool,
+                          shape((rows, max_blocks), jnp.int32),
+                          shape((rows,), jnp.int32), shape((), jnp.int32))
+    assert "tpu_custom_call" in text and "_paged_mla_jit" in text
+
+
+@pytest.mark.parametrize("rows", [96 * 8, 2048 * 8])
+def test_moe_grouped_matmul(shape, rows):
+    from repro.models.layers import LayerOf
+    up = shape((4, V3_HELD, V3_D, V3_EXPERT_FF))
+    down = shape((4, V3_HELD, V3_EXPERT_FF, V3_D))
+
+    def fn(x, wu, wd, sizes, layer):
+        h = grouped_matmul(x, LayerOf(wu, layer), sizes, interpret=False)
+        return grouped_matmul(h, LayerOf(wd, layer), sizes, interpret=False)
+    text = _compiled_text(fn, shape((rows, V3_D)), up, down,
+                          shape((V3_HELD + 1,), jnp.int32),
+                          shape((), jnp.int32))
+    assert text.count("tpu_custom_call") >= 2 and "_moe_gmm_jit" in text

@@ -2,7 +2,9 @@
 
 Pure JAX (no flax): every layer is an `init(key, ...) -> params` function plus
 an `apply(params, x, ...) -> y` function over dict pytrees.  Parameters are
-stored in float32 (master copy); compute runs in the config dtype.
+made in float32 (the training master copy); compute runs in the config
+dtype.  The serving engine keeps a copy whose GEMM weights are already in
+the compute dtype (`linear.cast_linear_params`).
 """
 from __future__ import annotations
 

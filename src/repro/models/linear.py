@@ -28,8 +28,10 @@ quantized path is inference-first: its backward falls back to the
 high-precision tuned matmul route (a straight-through estimator — the int8
 rounding is treated as identity for gradient purposes).
 
-Weight casting to the activation dtype happens here (params are f32 master
-copies), so call sites pass raw param leaves.
+Weight casting to the activation dtype happens here (training params are
+f32 master copies), so call sites pass raw param leaves; the serving engine
+hands over weights already in the compute dtype (`cast_linear_params`),
+where the cast is a no-op.
 """
 from __future__ import annotations
 
@@ -140,10 +142,13 @@ def _quantized_frozen_bwd(cfg, res, g):
 _quantized_linear_frozen.defvjp(_quantized_frozen_fwd, _quantized_frozen_bwd)
 
 
-# Param-leaf names that are (k, n) GEMM weights consumed through `linear()`.
-# Embeddings (indexed, and transposed for tied lm_heads), conv kernels, norm
-# gains, and 3-D expert stacks (quantized on the fly per expert) are NOT
-# here — quantizing them would break their non-GEMM consumers.
+# Param-leaf names of GEMM weights: every consumer casts them to the
+# activation dtype right before the product.  `quantize_linear_params` takes
+# the 2-D ones (consumed through `linear()`); `cast_linear_params` takes them
+# at any rank (layer-stacked weights and expert stacks too).  Embeddings
+# (indexed, and transposed for tied lm_heads), conv kernels, norm gains,
+# biases and routers are NOT here: their consumers read them in float32 or
+# outside a GEMM.
 QUANT_WEIGHT_KEYS = frozenset({
     "wq", "wk", "wv", "wo",                       # attention projections
     "wq_down", "wq_up", "wkv_down", "wk_up", "wv_up",  # MLA projections
@@ -153,6 +158,12 @@ QUANT_WEIGHT_KEYS = frozenset({
 })
 
 
+def _leaf_name(path):
+    """The innermost dict key on a pytree path (a param leaf's name)."""
+    return next((p.key for p in reversed(path)
+                 if isinstance(p, jax.tree_util.DictKey)), None)
+
+
 def quantize_linear_params(params, dtype: str = "int8"):
     """Quantize-once-at-load: replace every 2-D float GEMM weight leaf
     (matched by name, see `QUANT_WEIGHT_KEYS`) with a `QuantizedLinear`
@@ -160,13 +171,30 @@ def quantize_linear_params(params, dtype: str = "int8"):
     `linear(impl="quantized")` consumes the containers directly, skipping
     the per-call weight quantization; all other leaves pass through."""
     def one(path, leaf):
-        name = next((p.key for p in reversed(path)
-                     if isinstance(p, jax.tree_util.DictKey)), None)
-        if (name in QUANT_WEIGHT_KEYS and getattr(leaf, "ndim", 0) == 2
+        if (_leaf_name(path) in QUANT_WEIGHT_KEYS and getattr(leaf, "ndim", 0) == 2
                 and jnp.issubdtype(leaf.dtype, jnp.floating)):
             return quantize_weight(leaf, dtype)
         return leaf
     return jax.tree_util.tree_map_with_path(one, params)
+
+
+def cast_linear_params(params, dtype, *, tied: bool):
+    """The tree with its GEMM weights in `dtype`: every floating leaf named
+    in `QUANT_WEIGHT_KEYS`, at any rank, and `embed` when `tied` (it is then
+    the head, through `linear()`).  Every other leaf (norm gains, biases,
+    routers, an untied embedding, quantized containers) is returned as it
+    is, and so is a leaf already in `dtype`: nothing is copied.  Exact: the
+    products see the same operands as when they cast per call."""
+    def one(path, leaf):
+        name = _leaf_name(path)
+        gemm = name in QUANT_WEIGHT_KEYS or (tied and name == "embed")
+        if (gemm and not isinstance(leaf, QuantizedTensor)
+                and jnp.issubdtype(leaf.dtype, jnp.floating)
+                and leaf.dtype != dtype):
+            return leaf.astype(dtype)
+        return leaf
+    return jax.tree_util.tree_map_with_path(
+        one, params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
 
 
 def linear(x, w, *, impl: str = "jnp", hw_name: Optional[str] = None):

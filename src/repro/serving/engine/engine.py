@@ -47,6 +47,7 @@ from ...configs.base import ModelConfig
 from ...core.hardware import Hardware, running_hardware
 from ...models import apply_lm, init_caches
 from ...models.layers import compute_dtype
+from ...models.linear import cast_linear_params
 from .buckets import BucketPolicy, make_policy
 from .kv_pool import PagedPool, PoolExhausted, SlotPool
 from .request import Completion, EngineStats, Request
@@ -208,7 +209,11 @@ class _ResumeState:
 
 
 class Engine:
-    """Continuous-batching engine; see module docstring."""
+    """Continuous-batching engine; see module docstring.
+
+    The engine serves its own copy of `params`: the GEMM weights in the
+    compute dtype (`models.linear.cast_linear_params`), everything else as
+    given; `params_cast_bytes` counts the bytes of the leaves it cast."""
 
     def __init__(self, params, cfg: ModelConfig, *,
                  max_batch: int = 8, max_prompt: int = 64,
@@ -237,7 +242,14 @@ class Engine:
             # scale leaves; everything downstream (pools, prefill/decode
             # programs, paged kernels) keys off cfg.kv_dtype
             cfg = dataclasses.replace(cfg, kv_dtype=kv_dtype)
-        self.params = params
+        # the serving copy: GEMM weights in the compute dtype, cast once here
+        # rather than in every program call (the caller keeps its own tree)
+        self.params = cast_linear_params(params, compute_dtype(cfg.dtype),
+                                         tied=cfg.tie_embeddings)
+        self.params_cast_bytes = sum(
+            new.nbytes for new, old in zip(jax.tree.leaves(self.params),
+                                           jax.tree.leaves(params))
+            if new is not old)
         self.cfg = cfg
         hw = hw or running_hardware()
         self.hw = hw
@@ -761,6 +773,8 @@ class Engine:
         self._queue = None
         wall = self._now()
         self._publish_counts()
+        if obs.enabled():
+            obs.counter("engine.params_cast_bytes").inc(self.params_cast_bytes)
         done.sort(key=lambda c: c.rid)
         return done, EngineStats.collect(done, wall,
                                          decode_steps=self.decode_steps,

@@ -131,36 +131,40 @@ def _analytic_pricing(records):
 
 
 def _kv_decode_smoke(records):
-    from repro.kernels.flash_attention.ops import paged_decode
+    from repro.kernels.flash_attention.ops import paged_decode_blocktable
     from repro.quant import quantize_kv
 
-    slots, s_max, nkv, d, b = 8, 256, 2, 64, 4
+    rows, s_max, bs, nkv, d, b = 8, 256, 16, 2, 64, 4
+    max_blocks = s_max // bs
     key = jax.random.PRNGKey(2)
-    kp = jax.random.normal(key, (slots, s_max, nkv, d)) * 0.5
+    kp = jax.random.normal(key, (rows * max_blocks, bs, nkv, d)) * 0.5
     vp = jax.random.normal(jax.random.fold_in(key, 1),
-                           (slots, s_max, nkv, d)) * 0.5
+                           (rows * max_blocks, bs, nkv, d)) * 0.5
     q = jax.random.normal(jax.random.fold_in(key, 2), (b, nkv * 4, d))
-    idx = jnp.arange(b, dtype=jnp.int32)
+    # each row's table names its own run of consecutive blocks
+    tables = (jnp.arange(b, dtype=jnp.int32)[:, None] * max_blocks
+              + jnp.arange(max_blocks, dtype=jnp.int32)[None, :])
     lens = jnp.full((b,), s_max, jnp.int32)
     kq, ks = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
 
-    f32_us = wall_us(
-        lambda q: paged_decode(q, kp, vp, idx, lens, interpret=True),
-        q, iters=2, warmup=1, jit=False)
-    int8_us = wall_us(
-        lambda q: paged_decode(q, kq, vq, idx, lens, k_scale=ks, v_scale=vs,
-                               interpret=True),
-        q, iters=2, warmup=1, jit=False)
-    want = np.asarray(paged_decode(q, kp, vp, idx, lens, interpret=True))
-    got = np.asarray(paged_decode(q, kq, vq, idx, lens, k_scale=ks,
-                                  v_scale=vs, interpret=True))
+    def f32(q):
+        return paged_decode_blocktable(q, kp, vp, tables, lens,
+                                       interpret=True)
+
+    def int8(q):
+        return paged_decode_blocktable(q, kq, vq, tables, lens, k_scale=ks,
+                                       v_scale=vs, interpret=True)
+
+    f32_us = wall_us(f32, q, iters=2, warmup=1, jit=False)
+    int8_us = wall_us(int8, q, iters=2, warmup=1, jit=False)
+    want, got = np.asarray(f32(q)), np.asarray(int8(q))
     err = float(np.abs(got - want).max() / np.abs(want).max())
     records.append({"type": "kv_cpu", "f32_us": f32_us, "int8_us": int8_us,
-                    "rel_err": err, "slots": slots, "s_max": s_max,
-                    "nkv": nkv, "d": d})
+                    "rel_err": err, "slots": rows, "s_max": s_max,
+                    "nkv": nkv, "d": d, "block_size": bs})
     return [("quant_sweep/kv_decode_f32", round(f32_us, 1),
-             f"pool={slots}x{s_max}x{nkv}x{d}"),
+             f"pool={rows}x{s_max}x{nkv}x{d}/block{bs}"),
             ("quant_sweep/kv_decode_int8", round(int8_us, 1),
              f"rel_err={err:.4f}")]
 

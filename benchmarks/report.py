@@ -283,14 +283,13 @@ def serve_section(rows):
     """Serving-engine latency report: aggregate tok/s is not the whole
     story — per-request TTFT and inter-token percentiles are what a serving
     SLO is written against, so they ride alongside (p50/p99)."""
-    prefix_rows = [r for r in rows if "prefix_share" in r]
     rows = [r for r in rows if "pattern" in r]
     out = ["## §Serving", "",
            "Continuous-batching engine vs static batching "
            "(`benchmarks/serve_engine.py`, CPU smoke scale; both policies "
-           "share jitted programs + slot pool, only the scheduler differs — "
-           "see docs/serving-guide.md).  `steps` counts pool-wide decode "
-           "steps: static pays for dead slots riding to each batch max.", ""]
+           "share jitted programs + block pool, only the scheduler differs "
+           "— see docs/serving-guide.md).  `steps` counts pool-wide decode "
+           "steps: static pays for dead rows riding to each batch max.", ""]
     out.append("| pattern | policy | tok/s | TTFT p50 ms | TTFT p99 ms | "
                "ITL p50 ms | ITL p99 ms | decode steps |")
     out.append("|---|---|---|---|---|---|---|---|")
@@ -312,8 +311,6 @@ def serve_section(rows):
                    + ", ".join(f"{p} {g:.2f}x" for p, g in gains) + ".")
         out.append("")
     out += failure_class_lines(rows)
-    if prefix_rows:
-        out += prefix_cache_section(prefix_rows)
     return out
 
 
@@ -362,34 +359,6 @@ def overload_section(summary):
             f"| {r['load']:g}x | {r['num_ok']} | {r['num_shed']} | "
             f"{r['num_timeout']} | {r['goodput']:.2f} | "
             f"{r['ttft_ok_p99_s']*1e3:.1f} | {r['tok_s']:.1f} |")
-    out.append("")
-    return out
-
-
-def prefix_cache_section(rows):
-    """Prefix-cache (block-table pool) vs the slot pool on a shared-prefix
-    workload: hit rate + the TTFT split between cache-hit and cold requests
-    is the number a system-prompt deployment cares about."""
-    out = ["### Prefix cache (block-table pool vs slot pool)", "",
-           "Shared-prefix workload (`benchmarks/serve_engine.py "
-           "--prefix-share`); outputs asserted token-identical.  "
-           "`ttft hit speedup` is the median per-request TTFT improvement "
-           "of cache-hit requests vs the same requests on the slot pool.",
-           ""]
-    out.append("| engine | share | tok/s | hit rate | TTFT p50 ms "
-               "| TTFT hit p50 ms | TTFT cold p50 ms | ttft hit speedup |")
-    out.append("|---|---|---|---|---|---|---|---|")
-    def _ms(v):
-        # hit/cold splits are None when that request class is empty
-        return "n/a" if v is None else f"{v * 1e3:.1f}"
-
-    for r in rows:
-        out.append(
-            f"| {r['engine']} | {r['prefix_share']:.2f} | {r['tok_s']:.1f} "
-            f"| {r['cache_hit_rate']:.2f} | {r['ttft_p50_s']*1e3:.1f} "
-            f"| {_ms(r['ttft_hit_p50_s'])} "
-            f"| {_ms(r['ttft_cold_p50_s'])} "
-            f"| {r.get('ttft_hit_speedup', 0.0):.2f}x |")
     out.append("")
     return out
 

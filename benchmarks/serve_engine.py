@@ -1,21 +1,12 @@
-"""Continuous-batching engine vs static batching across arrival patterns,
-plus the prefix-cache (block-table) engine vs the slot pool on a
-shared-prefix workload.
+"""Continuous-batching engine vs static batching across arrival patterns.
 
 Both scheduling policies run through the SAME engine machinery (jitted
-programs, bucket policy, slot pool) — only the scheduler differs: continuous
-refills a slot the moment it frees; static waits for the whole pool to drain
-(the classic batch-serving baseline, and exactly what `launch/serve.py` did
-pre-engine).  The delta therefore isolates the scheduling policy: fewer
-pool-wide decode steps (no dead slots riding to the batch max) and no
-batch-boundary waiting.
-
-The prefix section replays a system-prompt workload (`--prefix-share` of
-requests open with a common prefix) through the slot engine and the
-block-table engine (prefix caching + copy-on-write sharing).  Outputs are
-asserted token-identical; the jsonl rows carry cache-hit rate and the TTFT
-split between cache-hit and cold requests.  `--prefix-json` persists the
-summary (BENCH_serve_prefix.json) the docs quote.
+programs, bucket policy, block-table pool) — only the scheduler differs:
+continuous refills a row the moment it frees; static waits for the whole
+pool to drain (the classic batch-serving baseline, and exactly what
+`launch/serve.py` did pre-engine).  The delta therefore isolates the
+scheduling policy: fewer pool-wide decode steps (no dead rows riding to the
+batch max) and no batch-boundary waiting.
 
 CPU smoke scale; deterministic workloads (`serving.engine.workload`), wall
 clock measured after a full compile warmup.  Emits the harness CSV rows and,
@@ -23,8 +14,7 @@ with --jsonl, per-run records `benchmarks.report` renders into the serving
 latency-percentile section.
 
     PYTHONPATH=src python -m benchmarks.run --only serve
-    PYTHONPATH=src python -m benchmarks.serve_engine --jsonl serve_engine.jsonl \
-        --prefix-json BENCH_serve_prefix.json
+    PYTHONPATH=src python -m benchmarks.serve_engine --jsonl serve_engine.jsonl
 """
 from __future__ import annotations
 
@@ -36,12 +26,6 @@ import jax
 NUM_REQUESTS = 16
 MAX_PROMPT = 48
 MAX_NEW = 24
-PREFIX_SHARE = 0.8
-# prefix section: long system prompt so prefill dominates TTFT (the regime
-# prefix caching targets); 16 full blocks at the default block size (16)
-PREFIX_MAX_PROMPT = 320
-PREFIX_MAX_NEW = 8
-SHARED_PREFIX_LEN = 256
 
 
 def _model():
@@ -52,11 +36,11 @@ def _model():
     return cfg, init_lm(jax.random.PRNGKey(0), cfg)
 
 
-def _engine(cfg, params, max_prompt=MAX_PROMPT, max_new=MAX_NEW, **kw):
+def _engine(cfg, params):
     from repro.serving.engine import Engine
 
-    eng = Engine(params, cfg, max_batch=8, max_prompt=max_prompt,
-                 max_new=max_new, **kw)
+    eng = Engine(params, cfg, max_batch=8, max_prompt=MAX_PROMPT,
+                 max_new=MAX_NEW)
     return eng, eng.calibrate_step_s()
 
 
@@ -86,93 +70,10 @@ def run_patterns(cfg, eng, step_s):
     return rows, records
 
 
-def run_prefix(cfg, params, *, prefix_share=PREFIX_SHARE,
-               num_requests=NUM_REQUESTS):
-    """Slot pool vs block-table prefix cache on a shared-prefix workload.
-
-    Long prompts (prefill-dominated TTFT — the regime prefix caching
-    targets), arrivals spaced so each request's TTFT measures its own
-    admission rather than queueing.  Returns (csv rows, jsonl records,
-    summary dict).  The paged engine runs the workload twice: cold (first
-    sharer populates the cache) and warm (every previously-seen prompt
-    hits — the steady serving state)."""
-    from repro.serving.engine import synthetic_requests
-
-    slot_eng, step_s = _engine(cfg, params, max_prompt=PREFIX_MAX_PROMPT,
-                               max_new=PREFIX_MAX_NEW)
-    paged_eng, _ = _engine(cfg, params, max_prompt=PREFIX_MAX_PROMPT,
-                           max_new=PREFIX_MAX_NEW, prefix_cache=True)
-    reqs = synthetic_requests(
-        num_requests, pattern="uniform", min_prompt=SHARED_PREFIX_LEN + 4,
-        max_prompt=PREFIX_MAX_PROMPT, min_new=4, max_new=PREFIX_MAX_NEW,
-        vocab=cfg.vocab_size, step_s=step_s, arrival_gap_steps=16,
-        prefix_share=prefix_share, shared_prefix_len=SHARED_PREFIX_LEN,
-        seed=23)
-
-    done_slot, stats_slot = slot_eng.run(reqs)
-    done_cold, stats_cold = paged_eng.run(reqs)
-    done_warm, stats_warm = paged_eng.run(reqs)
-    for a, b, c in zip(done_slot, done_cold, done_warm):
-        assert a.tokens == b.tokens == c.tokens, \
-            f"rid {a.rid}: prefix cache changed greedy tokens"
-    paged_eng.pool.blocks.check()
-
-    ttft_slot = {c.rid: c.ttft_s for c in done_slot}
-
-    def hit_ttft_speedup(done_paged):
-        """Median per-request TTFT improvement, cache-hit requests only,
-        each against the SAME request on the slot engine."""
-        import numpy as np
-        ratios = [ttft_slot[c.rid] / c.ttft_s for c in done_paged
-                  if c.cached_tokens > 0 and c.ttft_s > 0]
-        return float(np.median(ratios)) if ratios else 0.0
-
-    rows, records = [], []
-    for tag, stats, done in (("slot", stats_slot, done_slot),
-                             ("paged_cold", stats_cold, done_cold),
-                             ("paged_warm", stats_warm, done_warm)):
-        rows.append((f"serve_prefix/{tag}",
-                     f"{stats.ttft_p50_s*1e6:.0f}",
-                     f"{stats.tok_s:.1f}_tok_s_"
-                     f"{stats.cache_hit_rate:.2f}_hit_rate"))
-        records.append({"prefix_share": prefix_share, "engine": tag,
-                        "ttft_hit_speedup": hit_ttft_speedup(done),
-                        **stats.to_json()})
-    tok_speedup = stats_warm.tok_s / max(stats_slot.tok_s, 1e-9)
-    ttft_speedup = hit_ttft_speedup(done_warm)
-    rows.append(("serve_prefix/speedup", "0",
-                 f"{tok_speedup:.2f}x_tok_s_{ttft_speedup:.2f}x_ttft_hit"))
-    summary = {
-        "workload": {"num_requests": num_requests,
-                     "prefix_share": prefix_share,
-                     "shared_prefix_len": SHARED_PREFIX_LEN,
-                     "max_prompt": PREFIX_MAX_PROMPT,
-                     "max_new": PREFIX_MAX_NEW},
-        "block_size": paged_eng.pool.block_size,
-        "num_blocks": paged_eng.pool.blocks.num_blocks,
-        "slot": stats_slot.to_json(),
-        "paged_cold": stats_cold.to_json(),
-        "paged_warm": stats_warm.to_json(),
-        "tok_s_speedup_warm": tok_speedup,
-        "ttft_hit_speedup_cold": hit_ttft_speedup(done_cold),
-        "ttft_hit_speedup_warm": ttft_speedup,
-        "token_identical": True,
-    }
-    return rows, records, summary
-
-
-def run(jsonl_path=None, prefix_json=None, prefix_share=PREFIX_SHARE):
+def run(jsonl_path=None):
     cfg, params = _model()
     eng, step_s = _engine(cfg, params)
     rows, records = run_patterns(cfg, eng, step_s)
-    if prefix_share > 0.0:
-        prows, precs, summary = run_prefix(cfg, params,
-                                           prefix_share=prefix_share)
-        rows += prows
-        records += precs
-        if prefix_json:
-            with open(prefix_json, "w") as f:
-                json.dump(summary, f, indent=2, sort_keys=True)
     if jsonl_path:
         with open(jsonl_path, "w") as f:
             for r in records:
@@ -185,15 +86,8 @@ def main():
     ap.add_argument("--jsonl", default=None,
                     help="also write per-run stats records for "
                          "benchmarks.report --serve")
-    ap.add_argument("--prefix-share", type=float, default=PREFIX_SHARE,
-                    help="fraction of requests opening with the shared "
-                         "system prefix (0 disables the prefix section)")
-    ap.add_argument("--prefix-json", default=None,
-                    help="persist the prefix-cache summary "
-                         "(BENCH_serve_prefix.json)")
     args = ap.parse_args()
-    for name, us, derived in run(args.jsonl, args.prefix_json,
-                                 args.prefix_share):
+    for name, us, derived in run(args.jsonl):
         print(f"{name},{us},{derived}")
 
 

@@ -117,6 +117,7 @@ def rel_l2(a, b) -> float:
 
 def phase_serve(seed: int, kind: str) -> None:
     import jax
+    import jax.numpy as jnp
     import numpy as np
 
     from repro.configs.registry import get_config
@@ -128,8 +129,7 @@ def phase_serve(seed: int, kind: str) -> None:
     clock = Clock()
     params = jax.jit(init_lm, static_argnums=1)(jax.random.PRNGKey(seed), cfg)
     eng = Engine(params, cfg, max_batch=SERVE_SLOTS,
-                 max_prompt=SERVE_PROMPT[1], max_new=SERVE_GEN,
-                 use_paged_kernel=True, prefix_cache=True)
+                 max_prompt=SERVE_PROMPT[1], max_new=SERVE_GEN)
     pol = eng.policy
     step_s = eng.calibrate_step_s()     # warms every bucket + decode
     warm = {"wall_s": clock.wall_s(), "compile_s": clock.compile_s(),
@@ -151,7 +151,8 @@ def phase_serve(seed: int, kind: str) -> None:
     # engine's own (paged kernel).  Each writes the step's K/V at `pos`
     # before it reads the pool, so the jnp step leaves the kernel step's
     # inputs as they were, and no copy of the pool is needed
-    kernel_decode = eng._decode
+    kernel_decode = eng._decode     # the engine's call, counts kept inside
+    kernel_prog = _make_decode_bt(eng.cfg)
     ref_decode = _make_decode_bt(dataclasses.replace(eng.cfg,
                                                      attn_impl="naive"))
     seen = {}
@@ -159,9 +160,11 @@ def phase_serve(seed: int, kind: str) -> None:
     def decode_both(params, tok, caches, pos, tables):
         if seen:
             return kernel_decode(params, tok, caches, pos, tables)
-        seen["hlo"] = kernel_decode.lower(params, tok, caches, pos,
-                                          tables).compile().as_text()
-        ref_logits, caches = ref_decode(params, tok, caches, pos, tables)
+        counts = jnp.zeros_like(eng._counts)
+        seen["hlo"] = kernel_prog.lower(params, tok, caches, pos, tables,
+                                        counts).compile().as_text()
+        ref_logits, caches, _ = ref_decode(params, tok, caches, pos, tables,
+                                           counts)
         logits, caches = kernel_decode(params, tok, caches, pos, tables)
         live = np.asarray(pos) > 0
         seen["rows"] = int(live.sum())

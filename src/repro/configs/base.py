@@ -51,7 +51,8 @@ class ModelConfig:
     #           (kernels/flash_attention) — the differentiable TPU training
     #           path; consults the autotuning cache (tuned=True) and compiles
     #           on a TPU backend, interprets elsewhere (kernels.backend)
-    # "paged" = Pallas paged decode kernel over the serving slot pool
+    # "paged" = Pallas paged decode kernels over the serving engine's block
+    #           pool (block tables; the engine sets it)
     attn_impl: str = "naive"
     attn_block_kv: int = 1024
     # linear-execution dispatch for every dense projection GEMM (qkv/output,

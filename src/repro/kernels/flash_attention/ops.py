@@ -35,9 +35,9 @@ from ..backend import interpret_mode
 from .backward import flash_attention_bwd_pallas
 from .kernel import flash_attention_pallas
 from .paged import (CHUNK_TOKENS, paged_decode_blocktable_pallas,
-                    paged_decode_pallas, paged_mla_decode_pallas)
+                    paged_mla_decode_pallas)
 from .ref import (attention_ref, paged_decode_blocktable_ref,
-                  paged_decode_ref, paged_mla_decode_ref)
+                  paged_mla_decode_ref)
 
 
 def _fold(x):
@@ -187,82 +187,6 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 
 
 # --- paged decode (serving engine) ---------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("block_kv", "interpret",
-                                             "use_pallas"))
-def _paged_jit(q, k_pool, v_pool, slot_idx, lengths, k_scale, v_scale, *,
-               block_kv: int, interpret: bool, use_pallas: bool):
-    if not use_pallas:
-        if k_scale is not None:
-            k_pool = dequantize_kv(k_pool, k_scale, q.dtype)
-            v_pool = dequantize_kv(v_pool, v_scale, q.dtype)
-        return paged_decode_ref(q, k_pool.astype(q.dtype),
-                                v_pool.astype(q.dtype), slot_idx, lengths)
-    s_max = k_pool.shape[1]
-    bkv = min(block_kv, s_max)
-    if s_max % bkv:
-        # clamp to a divisor rather than padding: a pad would copy the whole
-        # pool inside the decode program, every layer, every step.  Pool
-        # depths are lane-aligned and block_kv candidates are lane
-        # multiples, so the gcd stays a healthy tile-aligned block.
-        import math
-        g = math.gcd(s_max, bkv)
-        if g >= 16:
-            bkv = g
-        else:  # pathological caller shapes only: pad once here
-            pad = round_up(s_max, bkv) - s_max
-            k_pool = jnp.pad(k_pool, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v_pool = jnp.pad(v_pool, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            if k_scale is not None:
-                k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
-                v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
-    return paged_decode_pallas(q, k_pool, v_pool, slot_idx, lengths,
-                               k_scale=k_scale, v_scale=v_scale,
-                               block_kv=bkv, interpret=interpret)
-
-
-def paged_decode(q, k_pool, v_pool, slot_idx, lengths, *,
-                 k_scale=None, v_scale=None,
-                 block_kv: int = 128, interpret: Optional[bool] = None,
-                 use_pallas: bool = True, tuned: bool = False,
-                 hw_name: Optional[str] = None):
-    """Slot-gathering decode attention over a fixed KV pool.
-
-    q: (b, a, d) — one query token per active request row; k_pool, v_pool:
-    (slots, s_max, nkv, d); slot_idx: (b,) row->slot; lengths: (b,) live kv
-    entries (0 = dead slot -> zero output).  Returns (b, a, d).
-
-    k_scale/v_scale: (slots, s_max, nkv) f32 per-(token, kv_head) scales
-    for an int8 KV pool (kv_dtype="int8"): the Pallas path dequantizes per
-    kv tile inside the kernel; the jnp path dequantizes the pool up front.
-
-    tuned=True overrides block_kv with the autotuning cache's measured-best
-    for this pool shape (op "paged_decode") when one exists — see
-    `repro.tuning.search.autotune_paged_decode`.  Quantized pools key the
-    lookup by the mixed dtype pair (e.g. "bfloat16xint8").
-    """
-    tuned_hit = None
-    dtype = jnp.dtype(q.dtype).name
-    if k_scale is not None:
-        dtype = mixed_dtype(dtype, jnp.dtype(k_pool.dtype).name)
-    if tuned and use_pallas:
-        b, a, d = q.shape
-        slots, s_max, nkv, _ = k_pool.shape
-        cfg = _tuning_lookup("paged_decode", (b, slots, s_max, nkv, a, d),
-                             dtype, hw_name or get_hardware().name)
-        tuned_hit = cfg is not None
-        if cfg is not None:
-            block_kv = cfg.blocks["block_kv"]
-    if obs.enabled():
-        obs.record_dispatch(
-            "paged_decode", impl="pallas" if use_pallas else "jnp",
-            shape=q.shape,
-            blocks={"block_kv": block_kv} if use_pallas else None,
-            tuned_hit=tuned_hit)
-    return _paged_jit(q, k_pool, v_pool, slot_idx, lengths, k_scale, v_scale,
-                      block_kv=block_kv, interpret=interpret_mode(interpret),
-                      use_pallas=use_pallas)
-
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret",
                                              "use_pallas"))

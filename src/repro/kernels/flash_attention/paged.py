@@ -1,47 +1,24 @@
-"""Paged/slotted decode-attention Pallas TPU kernel for the serving engine.
+"""Paged decode-attention Pallas TPU kernels for the serving engine's
+block pool.
 
-One query token per request row, K/V read from a fixed pool of cache slots
-(`serving.engine.kv_pool.SlotPool`).  The slot mapping and per-slot lengths
-ride in as scalar-prefetch operands (`pltpu.PrefetchScalarGridSpec`), so the
-K/V BlockSpec index maps *gather by slot index*: row b's kv blocks come from
-pool slot `slot_idx[b]` — the Pallas analogue of vLLM's paged attention at
-page size = one whole slot.
-
-Grid (b, kv_steps), kv innermost; each kv tile is (block_kv, nkv, d) — all
-kv heads of block_kv positions, so the block's two minor dims are the pool's
-own (nkv, d), the layout the TPU compiler accepts for a (…, nkv, d) pool (a
-per-head (1, d) slice of those dims is refused).  The kernel walks the heads
-of a tile in a static loop; VMEM scratch carries the online softmax state
-(m, l, acc) per head across kv steps (TPU grids are sequential per core).
-Per-slot lengths do double duty:
-  * kv blocks entirely past `lengths[b]` are skipped via pl.when — a dead
-    slot (length 0) costs zero FLOPs and writes zeros;
-  * the tail block is masked elementwise so slot-pool positions past the
-    sequence's live prefix (stale data from a previous occupant) never
-    contribute.
-
-The score tile is (g, block_kv) where g = query heads per kv head: decode
-works at tiny sublane occupancy by construction (the paper's skinny-GEMM
-regime); block_kv is the lane-side knob the autotuner sweeps
-(`tuning.search.autotune_paged_decode`).
-
-`paged_decode_blocktable_pallas` is the block-table variant (vLLM paged
-attention at a real page size): K/V live in a pool of physical blocks
-(pages) of `block_size` tokens and row b's logical page j is physical page
-`block_table[b, j]`.  Its grid is (b,), one step per row, so its work
-follows each row's live pages and not the table's width.  The table and
-lengths sit in SMEM (scalar prefetch); the pools stay in HBM
-(`memory_space=pl.ANY`).  A row loops over chunks of P pages
-(`blocktable_chunk_pages`: 256 tokens by default, `block_kv` tokens
+`paged_decode_blocktable_pallas` is vLLM's paged attention at a real page
+size: K/V live in a pool of physical blocks (pages) of `block_size` tokens
+(`serving.engine.kv_pool.PagedPool`) and row b's logical page j is physical
+page `block_table[b, j]`.  One query token per row.  The grid is (b,), one
+step per row, so the work follows each row's live pages and not the
+table's width.  The table and lengths sit in SMEM (scalar prefetch); the
+pools stay in HBM (`memory_space=pl.ANY`).  A row loops over chunks of P
+pages (`blocktable_chunk_pages`: 256 tokens by default, `block_kv` tokens
 rounded up to whole pages when given) for ceil(lengths[b] / chunk)
 iterations -- none for a dead row, which writes zeros.  Each chunk's live
 pages are gathered by one async copy per page and pool into a
 double-buffered VMEM scratch, the next chunk's copies started before this
-chunk is computed; pages past the live prefix are never copied.  The
-scores of a chunk are one lane-dense (a, chunk * nkv) tile for all query
-heads (see `_paged_bt_kernel`), masked by head and by `lengths[b]`, with
-the online softmax in f32.  An int8 pool's scale pages travel with their
-K/V pages.  The tuner sweeps the page size jointly with the chunk length
+chunk is computed; pages past the live prefix (a released row's stale
+bytes, the garbage block dead rows point at) are never copied.  The scores
+of a chunk are one lane-dense (a, chunk * nkv) tile for all query heads
+(see `_paged_bt_kernel`), masked by head and by `lengths[b]`, with the
+online softmax in f32.  An int8 pool's scale pages travel with their K/V
+pages.  The tuner sweeps the page size jointly with the chunk length
 (`tuning.search.autotune_paged_decode_blocktable`).
 
 `paged_mla_decode_pallas` is the MLA twin over a latent block pool: the
@@ -58,126 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
-
-
-def _paged_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  num_kv_heads: int, kv_steps: int, block_kv: int,
-                  scale: float):
-    # rest is (o, m, l, acc) for the plain variant, or
-    # (ks, vs, o, m, l, acc) when the pool is int8-quantized KV: the scale
-    # tiles ride as extra inputs and the dequant happens per kv tile, so the
-    # pool stays 1 byte/elem in HBM and only live tiles pay the multiply.
-    if len(rest) == 6:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b_i, ki = pl.program_id(0), pl.program_id(1)
-    length = len_ref[b_i]
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # skip blocks wholly past the live prefix (dead slot: skips everything)
-    @pl.when(ki * block_kv < length)
-    def _step():
-        kv_pos = ki * block_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_kv), 1)
-        # one kv tile carries every kv head (the (nkv, d) minor dims are the
-        # pool's own, which keeps the block legal on the TPU); each head's
-        # (bkv, d) slab is a strided read of that tile
-        for h in range(num_kv_heads):
-            q = q_ref[0, h].astype(jnp.float32)          # (g, d)
-            k = k_ref[0, :, h, :].astype(jnp.float32)    # (bkv, d)
-            v = v_ref[0, :, h, :].astype(jnp.float32)    # (bkv, d)
-            if ks_ref is not None:                       # per-(token, head)
-                k = k * ks_ref[0, :, h:h + 1]
-                v = v * vs_ref[0, :, h:h + 1]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            s = jnp.where(kv_pos < length, s, NEG_INF)
-            m_prev = m_ref[h]                            # (g, 1)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
-
-    @pl.when(ki == kv_steps - 1)
-    def _done():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # dead slot -> zero output
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _scratch(nkv: int, g: int, d: int):
-    """Online-softmax state (m, l, acc) for every kv head of one row."""
-    from jax.experimental.pallas import tpu as pltpu
-    return [pltpu.VMEM((nkv, g, 1), jnp.float32),
-            pltpu.VMEM((nkv, g, 1), jnp.float32),
-            pltpu.VMEM((nkv, g, d), jnp.float32)]
-
-
-def paged_decode_pallas(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                        slot_idx: jax.Array, lengths: jax.Array, *,
-                        k_scale: jax.Array | None = None,
-                        v_scale: jax.Array | None = None,
-                        block_kv: int = 128, scale: float | None = None,
-                        interpret: bool = False) -> jax.Array:
-    """q: (b, a, d) one token per row; k_pool, v_pool: (slots, s_max, nkv, d);
-    slot_idx: (b,) int32 row->slot; lengths: (b,) int32 live kv per row.
-
-    k_scale/v_scale: (slots, s_max, nkv) f32 per-(token, kv_head) dequant
-    scales for an int8 pool (both or neither); the kernel dequantizes each
-    kv tile in VMEM, so HBM traffic stays at 1 byte per cached element.
-
-    Requires s_max % block_kv == 0 (ops.py clamps/pads) and a % nkv == 0.
-    Returns (b, a, d); rows with length 0 return zeros.
-    """
-    b, a, d = q.shape
-    slots, s_max, nkv, dk = k_pool.shape
-    assert d == dk and a % nkv == 0
-    assert s_max % block_kv == 0, (s_max, block_kv)
-    assert (k_scale is None) == (v_scale is None)
-    g = a // nkv
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    kv_steps = s_max // block_kv
-    qh = q.reshape(b, nkv, g, d)
-    from jax.experimental.pallas import tpu as pltpu
-    kv_spec = pl.BlockSpec((1, block_kv, nkv, d),
-                           lambda bi, j, slot, lens: (slot[bi], j, 0, 0))
-    row_spec = pl.BlockSpec((1, nkv, g, d),
-                            lambda bi, j, slot, lens: (bi, 0, 0, 0))
-    in_specs = [row_spec, kv_spec, kv_spec]
-    operands = [qh, k_pool, v_pool]
-    if k_scale is not None:
-        assert k_scale.shape == (slots, s_max, nkv), k_scale.shape
-        sc_spec = pl.BlockSpec((1, block_kv, nkv),
-                               lambda bi, j, slot, lens: (slot[bi], j, 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kv_steps),
-        in_specs=in_specs,
-        out_specs=row_spec,
-        scratch_shapes=_scratch(nkv, g, d),
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, num_kv_heads=nkv, kv_steps=kv_steps,
-                          block_kv=block_kv, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
-        interpret=interpret,
-    )(slot_idx.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
-    return out.reshape(b, a, d)
-
 
 CHUNK_TOKENS = 256          # default chunk of the block-table kernel
 CHUNK_VMEM_BYTES = 8 << 20  # cap on its double-buffered chunk scratch

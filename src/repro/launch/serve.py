@@ -6,9 +6,9 @@ Static batch (the PR-1 behavior, kept as the baseline):
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b --smoke \
         --batch 4 --prompt-len 32 --gen 16
 
-Continuous-batching engine — admits a synthetic request stream into a
-tile-aligned KV slot pool, reporting aggregate tok/s plus per-request TTFT
-and inter-token latency percentiles:
+Continuous-batching engine — admits a synthetic request stream into the
+block-table KV pool (prefix cache, Pallas paged decode), reporting aggregate
+tok/s plus per-request TTFT and inter-token latency percentiles:
 
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
         --engine --smoke --requests 12 --arrival uniform --gen 16
@@ -102,8 +102,7 @@ def run_engine(cfg, params, args) -> None:
 
     eng = Engine(params, cfg, max_batch=args.batch,
                  max_prompt=args.prompt_len, max_new=args.gen,
-                 use_paged_kernel=args.paged, grow_batch=args.grow_batch,
-                 prefix_cache=args.prefix_cache, kv_dtype=args.kv_dtype)
+                 grow_batch=args.grow_batch, kv_dtype=args.kv_dtype)
     pol = eng.policy
     print(f"bucket policy: {pol.num_slots} slots x {pol.seq_max} kv depth, "
           f"prompt buckets {list(pol.prompt_buckets)} "
@@ -184,17 +183,12 @@ def main(argv=None):
     ap.add_argument("--arrival", default="uniform",
                     choices=("burst", "uniform", "bursty", "longtail"))
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--paged", action="store_true",
-                    help="decode attention via the Pallas paged kernel")
     ap.add_argument("--kv-dtype", default="auto", choices=["auto", "int8"],
                     help="KV-cache storage dtype: int8 halves pool bytes "
                          "(vs bf16) with per-(token, head) f32 scales")
     ap.add_argument("--grow-batch", action="store_true",
                     help="let the advisor grow the slot bucket when the "
                          "calibrated model predicts enough amortization")
-    ap.add_argument("--prefix-cache", action="store_true",
-                    help="block-table KV pool with content-addressed prefix "
-                         "sharing")
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request completion deadline in seconds; "
                          "expiry returns the partial result as "

@@ -94,19 +94,21 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
     """x: (b, s, h).  Returns (out, new_cache).
 
     cache: dict(k=(b, s_max, kv, hd), v=...) or None.
-    cache_index: write offset for decode — a scalar, or a (b,) vector of
-    per-row offsets (serving engine: each cache slot at its own depth; the
-    write is then a per-row one-hot scatter and requires s == 1, and
-    `positions` should be the matching (b, s) per-row positions).
+    cache_index: scalar write offset into a contiguous cache (prefill, and
+    decode at one shared depth), or with block_tables a (b,) vector of
+    per-row offsets; `positions` should then be the matching (b, 1) per-row
+    positions.
     kv_input: if set, keys/values come from this tensor (cross-attention).
     block_tables: (b, max_blocks) int32 — switches the cache to the
-    *block-pool* layout (k/v: (num_blocks, block_size, kv, hd)): row b's
-    logical kv block j lives in physical block `block_tables[b, j]`.
-    Requires single-token decode with a (b,) vector cache_index; the new
-    token is scattered into (table[b, ci//bs], ci % bs) — each live row's
-    tail block is private by the pool's copy-on-write discipline, so rows
-    never collide (dead rows all write the pool's garbage block, which is
-    never read).
+    serving engine's *block-pool* layout (k/v: (num_blocks, block_size, kv,
+    hd)): row b's logical kv block j lives in physical block
+    `block_tables[b, j]`.  Requires single-token decode with a (b,) vector
+    cache_index; the new token is scattered into (table[b, ci//bs], ci % bs)
+    — each live row's tail block is private by the pool's copy-on-write
+    discipline, so rows never collide (dead rows all write the pool's
+    garbage block, which is never read).  Attention then runs the Pallas
+    paged kernel when cfg.attn_impl == "paged", else the jnp reference over
+    each row's gathered blocks.
     """
     b, s, h = x.shape
     a, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -177,58 +179,30 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
         return out, new_cache
     if cache is not None and kv_input is None:
         ci = jnp.asarray(cache_index)
+        assert ci.ndim == 0, \
+            "a per-row (vector) cache_index needs block_tables (block pool)"
+        upd = jax.lax.dynamic_update_slice_in_dim
         if quant:
             from ..quant import dequantize_kv, quantize_kv
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            if ci.ndim:  # per-row write positions (serving-engine slot pool)
-                assert s == 1, "vector cache_index requires single-token decode"
-                write = jnp.arange(cache["k"].shape[1]) == ci[:, None]
-                sel = write[:, :, None, None]
-                kq = jnp.where(sel, kq, cache["k"])
-                vq = jnp.where(sel, vq, cache["v"])
-                k_scale = jnp.where(write[:, :, None], ks, cache["k_scale"])
-                v_scale = jnp.where(write[:, :, None], vs, cache["v_scale"])
-            else:
-                upd = jax.lax.dynamic_update_slice_in_dim
-                kq = upd(cache["k"], kq, cache_index, axis=1)
-                vq = upd(cache["v"], vq, cache_index, axis=1)
-                k_scale = upd(cache["k_scale"], ks, cache_index, axis=1)
-                v_scale = upd(cache["v_scale"], vs, cache_index, axis=1)
+            kq = upd(cache["k"], kq, ci, axis=1)
+            vq = upd(cache["v"], vq, ci, axis=1)
+            k_scale = upd(cache["k_scale"], ks, ci, axis=1)
+            v_scale = upd(cache["v_scale"], vs, ci, axis=1)
             new_cache = {"k": kq, "v": vq,
                          "k_scale": k_scale, "v_scale": v_scale}
-            kv_len = ci + s
-            if cfg.attn_impl == "paged" and s == 1:
-                k, v = kq, vq  # paged kernel dequantizes per kv tile
-            else:
-                k = dequantize_kv(kq, k_scale, q.dtype)
-                v = dequantize_kv(vq, v_scale, q.dtype)
+            k = dequantize_kv(kq, k_scale, q.dtype)
+            v = dequantize_kv(vq, v_scale, q.dtype)
         else:
-            if ci.ndim:  # per-row write positions (serving-engine slot pool)
-                assert s == 1, "vector cache_index requires single-token decode"
-                write = jnp.arange(cache["k"].shape[1]) == ci[:, None]  # (b, s_max)
-                sel = write[:, :, None, None]
-                k = jnp.where(sel, k.astype(cache["k"].dtype), cache["k"])
-                v = jnp.where(sel, v.astype(cache["v"].dtype), cache["v"])
-            else:
-                k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), cache_index, axis=1)
-                v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), cache_index, axis=1)
+            k = upd(cache["k"], k.astype(cache["k"].dtype), ci, axis=1)
+            v = upd(cache["v"], v.astype(cache["v"].dtype), ci, axis=1)
             new_cache = {"k": k, "v": v}
-            kv_len = ci + s
+        kv_len = ci + s
     # 2-D positions are per-row query positions; _sdpa masks them row-wise
     q_pos = positions
     is_decode = cache is not None and s == 1
-    if cfg.attn_impl == "paged" and is_decode:
-        # Pallas paged decode over the slot pool (identity slot map here;
-        # the kernel's gather-by-slot path is exercised by the engine tests)
-        from ..kernels.flash_attention.ops import paged_decode
-        lengths = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
-        out = paged_decode(q[:, 0], k if quant else k.astype(q.dtype),
-                           v if quant else v.astype(q.dtype),
-                           jnp.arange(b, dtype=jnp.int32), lengths,
-                           k_scale=k_scale, v_scale=v_scale,
-                           tuned=True)[:, None]
-    elif cfg.attn_impl == "flash" and not is_decode and cache is None:
+    if cfg.attn_impl == "flash" and not is_decode and cache is None:
         # Pallas flash kernel with its custom-VJP fused backward: the
         # training/prefill fast path.  Cache-backed prefill (dynamic kv_len)
         # and decode stay on the jnp paths below; MLA never routes here.
@@ -394,12 +368,12 @@ def apply_mla(p, x, cfg: ModelConfig, *, positions, cache=None,
     (zero-padded to `mla_cache_width`).
 
     cache: dict(latent=(b, s_max, width)) with a scalar cache_index (a
-    write at [ci, ci+s)), or a (b,) cache_index for one-token decode with
-    per-row write positions; with block_tables the cache is a latent block
-    pool (num_blocks, block_size, width) and row b's logical block j is
-    physical block block_tables[b, j].  One-token decode runs the absorbed
-    form (`_mla_decode`); prefill and training the decompressed form with
-    query chunks (`_mla_prefill`).
+    write at [ci, ci+s)); with block_tables the cache is a latent block
+    pool (num_blocks, block_size, width), row b's logical block j is
+    physical block block_tables[b, j], and a (b,) cache_index gives each
+    row's write position for one-token decode.  One-token decode runs the
+    absorbed form (`_mla_decode`); prefill and training the decompressed
+    form with query chunks (`_mla_prefill`).
     """
     b, s, _ = x.shape
     q_nope, q_rope, lat = _mla_latent_q(p, x, cfg, positions)
@@ -426,13 +400,10 @@ def apply_mla(p, x, cfg: ModelConfig, *, positions, cache=None,
                           block_tables=block_tables,
                           lengths=(ci + 1).astype(jnp.int32))
         return out, {"latent": pool}
-    if ci.ndim:  # per-row write positions (serving-engine slot pool)
-        assert s == 1, "vector cache_index requires single-token decode"
-        write = (jnp.arange(pool.shape[1]) == ci[:, None])[..., None]
-        pool = jnp.where(write, lat.astype(pool.dtype), pool)
-    else:
-        pool = jax.lax.dynamic_update_slice_in_dim(
-            pool, lat.astype(pool.dtype), ci, axis=1)
+    assert ci.ndim == 0, \
+        "a per-row (vector) cache_index needs block_tables (block pool)"
+    pool = jax.lax.dynamic_update_slice_in_dim(
+        pool, lat.astype(pool.dtype), ci, axis=1)
     if s == 1:
         lengths = jnp.broadcast_to(ci + 1, (b,)).astype(jnp.int32)
         out = _mla_decode(p, q_nope, q_rope, cfg, latent=pool,

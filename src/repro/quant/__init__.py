@@ -102,7 +102,7 @@ def quantize_kv(x) -> Tuple[jax.Array, jax.Array]:
     """Quantize a KV tensor (..., kv_heads, head_dim) per (token, kv_head).
 
     Returns (int8 values, float32 scales with head_dim dropped) — the layout
-    the quantized SlotPool/PagedPool leaves carry ("k"/"v" int8 plus
+    the quantized PagedPool leaves carry ("k"/"v" int8 plus
     "k_scale"/"v_scale" float32, see models/blocks._kv_cache_shape).
     """
     q, scale = quantize_int8(x, axis=-1)

@@ -5,8 +5,8 @@ Public surface:
   Request / SamplingParams / Completion / EngineStats — request API
   FINISH_REASONS / OK_REASONS — the finish_reason catalog (request.py)
   BucketPolicy / make_policy — tile-aligned shape policy (buckets.py)
-  SlotPool                — fixed KV slot pool (kv_pool.py)
   BlockPool / PagedPool   — block-table KV pool with prefix caching + COW
+                            (kv_pool.py)
   ShedPolicy / Shed       — admission control / overload shedding
   FaultPlan / chaos_soak  — deterministic fault injection (faults.py)
   synthetic_requests      — workload generator shared with benchmarks
@@ -14,7 +14,7 @@ Public surface:
 from .buckets import BucketPolicy, make_policy
 from .engine import Engine
 from .faults import FaultEvent, FaultPlan, SoakResult, chaos_soak
-from .kv_pool import BlockPool, BlockSeq, CowCopy, PagedPool, PoolExhausted, SlotPool
+from .kv_pool import BlockPool, BlockSeq, CowCopy, PagedPool, PoolExhausted
 from .request import (FINISH_REASONS, OK_REASONS, Completion, EngineStats,
                       Request, SamplingParams)
 from .scheduler import RequestQueue, Scheduler, Shed, ShedPolicy
@@ -23,7 +23,7 @@ from .workload import PATTERNS, synthetic_requests
 __all__ = [
     "Engine", "Request", "SamplingParams", "Completion", "EngineStats",
     "FINISH_REASONS", "OK_REASONS",
-    "BucketPolicy", "make_policy", "SlotPool", "BlockPool", "BlockSeq",
+    "BucketPolicy", "make_policy", "BlockPool", "BlockSeq",
     "CowCopy", "PagedPool", "PoolExhausted", "RequestQueue", "Scheduler",
     "Shed", "ShedPolicy", "FaultEvent", "FaultPlan", "SoakResult",
     "chaos_soak", "PATTERNS", "synthetic_requests",

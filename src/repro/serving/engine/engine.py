@@ -1,18 +1,22 @@
-"""Continuous-batching serving engine over a tile-aligned KV slot pool.
+"""Continuous-batching serving engine over a block-table KV pool.
 
 One `Engine` owns: the bucket policy (shapes snapped to the hardware tile
-lattice — `buckets`), a fixed `SlotPool` of KV cache slots, and a bounded
-set of jitted programs:
+lattice — `buckets`), a `PagedPool` of physical KV blocks with a fixed
+lattice of decode rows (`kv_pool`: block tables, a content-addressed prefix
+cache, copy-on-write sharing), and a bounded set of jitted programs:
 
-  * one prefill program per prompt bucket — a single request, right-padded
-    to the bucket, cache written at positions 0..bucket (the pad tail is
-    dead weight masked by the slot length everywhere downstream);
-  * ONE decode program for the whole pool — every step advances all slots
-    one token with per-slot write positions (vector cache_index) and
-    per-slot causal masks; dead slots ride along masked;
+  * one suffix-prefill program per prompt bucket — a single request's
+    uncached suffix, right-padded to the bucket, prefilled against the
+    row's gathered (1, seq_max) cache view at cache_index = the cached
+    prefix length (a cold prompt is a suffix at 0), then scattered back
+    into the row's blocks;
+  * ONE decode program for the whole row lattice — every step advances all
+    rows one token: each row writes its token into its tail block
+    (vector cache_index + block tables) and attends over its live pages
+    through the Pallas paged-decode kernel; dead rows ride along masked;
   * a sampling program (greedy + temperature with per-request PRNG streams).
 
-The host loop interleaves admission (prefill into freed slots) with pool
+The host loop interleaves admission (prefill into freed rows) with pool
 decode steps — continuous batching.  `policy="static"` runs the same
 machinery but only refills the pool once it has fully drained, which is the
 static-batch baseline the benchmarks compare against.
@@ -22,10 +26,10 @@ catalog): `run()` never raises for a per-request problem.  Invalid requests
 become `rejected` completions before they touch a slot; admission control
 (`scheduler.ShedPolicy`) sheds under overload; per-request deadlines time
 out with partial results; and KV backpressure mid-decode (block-pool
-exhaustion during COW/tail growth on the paged pool) preempts the youngest
-sequence with exact rollback — its full KV blocks are committed to the
-prefix cache, the request re-queues, and on re-admission only the (≤ one
-block) uncached tail is re-prefilled, so outputs stay token-identical.
+exhaustion during COW/tail growth) preempts the youngest sequence with
+exact rollback — its full KV blocks are committed to the prefix cache, the
+request re-queues, and on re-admission only the (≤ one block) uncached
+tail is re-prefilled, so outputs stay token-identical.
 Retries are bounded; a request that exhausts them completes as
 `preempted-retry-exhausted` with whatever tokens it has.
 
@@ -45,11 +49,11 @@ import numpy as np
 from ... import obs
 from ...configs.base import ModelConfig
 from ...core.hardware import Hardware, running_hardware
-from ...models import apply_lm, init_caches
+from ...models import apply_lm
 from ...models.layers import compute_dtype
 from ...models.linear import cast_linear_params
 from .buckets import BucketPolicy, make_policy
-from .kv_pool import PagedPool, PoolExhausted, SlotPool
+from .kv_pool import PagedPool, PoolExhausted
 from .request import Completion, EngineStats, Request
 from .scheduler import RequestQueue, Scheduler, ShedPolicy
 
@@ -84,45 +88,8 @@ def _prompt_mask(tokens, true_len):
     return jnp.arange(tokens.shape[1])[None, :] < true_len
 
 
-def _make_prefill(cfg: ModelConfig, s_max: int):
-    """(params, tokens (1, bucket), true_len, counts) -> (logits (1, v),
-    caches, counts).
-
-    Logits are gathered at the last *real* prompt position; cache entries
-    past true_len hold pad garbage that per-slot lengths mask downstream.
-    """
-
-    def prefill(params, tokens, true_len, counts):
-        caches = init_caches(cfg, 1, s_max, compute_dtype(cfg.dtype))
-        logits, caches, _, st = apply_lm(
-            params, tokens, cfg, caches=caches, cache_index=0,
-            token_mask=_prompt_mask(tokens, true_len), return_stats=True)
-        last = jax.lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=1)
-        return last[:, 0], caches, counts.at[_PREFILL].add(st)
-
-    return jax.jit(prefill, donate_argnums=(3,))
-
-
-def _make_decode(cfg: ModelConfig):
-    """(params, tok (slots, 1), caches, pos (slots,), counts) -> (logits,
-    caches, counts).
-
-    pos is the per-slot write position (== live kv length, 0 for a dead
-    slot); the KV pool is donated so every step updates the cache buffers
-    in place.
-    """
-
-    def decode(params, tok, caches, pos, counts):
-        logits, caches, _, st = apply_lm(
-            params, tok, cfg, caches=caches, cache_index=pos, decode=True,
-            token_mask=(pos > 0)[:, None], return_stats=True)
-        return logits[:, -1], caches, counts.at[_DECODE].add(st)
-
-    return jax.jit(decode, donate_argnums=(2, 4))
-
-
 def _make_prefix_prefill(cfg: ModelConfig):
-    """Cache-backed suffix prefill for the paged engine.
+    """Cache-backed suffix prefill.
 
     (params, tokens (1, bucket), true_len, start, contig, counts)
         -> (logits, contig, counts)
@@ -145,8 +112,12 @@ def _make_prefix_prefill(cfg: ModelConfig):
 
 
 def _make_decode_bt(cfg: ModelConfig):
-    """Block-table decode: like `_make_decode` but the caches are a physical
-    block pool and each row's KV is gathered through (tables, pos)."""
+    """(params, tok (rows, 1), caches, pos (rows,), tables, counts) ->
+    (logits, caches, counts).
+
+    The caches are the physical block pool; pos is each row's write
+    position (== live kv length, 0 for a dead row) and tables its block
+    table.  The pool is donated so every step writes it in place."""
 
     def decode(params, tok, caches, pos, tables, counts):
         logits, caches, _, st = apply_lm(
@@ -213,22 +184,31 @@ class Engine:
 
     The engine serves its own copy of `params`: the GEMM weights in the
     compute dtype (`models.linear.cast_linear_params`), everything else as
-    given; `params_cast_bytes` counts the bytes of the leaves it cast."""
+    given; `params_cast_bytes` counts the bytes of the leaves it cast.
+
+    `use_paged_kernel` and `prefix_cache` take only True: the engine has one
+    block-table pool and one paged decode kernel.  They stay as parameters
+    for callers that still pass them, and False raises ValueError."""
 
     def __init__(self, params, cfg: ModelConfig, *,
                  max_batch: int = 8, max_prompt: int = 64,
                  max_new: int = 64, hw: Optional[Hardware] = None,
                  policy: Optional[BucketPolicy] = None,
-                 use_paged_kernel: bool = False,
+                 use_paged_kernel: bool = True,
                  grow_batch: bool = False,
-                 prefix_cache: bool = False,
+                 prefix_cache: bool = True,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  kv_dtype: str = "auto",
                  preempt_retries: int = 4):
+        for name, on in (("use_paged_kernel", use_paged_kernel),
+                         ("prefix_cache", prefix_cache)):
+            if not on:
+                raise ValueError(
+                    f"{name}={on!r}: the engine has one block-table KV pool "
+                    f"and one paged decode kernel, so {name} takes only True")
         _check_supported(cfg)
-        if use_paged_kernel:
-            cfg = dataclasses.replace(cfg, attn_impl="paged")
+        cfg = dataclasses.replace(cfg, attn_impl="paged")
         if cfg.num_experts:
             # served tokens never depend on their batch neighbours: no
             # capacity, no drops (training keeps the capacity path)
@@ -257,29 +237,16 @@ class Engine:
         self.policy = policy or make_policy(
             cfg, hw, max_batch=max_batch, max_prompt=max_prompt,
             max_seq=max_prompt + max_new, grow_batch=grow_batch)
-        self.prefix_cache = prefix_cache
         self.preempt_retries = preempt_retries
-        if prefix_cache:
-            bs = block_size or self._pick_block_size(hw)
-            self.pool = PagedPool(cfg, self.policy.num_slots,
-                                  self.policy.seq_max,
-                                  compute_dtype(cfg.dtype), block_size=bs,
-                                  num_blocks=num_blocks)
-            # every admission is a cache-backed *suffix* prefill (a cold
-            # prompt is a suffix at start=0); bucketed on the suffix length
-            pf = self._counting(_make_prefix_prefill(cfg))
-            self._prefills = {b: pf for b in self.policy.prompt_buckets}
-            self._decode = self._counting(_make_decode_bt(cfg))
-        else:
-            assert num_blocks is None, \
-                "num_blocks applies to the prefix_cache (block-table) pool"
-            self.pool = SlotPool(cfg, self.policy.num_slots,
-                                 self.policy.seq_max,
-                                 compute_dtype(cfg.dtype))
-            self._prefills = {
-                b: self._counting(_make_prefill(cfg, self.policy.seq_max))
-                for b in self.policy.prompt_buckets}
-            self._decode = self._counting(_make_decode(cfg))
+        bs = block_size or self._pick_block_size(hw)
+        self.pool = PagedPool(cfg, self.policy.num_slots, self.policy.seq_max,
+                              compute_dtype(cfg.dtype), block_size=bs,
+                              num_blocks=num_blocks)
+        # every admission is a cache-backed *suffix* prefill (a cold prompt
+        # is a suffix at start=0); bucketed on the suffix length
+        pf = self._counting(_make_prefix_prefill(cfg))
+        self._prefills = {b: pf for b in self.policy.prompt_buckets}
+        self._decode = self._counting(_make_decode_bt(cfg))
         self._sample = _make_sampler()
         # per-slot device-facing state (dead slots: token 0, temp 0)
         n = self.policy.num_slots
@@ -395,11 +362,10 @@ class Engine:
         if req.prompt_len + req.max_new_tokens > self.policy.seq_max:
             return (f"prompt {req.prompt_len} + gen {req.max_new_tokens} "
                     f"exceeds pool depth {self.policy.seq_max}")
-        if self.prefix_cache:
-            need = -(-req.prompt_len // self.pool.block_size)
-            if need > self.pool.blocks.num_blocks:
-                return (f"prompt needs {need} KV blocks; the pool only has "
-                        f"{self.pool.blocks.num_blocks}")
+        need = -(-req.prompt_len // self.pool.block_size)
+        if need > self.pool.blocks.num_blocks:
+            return (f"prompt needs {need} KV blocks; the pool only has "
+                    f"{self.pool.blocks.num_blocks}")
         return None
 
     def _reject(self, req: Request, detail: str,
@@ -448,22 +414,7 @@ class Engine:
                       prompt_len=req.prompt_len, bucket=bucket,
                       resume=res is not None):
             try:
-                if self.prefix_cache:
-                    logits, cached = self._prefill_paged(req, slot, res)
-                else:
-                    cached = 0
-                    padded = np.zeros((1, bucket), np.int32)
-                    padded[0, :req.prompt_len] = req.tokens
-                    with obs.span("prefill", bucket=bucket, rid=req.rid,
-                                  cached_tokens=0):
-                        logits, caches = self._prefills[bucket](
-                            self.params, jnp.asarray(padded),
-                            jnp.asarray(req.prompt_len, jnp.int32))
-                    if obs.enabled():
-                        obs.counter("engine.prefill_tokens").inc(
-                            req.prompt_len)
-                        obs.counter("engine.prefill_bucket_tokens").inc(bucket)
-                    self.pool.write(slot, caches, req.prompt_len)
+                logits, cached = self._prefill(req, slot, res)
             except PoolExhausted as e:
                 # admission raced a COW burst / held blocks: the slot is
                 # returned, the request re-queued with a bounded retry budget
@@ -554,10 +505,9 @@ class Engine:
         else:
             self._reject(req, detail, done)
 
-    def _prefill_paged(self, req: Request, slot: int,
-                       res: Optional[_ResumeState]
-                       ) -> Tuple[jax.Array, int]:
-        """Paged admission: bind a block table (sharing every cached full
+    def _prefill(self, req: Request, slot: int,
+                 res: Optional[_ResumeState]) -> Tuple[jax.Array, int]:
+        """Admission: bind a block table (sharing every cached full
         prefix block), prefill only the uncached suffix, scatter the new
         blocks back, and register the prompt's full blocks for future hits.
         A resumed request prefills prompt + generated-so-far; its full
@@ -681,14 +631,13 @@ class Engine:
         return time.perf_counter() - self._t0
 
     def _kv_gauges(self) -> None:
-        """Publish pool occupancy; block-level detail on the paged pool."""
+        """Publish pool occupancy: rows, then blocks."""
         obs.gauge("engine.live_slots").set(self.pool.num_active)
         obs.gauge("engine.free_slots").set(self.pool.num_free)
-        if self.prefix_cache:
-            bp = self.pool.blocks
-            obs.gauge("kv.free_blocks").set(bp.num_free_blocks)
-            obs.gauge("kv.cached_blocks").set(bp.num_cached_blocks)
-            obs.gauge("kv.referenced_blocks").set(bp.num_referenced_blocks)
+        bp = self.pool.blocks
+        obs.gauge("kv.free_blocks").set(bp.num_free_blocks)
+        obs.gauge("kv.cached_blocks").set(bp.num_cached_blocks)
+        obs.gauge("kv.referenced_blocks").set(bp.num_referenced_blocks)
 
     def run(self, requests: List[Request], *,
             policy: str = "continuous",
@@ -762,12 +711,12 @@ class Engine:
                                 "(exceeds usable capacity)", done)
                 continue
             self._step(states, done)
-            if check_invariants and self.prefix_cache:
+            if check_invariants:
                 self.pool.blocks.check()
 
         if faults is not None:
             faults.drain_holds(self)
-        if check_invariants and self.prefix_cache:
+        if check_invariants:
             self.pool.blocks.check()
         self._faults = None
         self._queue = None
@@ -784,38 +733,36 @@ class Engine:
 
     def _step(self, states: Dict[int, _SlotState],
               done: List[Completion]) -> None:
-        """One pool-wide decode step: every live slot advances one token.
-        On the paged pool, KV backpressure (block exhaustion while making
-        write positions appendable) preempts youngest-first instead of
-        raising; preempted rows ride through the step masked-dead."""
+        """One pool-wide decode step: every live row advances one token.
+        KV backpressure (block exhaustion while making write positions
+        appendable) preempts youngest-first instead of raising; preempted
+        rows ride through the step masked-dead."""
         if self._faults is not None:
             self._faults.on_step(self, self.decode_steps)
         step = self.decode_steps
         with obs.span("decode_step", step=step, live=len(states),
                       batch=self.policy.num_slots) as dsp:
-            if self.prefix_cache:
-                # make each live row's write position physically writable
-                # (tail-block alloc / copy-on-write) before the device step
-                with obs.span("prepare_append", cat="kv", live=len(states)):
-                    for slot in list(states):
-                        if slot not in states:
-                            continue    # already preempted as a victim
-                        while slot in states:
-                            try:
-                                self.pool.prepare_append(slot)
-                                break
-                            except PoolExhausted:
-                                self._preempt(self._pick_victim(states),
-                                              states, done)
-                if not states:
-                    return      # every row was preempted: nothing to decode
+            # make each live row's write position physically writable
+            # (tail-block alloc / copy-on-write) before the device step
+            with obs.span("prepare_append", cat="kv", live=len(states)):
+                for slot in list(states):
+                    if slot not in states:
+                        continue    # already preempted as a victim
+                    while slot in states:
+                        try:
+                            self.pool.prepare_append(slot)
+                            break
+                        except PoolExhausted:
+                            self._preempt(self._pick_victim(states),
+                                          states, done)
+            if not states:
+                return      # every row was preempted: nothing to decode
             with obs.span("decode_inputs", step=step):
-                args = (self.params, jnp.asarray(self._last_tok[:, None]),
-                        self.pool.caches,
-                        jnp.asarray(np.asarray(self.pool.lengths, np.int32)))
-                if self.prefix_cache:
-                    args += (jnp.asarray(self.pool.tables()),)
-                logits, self.pool.caches = self._decode(*args)
+                logits, self.pool.caches = self._decode(
+                    self.params, jnp.asarray(self._last_tok[:, None]),
+                    self.pool.caches,
+                    jnp.asarray(np.asarray(self.pool.lengths, np.int32)),
+                    jnp.asarray(self.pool.tables()))
             with obs.span("sample", cat="sample",
                           batch=self.policy.num_slots):
                 toks = np.asarray(self._sample(
@@ -829,14 +776,13 @@ class Engine:
                     self.drift.observe("decode_step", dsp.dur_s)
                 obs.counter("engine.decode_steps").inc()
                 obs.counter("engine.decode_rows_live").inc(len(states))
-                if self.prefix_cache:
-                    # pages the paged-decode kernel reads: each live row's
-                    # KV (MLA: latent), the token this step wrote included
-                    bs, lens = self.pool.block_size, self.pool.lengths
-                    obs.counter("engine.decode_latent_pages"
-                                if self.cfg.attn_type == "mla"
-                                else "engine.decode_kv_pages").inc(
-                        sum(-(-(lens[s] + 1) // bs) for s in states))
+                # pages the paged-decode kernel reads: each live row's KV
+                # (MLA: latent), the token this step wrote included
+                bs, lens = self.pool.block_size, self.pool.lengths
+                obs.counter("engine.decode_latent_pages"
+                            if self.cfg.attn_type == "mla"
+                            else "engine.decode_kv_pages").inc(
+                    sum(-(-(lens[s] + 1) // bs) for s in states))
                 obs.counter("engine.tokens_generated").inc(len(states))
                 obs.histogram("engine.decode_step_s").observe(dsp.dur_s)
             for slot in list(states):

@@ -147,8 +147,6 @@ class FaultPlan:
         """Engine hook, called at the top of every decode step (before
         prepare_append): first expire due holds, then fire due events."""
         self._release_expired(engine, step)
-        if not engine.prefix_cache:
-            return              # block-level faults need the paged pool
         for i, ev in enumerate(self.events):
             if ev.step != step or i in self._fired:
                 continue

@@ -1,34 +1,26 @@
-"""KV-cache pools for the serving engine: contiguous slots and paged blocks.
+"""The serving engine's KV-cache pool: physical blocks named by block tables.
 
-Two pool designs share this module:
-
-`SlotPool` — slot = one contiguous KV region.  One cache pytree with batch
-dim = `num_slots` and sequence depth = `seq_max`, both snapped to the bucket
-lattice (`buckets.BucketPolicy`).  Requests borrow a slot for their
-lifetime; prefilled single-request caches are scattered into the pool at the
-slot index (donated, so the scatter is in-place on device), and a freed slot
-is simply marked length-0 — the stale bytes are masked by per-slot lengths
-everywhere downstream (decode masks, paged kernel) and overwritten by the
-next occupant's prefill.
-
-`BlockPool` + `PagedPool` — vLLM-style block-table indirection.  The KV
-space is a fixed pool of physical blocks of `block_size` tokens (the block
-size is a tile-lattice choice: snapped to the bucket lattice and picked from
-the `paged_decode_blocktable` tuning-cache entry, exactly like a GEMM
-blocking dimension).  A request's logical KV positions [j*bs, (j+1)*bs) live
-in physical block `table[j]`; full prompt blocks are content-addressed
-(chained SHA-256 over the token prefix) and shared across requests with
-refcounts, copy-on-write on divergence, and LRU eviction of unreferenced
-cached blocks under pressure.  `BlockPool` is the pure-host state machine
-(what the property-based tests drive); `PagedPool` wraps it with the device
-cache pytree and the jitted gather/scatter/copy programs the engine uses.
+vLLM-style block-table indirection.  The KV space is a fixed pool of
+physical blocks of `block_size` tokens (the block size is a tile-lattice
+choice: snapped to the bucket lattice and picked from the
+`paged_decode_blocktable` tuning-cache entry, exactly like a GEMM blocking
+dimension).  A request's logical KV positions [j*bs, (j+1)*bs) live in
+physical block `table[j]`; full prompt blocks are content-addressed (chained
+SHA-256 over the token prefix) and shared across requests with refcounts,
+copy-on-write on divergence, and LRU eviction of unreferenced cached blocks
+under pressure.  `BlockPool` is the pure-host state machine (what the
+property-based tests drive); `PagedPool` wraps it with the device cache
+pytree, a fixed lattice of decode rows, and the jitted gather/scatter/copy
+programs the engine uses.  A released row keeps its blocks' bytes: per-row
+lengths mask them everywhere downstream (the decode kernel reads only each
+row's live pages).
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,89 +30,6 @@ from ... import obs
 from ...configs.base import ModelConfig
 from ...models import init_caches
 from ...models.blocks import stack_plan
-
-
-def _update(pool_leaf, new_leaf, slot, axis: int):
-    return jax.lax.dynamic_update_slice_in_dim(
-        pool_leaf, new_leaf.astype(pool_leaf.dtype), slot, axis=axis)
-
-
-def _write_segment(kind: str, pool_seg, new_seg, slot):
-    """Scatter one segment's single-request cache into the pool at `slot`.
-
-    Cache leaves carry the scanned layer dim first, so batch is axis 1 —
-    except the SSM states inside a hybrid superblock, which stack the
-    per-superblock sub-layers ahead of batch (axis 2); mirrors
-    models.blocks.init_cache_segment.
-    """
-    if kind == "hybrid_super":
-        return {
-            "ssm": jax.tree.map(lambda p, n: _update(p, n, slot, 2),
-                                pool_seg["ssm"], new_seg["ssm"]),
-            "shared_attn": jax.tree.map(lambda p, n: _update(p, n, slot, 1),
-                                        pool_seg["shared_attn"],
-                                        new_seg["shared_attn"]),
-        }
-    return jax.tree.map(lambda p, n: _update(p, n, slot, 1),
-                        pool_seg, new_seg)
-
-
-def make_slot_writer(cfg: ModelConfig):
-    """jit'd (pool_caches, new_caches, slot) -> pool_caches, donating the
-    pool so the scatter updates buffers in place."""
-    kinds = [kind for kind, _ in stack_plan(cfg)]
-
-    def write(pool_caches, new_caches, slot):
-        return [
-            _write_segment(kind, pool_seg, new_seg, slot)
-            for kind, pool_seg, new_seg in zip(kinds, pool_caches, new_caches)
-        ]
-
-    return jax.jit(write, donate_argnums=(0,))
-
-
-class SlotPool:
-    """Host-side slot bookkeeping + the device cache pytree."""
-
-    def __init__(self, cfg: ModelConfig, num_slots: int, seq_max: int,
-                 dtype=jnp.bfloat16):
-        self.cfg = cfg
-        self.num_slots = num_slots
-        self.seq_max = seq_max
-        self.caches = init_caches(cfg, num_slots, seq_max, dtype)
-        self.lengths = [0] * num_slots   # live kv entries per slot
-        self._free: List[int] = list(range(num_slots - 1, -1, -1))
-        self._writer = make_slot_writer(cfg)
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def num_active(self) -> int:
-        return self.num_slots - len(self._free)
-
-    def alloc(self) -> Optional[int]:
-        return self._free.pop() if self._free else None
-
-    def can_admit(self, prompt_len: int) -> bool:
-        """A free slot always fits a validated prompt: slots are full
-        seq_max-deep regions, so depth was checked at validation time."""
-        return bool(self._free)
-
-    def release(self, slot: int) -> None:
-        self.lengths[slot] = 0
-        self._free.append(slot)
-
-    def write(self, slot: int, new_caches: Any, length: int) -> None:
-        """Install a prefilled batch-1 cache pytree into `slot`."""
-        self.caches = self._writer(self.caches, new_caches,
-                                   jnp.asarray(slot, jnp.int32))
-        self.lengths[slot] = length
-
-    def advance(self, slot: int) -> None:
-        """One decode token was written at position `lengths[slot]`."""
-        self.lengths[slot] += 1
 
 
 # --- block-table pool ----------------------------------------------------------------
@@ -242,8 +151,7 @@ class BlockPool:
 
     # -- sequence lifecycle ---------------------------------------------------
 
-    def alloc_sequence(self, tokens: Sequence[int], *,
-                       prefix_cache: bool = True
+    def alloc_sequence(self, tokens: Sequence[int]
                        ) -> Tuple[BlockSeq, List[CowCopy]]:
         """Build a block table covering `tokens` (a prompt).
 
@@ -263,19 +171,18 @@ class BlockPool:
         cows: List[CowCopy] = []
         matched = 0
         parent: Optional[bytes] = None
-        if prefix_cache:
-            while (matched + 1) * bs <= n:
-                key = self._chain_key(parent, tokens[matched * bs:(matched + 1) * bs])
-                blk = self._hash.get(key)
-                if blk is None:
-                    break
-                if self.ref[blk] == 0:
-                    self._take_cached(blk)
-                else:
-                    self.ref[blk] += 1
-                table.append(blk)
-                parent = key
-                matched += 1
+        while (matched + 1) * bs <= n:
+            key = self._chain_key(parent, tokens[matched * bs:(matched + 1) * bs])
+            blk = self._hash.get(key)
+            if blk is None:
+                break
+            if self.ref[blk] == 0:
+                self._take_cached(blk)
+            else:
+                self.ref[blk] += 1
+            table.append(blk)
+            parent = key
+            matched += 1
         num_cached = matched * bs
         if obs.enabled() and matched:
             obs.counter("kv.prefix_hit_blocks").inc(matched)
@@ -489,12 +396,12 @@ class PagedPool:
     cache pytree + a fixed lattice of decode rows.
 
     The decode batch stays a bucketed constant (`num_rows` — the sublane dim
-    of every decode GEMM), but each row's KV now lives in `seq_max //
-    block_size` physical blocks named by a block table instead of one
-    contiguous slot.  Capacity is `num_rows * seq_max / block_size` blocks —
-    the SlotPool byte budget — plus one reserved garbage block (device index
-    `num_blocks`) that dead rows point at and nothing ever reads, so prefix
-    sharing strictly adds headroom for the cached-free pool.
+    of every decode GEMM), and each row's KV lives in up to `seq_max //
+    block_size` physical blocks named by a block table.  Capacity is
+    `num_rows * seq_max / block_size` blocks by default (every row at full
+    depth) plus one reserved garbage block (device index `num_blocks`) that
+    dead rows point at and nothing ever reads, so prefix sharing strictly
+    adds headroom for the cached-free pool.
     """
 
     def __init__(self, cfg: ModelConfig, num_rows: int, seq_max: int,
@@ -515,7 +422,7 @@ class PagedPool:
         self.row_seq: List[Optional[BlockSeq]] = [None] * num_rows
         self._free_rows: List[int] = list(range(num_rows - 1, -1, -1))
 
-    # -- SlotPool-compatible row interface (Scheduler speaks this) ------------
+    # -- row interface (Scheduler speaks this) ---------------------------------
 
     @property
     def num_free(self) -> int:

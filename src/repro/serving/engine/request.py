@@ -93,8 +93,8 @@ class Completion:
         return self.first_token_s - self.arrival_s
 
     itl_s: List[float] = dataclasses.field(default_factory=list)
-    # prompt tokens whose KV came from the prefix cache (block-table engine;
-    # 0 on the slot pool / a cold prompt) — these skipped prefill entirely
+    # prompt tokens whose KV came from the prefix cache (0 for a cold
+    # prompt) — these skipped prefill entirely
     cached_tokens: int = 0
     finish_reason: str = "length"
     detail: str = ""              # human-readable cause for non-ok reasons
@@ -129,7 +129,7 @@ class EngineStats:
     ttft_p99_s: float
     itl_p50_s: float
     itl_p99_s: float
-    # prefix-cache accounting (block-table engine; all zero on the slot pool)
+    # prefix-cache accounting
     cache_hit_requests: int = 0   # requests with >= 1 cached prompt token
     cached_tokens: int = 0        # prompt tokens served from the cache
     prompt_tokens: int = 0

@@ -37,7 +37,7 @@ import bisect
 import dataclasses
 from typing import List, Optional, Tuple
 
-from .kv_pool import SlotPool
+from .kv_pool import PagedPool
 from .request import Request
 
 
@@ -109,7 +109,7 @@ class Scheduler:
     """Decides which queued requests enter which slots at each engine tick,
     and which get shed by admission control."""
 
-    def __init__(self, queue: RequestQueue, pool: SlotPool,
+    def __init__(self, queue: RequestQueue, pool: PagedPool,
                  policy: str = "continuous",
                  shed: Optional[ShedPolicy] = None):
         assert policy in ("continuous", "static"), policy

@@ -23,15 +23,13 @@ from .candidates import (bucket_steps, flash_backward_candidates,
                          fused_mlp_vmem_bytes, int8_fused_mlp_candidates,
                          int8_fused_mlp_vmem_bytes, int8_matmul_candidates,
                          int8_matmul_vmem_bytes, matmul_candidates,
-                         matmul_vmem_bytes, paged_blocktable_candidates,
-                         paged_decode_candidates)
+                         matmul_vmem_bytes, paged_blocktable_candidates)
 from .measure import wall_us
 
 _SEARCH_EXPORTS = ("autotune_matmul", "autotune_flash_attention",
                    "autotune_flash_backward", "autotune_fused_mlp",
                    "autotune_int8_matmul", "autotune_fp8_matmul",
                    "autotune_int8_fused_mlp",
-                   "autotune_paged_decode",
                    "autotune_paged_decode_blocktable",
                    "flash_op_name", "flash_bwd_op_name")
 
@@ -45,7 +43,6 @@ __all__ = [
     "int8_fused_mlp_candidates", "int8_fused_mlp_vmem_bytes",
     "int8_matmul_candidates", "int8_matmul_vmem_bytes",
     "matmul_candidates", "matmul_vmem_bytes", "paged_blocktable_candidates",
-    "paged_decode_candidates",
     "wall_us", *_SEARCH_EXPORTS,
 ]
 

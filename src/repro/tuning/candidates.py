@@ -277,33 +277,6 @@ def int8_fused_mlp_candidates(m: int, h: int, f: int,
         hw, 1, max_candidates)
 
 
-def paged_decode_candidates(s_max: int, head_dim: int, group: int = 1,
-                            hw: Hardware | None = None, dtype_bytes: int = 2,
-                            max_candidates: int | None = None) -> List[int]:
-    """block_kv values worth timing for the paged decode kernel.
-
-    The score tile is (group, block_kv) with group = query heads per kv head,
-    so only the lane-side block is searchable; candidates are lane-aligned
-    and bounded by the streaming VMEM working set (the flash budget at
-    block_q = group).  The 128 default is always included."""
-    hw = hw or get_hardware()
-    lane = lane_granule(hw)
-    cands = [bkv for bkv in _steps(s_max, lane)
-             if flash_vmem_bytes(group, bkv, head_dim, dtype_bytes)
-             <= hw.sram_bytes]
-    cands.sort(key=lambda c: -c)
-    default = 128
-    if default not in cands and flash_vmem_bytes(
-            group, default, head_dim, dtype_bytes) <= hw.sram_bytes:
-        cands.append(default)
-    if max_candidates is not None and len(cands) > max_candidates:
-        keep = cands[:max_candidates]
-        if default in cands and default not in keep:
-            keep[-1] = default
-        cands = keep
-    return cands
-
-
 def paged_blocktable_candidates(seq_max: int, head_dim: int, group: int = 1,
                                 hw: Hardware | None = None,
                                 dtype_bytes: int = 2,

@@ -28,13 +28,11 @@ from .cache import TunedConfig, TuningCache, get_default_cache, mixed_dtype
 from .candidates import (flash_backward_candidates, flash_candidates,
                          fp8_matmul_candidates, fused_mlp_candidates,
                          int8_fused_mlp_candidates, int8_matmul_candidates,
-                         matmul_candidates, paged_blocktable_candidates,
-                         paged_decode_candidates)
+                         matmul_candidates, paged_blocktable_candidates)
 from .measure import wall_us
 
 DEFAULT_MATMUL_BLOCKS = (128, 128, 128)
 DEFAULT_FLASH_BLOCKS = (128, 128)
-DEFAULT_PAGED_BLOCK_KV = 128
 DEFAULT_FUSED_MLP_BLOCKS = (128, 128, 128)
 
 
@@ -327,59 +325,6 @@ def autotune_int8_fused_mlp(m: int, h: int, f: int, *,
     return cfg
 
 
-def autotune_paged_decode(batch: int, slots: int, s_max: int, kv_heads: int,
-                          heads: int, head_dim: int, *, dtype=jnp.float32,
-                          hw: Optional[Hardware] = None,
-                          cache: Optional[TuningCache] = None,
-                          interpret: Optional[bool] = None, iters: int = 3,
-                          warmup: int = 1,
-                          max_candidates: Optional[int] = None,
-                          verbose: bool = False) -> TunedConfig:
-    """Sweep block_kv for the serving engine's paged decode kernel over a
-    (slots, s_max, kv_heads, head_dim) KV pool with `batch` active rows;
-    persist and return the measured winner (op "paged_decode")."""
-    from ..kernels.flash_attention.ops import paged_decode
-
-    hw = hw or get_hardware()
-    cache = cache if cache is not None else get_default_cache()
-    dtype_bytes = jnp.dtype(dtype).itemsize
-    g = heads // kv_heads
-    cands = paged_decode_candidates(s_max, head_dim, g, hw, dtype_bytes,
-                                    max_candidates=max_candidates)
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (batch, heads, head_dim)).astype(dtype)
-    pool_shape = (slots, s_max, kv_heads, head_dim)
-    kp = jax.random.normal(jax.random.fold_in(key, 1), pool_shape).astype(dtype)
-    vp = jax.random.normal(jax.random.fold_in(key, 2), pool_shape).astype(dtype)
-    slot_idx = jnp.arange(batch, dtype=jnp.int32) % slots
-    lengths = jnp.full((batch,), s_max, jnp.int32)
-
-    trials: List[Trial] = []
-    baseline_us = 0.0
-    for bkv in cands:
-        t, std = _measure(
-            "paged_decode",
-            lambda q, kp, vp, si, ln, bkv=bkv: paged_decode(
-                q, kp, vp, si, ln, block_kv=bkv, interpret=interpret),
-            q, kp, vp, slot_idx, lengths, iters=iters, warmup=warmup)
-        trials.append(Trial((bkv,), t, std))
-        if bkv == DEFAULT_PAGED_BLOCK_KV:
-            baseline_us = t
-        if verbose:
-            print(f"  paged b{batch} pool{slots}x{s_max} kv{kv_heads} "
-                  f"d{head_dim} block_kv={bkv}: {t:.1f} us")
-    best = min(trials, key=lambda t: t.time_us)
-    cfg = TunedConfig(
-        op="paged_decode",
-        shape=(batch, slots, s_max, kv_heads, heads, head_dim),
-        dtype=_dtype_name(dtype), hw_name=hw.name,
-        blocks={"block_kv": best.blocks[0]},
-        time_us=best.time_us, baseline_us=baseline_us,
-        candidates_tried=len(trials), time_us_std=best.time_us_std)
-    cache.put(cfg)
-    return cfg
-
-
 def autotune_paged_decode_blocktable(batch: int, num_rows: int, s_max: int,
                                      kv_heads: int, heads: int,
                                      head_dim: int, *, dtype=jnp.float32,
@@ -402,8 +347,8 @@ def autotune_paged_decode_blocktable(batch: int, num_rows: int, s_max: int,
     Two kinds of cache entry are written:
       * op "paged_decode_blocktable_pool", shape (batch, num_rows, s_max,
         kv_heads, heads, head_dim), blocks {block_size, block_kv} — the
-        engine-level entry `ServeEngine(prefix_cache=True)` consults to pick
-        its physical block size;
+        engine-level entry `Engine._pick_block_size` consults to pick its
+        physical block size;
       * op "paged_decode_blocktable", shape (batch, num_blocks, block_size,
         kv_heads, heads, head_dim), blocks {block_kv} — one per block_size
         tried (best block_kv at that size), so
@@ -457,7 +402,7 @@ def autotune_paged_decode_blocktable(batch: int, num_rows: int, s_max: int,
             time_us_std=std))
     best = min(trials, key=lambda t: t.time_us)
     # baseline for the speedup quote: the coarsest paging granule tried
-    # (one block = whole sequence, i.e. the slot-pool layout)
+    # (at most one block = one whole sequence)
     bs_max = max(bs for bs, _ in cands)
     baseline_us = min((t.time_us for t in trials if t.blocks[0] == bs_max),
                       default=0.0)

@@ -146,30 +146,37 @@ class TestTunedDispatch:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-4, rtol=2e-4)
 
-    def test_autotune_then_tuned_paged_decode_matches_ref(self):
-        from repro.kernels.flash_attention.ops import paged_decode
-        from repro.kernels.flash_attention.ref import paged_decode_ref
-        from repro.tuning.search import autotune_paged_decode
-        b, slots, s_max, nkv, heads, d = 3, 4, 128, 2, 4, 32
+    def test_autotune_then_tuned_paged_decode_blocktable_matches_ref(self):
+        from repro.kernels.flash_attention.ops import paged_decode_blocktable
+        from repro.kernels.flash_attention.ref import \
+            paged_decode_blocktable_ref
+        from repro.tuning.search import autotune_paged_decode_blocktable
+        b, rows, s_max, nkv, heads, d = 3, 4, 128, 2, 4, 32
         cache = TuningCache()
-        cfg = autotune_paged_decode(b, slots, s_max, nkv, heads, d,
-                                    cache=cache, iters=1, warmup=1,
-                                    max_candidates=2)
-        assert cfg.blocks["block_kv"] % lane_granule(HW) == 0
-        assert cache.get("paged_decode", (b, slots, s_max, nkv, heads, d),
-                         "float32", "tpu_v5e") == cfg
+        pool = autotune_paged_decode_blocktable(
+            b, rows, s_max, nkv, heads, d, cache=cache, iters=1, warmup=1,
+            max_candidates=2)
+        bs, bkv = pool.blocks["block_size"], pool.blocks["block_kv"]
+        assert s_max % bs == 0 and bkv % bs == 0
+        assert cache.get("paged_decode_blocktable_pool",
+                         (b, rows, s_max, nkv, heads, d), "float32",
+                         "tpu_v5e") == pool
+        # the kernel-level entry for the winning page size, at its pool
+        nb = rows * (s_max // bs)
+        kernel = cache.get("paged_decode_blocktable",
+                           (b, nb, bs, nkv, heads, d), "float32", "tpu_v5e")
+        assert kernel is not None and kernel.blocks["block_kv"] == bkv
         set_default_cache(cache)
         key = jax.random.PRNGKey(8)
         q = jax.random.normal(key, (b, heads, d))
-        kp = jax.random.normal(jax.random.fold_in(key, 1),
-                               (slots, s_max, nkv, d))
-        vp = jax.random.normal(jax.random.fold_in(key, 2),
-                               (slots, s_max, nkv, d))
-        slot_idx = jnp.asarray([2, 0, 3], jnp.int32)
+        kb = jax.random.normal(jax.random.fold_in(key, 1), (nb, bs, nkv, d))
+        vb = jax.random.normal(jax.random.fold_in(key, 2), (nb, bs, nkv, d))
+        tables = jnp.asarray(np.random.default_rng(4).permutation(nb)[
+            :b * (s_max // bs)].reshape(b, -1), jnp.int32)
         lengths = jnp.asarray([5, 64, 128], jnp.int32)
-        got = paged_decode(q, kp, vp, slot_idx, lengths, tuned=True,
-                           interpret=True)
-        want = paged_decode_ref(q, kp, vp, slot_idx, lengths)
+        got = paged_decode_blocktable(q, kb, vb, tables, lengths, tuned=True,
+                                      interpret=True)
+        want = paged_decode_blocktable_ref(q, kb, vb, tables, lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-4, rtol=2e-4)
 

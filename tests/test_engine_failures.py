@@ -176,14 +176,14 @@ class TestPreemption:
         and the block-pool invariants hold throughout."""
         cfg, params = smoke_lm
         roomy = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=32,
-                       prefix_cache=True, block_size=8)
+                       block_size=8)
         reqs = synthetic_requests(8, pattern="burst", min_prompt=12,
                                   max_prompt=28, min_new=24, max_new=30,
                                   vocab=cfg.vocab_size, seed=5)
         want = {c.rid: c.tokens for c in roomy.run(reqs)[0]}
         # 8 rows x up to ceil(58/8)=8 blocks each want 64 blocks; give 24
         tight = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=32,
-                       prefix_cache=True, block_size=8, num_blocks=24)
+                       block_size=8, num_blocks=24)
         done, stats = tight.run(reqs, check_invariants=True)
         assert stats.preemptions > 0, "starved pool must preempt"
         assert stats.resumes > 0, "some preempted request must resume"
@@ -206,7 +206,7 @@ class TestPreemption:
         the fault-free tokens (exact rollback + seeded sampler resume)."""
         cfg, params = smoke_lm
         eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=16,
-                     prefix_cache=True, block_size=8, num_blocks=32)
+                     block_size=8, num_blocks=32)
         reqs = synthetic_requests(8, pattern="burst", min_prompt=12,
                                   max_prompt=28, min_new=10, max_new=14,
                                   vocab=cfg.vocab_size, temperature=0.7,
@@ -232,7 +232,7 @@ class TestChaosSoak:
         after every step, token-identical outputs for unaffected rids."""
         cfg, params = smoke_lm
         eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8,
-                     prefix_cache=True, block_size=8, num_blocks=48)
+                     block_size=8, num_blocks=48)
         reqs = synthetic_requests(64, pattern="bursty", min_prompt=4,
                                   max_prompt=28, min_new=3, max_new=7,
                                   vocab=cfg.vocab_size, prefix_share=0.4,

@@ -54,8 +54,7 @@ def _served_tokens(params, cfg, swap=None):
     rng = np.random.RandomState(0)
     reqs = [Request(rid=i, tokens=rng.randint(0, cfg.vocab_size, n).astype(
         np.int32), max_new_tokens=6) for i, n in enumerate([5, 19, 30])]
-    eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8,
-                 prefix_cache=True, use_paged_kernel=True)
+    eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8)
     if swap is not None:
         eng.params = swap       # the caller's tree, cast in every call
     done, _ = eng.run(reqs)
@@ -102,8 +101,7 @@ def test_prefill_and_decode_logits_bit_identical(case):
         pre, caches, _ = apply_lm(p, tokens, cfg, caches=caches,
                                   cache_index=0)
         nxt = jnp.argmax(pre[:, -1], -1).astype(jnp.int32)[:, None]
-        dec, _, _ = apply_lm(p, nxt, cfg, caches=caches,
-                             cache_index=jnp.full((2,), 12, jnp.int32),
+        dec, _, _ = apply_lm(p, nxt, cfg, caches=caches, cache_index=12,
                              decode=True)
         return pre, dec
 

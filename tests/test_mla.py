@@ -145,7 +145,7 @@ def test_prefill_chunks_queries_and_matches_one_chunk():
 
 
 def test_engine_serves_mla_moe_share_like_the_full_forward():
-    """prefix_cache + paged kernel, an expert share of 4 of 8 experts:
+    """The engine's latent block pool and paged kernel, a share of 4 of 8:
     every served token is the greedy token of the full (uncached) forward
     of the same share over the prompt and the tokens served before it."""
     from repro.serving.engine import Engine
@@ -155,8 +155,7 @@ def test_engine_serves_mla_moe_share_like_the_full_forward():
     rng = np.random.RandomState(0)
     reqs = [Request(rid=i, tokens=rng.randint(0, cfg.vocab_size, n).astype(
         np.int32), max_new_tokens=5) for i, n in enumerate([5, 17, 30])]
-    eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8,
-                 prefix_cache=True, use_paged_kernel=True)
+    eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8)
     done, _ = eng.run(reqs)
     assert eng.cfg.moe_dispatch == "dropless"
     full = dataclasses.replace(cfg, moe_dispatch="dropless")
@@ -180,4 +179,4 @@ def test_engine_rejects_int8_latent_cache():
     cfg = _mla_cfg()
     with pytest.raises(ValueError, match="mla"):
         Engine(init_lm(KEY, cfg), cfg, max_batch=2, max_prompt=16,
-               max_new=4, prefix_cache=True, kv_dtype="int8")
+               max_new=4, kv_dtype="int8")

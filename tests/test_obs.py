@@ -318,12 +318,14 @@ class TestEngineObservability:
 
             # inject an out-of-lattice shape: the armed watchdog must fire
             # from inside the offending jit call
+            contig = eng.pool.gather(0)     # a row's cache view, as admit
             watch.arm()
             bucket = eng.policy.prompt_buckets[0]
             bad = np.zeros((1, bucket + 3), np.int32)  # width off the lattice
             with pytest.raises(obs.UnexpectedCompile):
                 eng._prefills[bucket](params, jnp.asarray(bad),
-                                      jnp.asarray(1, jnp.int32))
+                                      jnp.asarray(1, jnp.int32),
+                                      jnp.asarray(0, jnp.int32), contig)
             assert watch.violations
             watch.disarm()
         finally:
@@ -348,11 +350,10 @@ def _shared_prefix_requests(n, *, vocab, gap_s=0.0, seed=5):
     return reqs
 
 
-def _tiny_engine(smoke_lm, prefix_cache):
+def _tiny_engine(smoke_lm, kv_dtype="auto"):
     cfg, params = smoke_lm
-    kw = dict(block_size=8, num_blocks=64) if prefix_cache else {}
     return Engine(params, cfg, max_batch=3, max_prompt=32, max_new=8,
-                  prefix_cache=prefix_cache, **kw)
+                  block_size=8, num_blocks=64, kv_dtype=kv_dtype)
 
 
 class TestEngineLoopTracing:
@@ -361,11 +362,11 @@ class TestEngineLoopTracing:
     spans that account for the run's wall time, and counters that agree
     with what the completions and the spans say happened."""
 
-    @pytest.mark.parametrize("prefix_cache", [True, False])
+    @pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
     def test_same_host_syncs_with_obs_on_and_off(self, smoke_lm,
-                                                 prefix_cache, monkeypatch):
+                                                 kv_dtype, monkeypatch):
         from jax._src.array import ArrayImpl
-        eng = _tiny_engine(smoke_lm, prefix_cache)
+        eng = _tiny_engine(smoke_lm, kv_dtype)
         reqs = _shared_prefix_requests(6, vocab=smoke_lm[0].vocab_size)
         eng.run(reqs)                               # compile everything
         counts = {"sync": 0, "readback": 0}
@@ -408,7 +409,7 @@ class TestEngineLoopTracing:
         assert eng.drift is None
 
     def test_loop_spans_cover_the_run(self, smoke_lm):
-        eng = _tiny_engine(smoke_lm, prefix_cache=True)
+        eng = _tiny_engine(smoke_lm)
         warm = _shared_prefix_requests(6, vocab=smoke_lm[0].vocab_size)
         eng.run(warm)
         obs.enable(annotate_device=False)
@@ -429,16 +430,15 @@ class TestEngineLoopTracing:
         assert all(e["args"]["depth"] == 1 for e in by("decode_inputs"))
         assert len(by("decode_inputs")) == stats.decode_steps
 
-    @pytest.mark.parametrize("prefix_cache", [True, False])
+    @pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
     def test_counters_match_completions_and_spans(self, smoke_lm,
-                                                  prefix_cache):
-        eng = _tiny_engine(smoke_lm, prefix_cache)
+                                                  kv_dtype):
+        eng = _tiny_engine(smoke_lm, kv_dtype)
         reqs = _shared_prefix_requests(8, vocab=smoke_lm[0].vocab_size)
         obs.enable(annotate_device=False)
         done, stats = eng.run(reqs)
         assert all(c.ok for c in done) and stats.preemptions == 0
-        if prefix_cache:
-            assert sum(c.cached_tokens for c in done) > 0
+        assert sum(c.cached_tokens for c in done) > 0
         ran = [c.prompt_len - c.cached_tokens for c in done]
         live = [e["args"]["live"] for e in obs.get_tracer().events()
                 if e["name"] == "decode_step"]
@@ -453,7 +453,7 @@ class TestEngineLoopTracing:
         """`engine.decode_kv_pages` is the pages the paged-decode kernel
         reads: ceil(kv length / block_size) over the live rows of each
         decode call, the kv length counting the token the call writes."""
-        eng = _tiny_engine(smoke_lm, prefix_cache=True)
+        eng = _tiny_engine(smoke_lm)
         reqs = _shared_prefix_requests(8, vocab=smoke_lm[0].vocab_size)
         decode, pages = eng._decode, []
 

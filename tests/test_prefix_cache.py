@@ -11,10 +11,10 @@ prefix-cache engine path end to end:
     shadow block contents that asserts COW never lets one sequence observe
     another's writes (runs even without hypothesis; the hypothesis twin
     lives in test_property.py);
-  * the engine contract: greedy outputs with prefix_cache=True are
-    byte-identical to the non-cached engine, on an 80% shared / 20% cold
-    workload, across divergence after a shared prefix, and under eviction
-    pressure.
+  * the engine contract: greedy outputs through the block pool and its
+    prefix cache are byte-identical to the reference loop
+    (`serve_step.greedy_generate`), on an 80% shared / 20% cold workload,
+    across divergence after a shared prefix, and under eviction pressure.
 """
 import jax
 import jax.numpy as jnp
@@ -59,10 +59,12 @@ class TestBlockTableKernel:
         lengths = jnp.asarray([50, 64, 17, 0, 33], jnp.int32)  # 0 = dead row
         return q, kp, vp, tables, lengths
 
-    @pytest.mark.parametrize("block_kv", [None, 8, 16, 12])
+    @pytest.mark.parametrize("block_kv", [None, 8, 16, 12, 32, 48])
     def test_vs_ref(self, block_kv):
         q, kp, vp, tables, lengths = self._inputs()
-        # 8, 12 and 16 round up to a chunk of one block of 16; None is the
+        # 8, 12 and 16 round up to a chunk of one block of 16; 32 is two
+        # blocks a chunk; 48 is three, which do not divide the table's 4, so
+        # the 50- and 64-token rows end on a partial chunk; None is the
         # default chunk, capped at the table's 4 blocks
         got = paged_decode_blocktable(q, kp, vp, tables, lengths,
                                       block_kv=block_kv, interpret=True)
@@ -393,16 +395,16 @@ class TestPagedPoolDevice:
 
 
 def _run_pair(cfg, params, reqs, *, policy=None, block_size=8, **kw):
-    """Run the same workload through the slot engine and the prefix-cache
-    engine; assert byte-identical greedy tokens; return the paged stats."""
-    base = Engine(params, cfg, policy=policy, **kw)
-    done_b, _ = base.run(reqs)
-    eng = Engine(params, cfg, policy=policy, prefix_cache=True,
-                 block_size=block_size, **kw)
+    """Run the workload through the engine and each request through the
+    reference greedy loop; assert byte-identical tokens; return the engine,
+    its stats and its completions."""
+    eng = Engine(params, cfg, policy=policy, block_size=block_size, **kw)
     done_p, stats = eng.run(reqs)
-    assert [c.rid for c in done_p] == [c.rid for c in done_b]
-    for a, b in zip(done_b, done_p):
-        assert a.tokens == b.tokens, f"rid {a.rid}: cache changed tokens"
+    assert [c.rid for c in done_p] == [r.rid for r in reqs]
+    for r, c in zip(reqs, done_p):
+        want = np.asarray(greedy_generate(
+            params, cfg, jnp.asarray(r.tokens[None]), r.max_new_tokens))[0]
+        assert c.tokens == want.tolist(), f"rid {r.rid}: cache changed tokens"
     eng.pool.blocks.check()
     assert eng.pool.num_free == eng.policy.num_slots    # all rows released
     return eng, stats, done_p
@@ -446,7 +448,7 @@ class TestPrefixCacheEngine:
             Request(rid=2, tokens=P.copy(), max_new_tokens=6),  # full hit
         ]
         eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8,
-                     prefix_cache=True, block_size=8)
+                     block_size=8)
         done, _ = eng.run(reqs)
         for r, c in zip(reqs, done):
             want = np.asarray(greedy_generate(
@@ -482,7 +484,7 @@ class TestPrefixCacheEngine:
                                   vocab=cfg.vocab_size, prefix_share=0.8,
                                   shared_prefix_len=16, seed=17)
         eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8,
-                     prefix_cache=True, block_size=8, use_paged_kernel=True)
+                     block_size=8)
         assert eng.cfg.attn_impl == "paged"
         done, stats = eng.run(reqs)
         for r, c in zip(reqs, done):

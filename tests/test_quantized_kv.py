@@ -9,10 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config, get_smoke_config
-from repro.kernels.flash_attention.ops import (paged_decode,
-                                               paged_decode_blocktable)
-from repro.kernels.flash_attention.ref import (paged_decode_blocktable_ref,
-                                               paged_decode_ref)
+from repro.kernels.flash_attention.ops import paged_decode_blocktable
+from repro.kernels.flash_attention.ref import paged_decode_blocktable_ref
 from repro.models import apply_lm, init_caches, init_lm
 from repro.models.blocks import KV_DTYPES, kv_cache_dtype
 from repro.quant import dequantize_kv, kv_bytes_per_token, quantize_kv
@@ -88,10 +86,10 @@ class TestQuantizeKV:
 
 # -- dequantizing paged kernels ----------------------------------------------
 
-def _quant_pools(slots, s_max, nkv, d):
-    kp = jax.random.normal(KEY, (slots, s_max, nkv, d)) * 0.5
+def _quant_pools(nb, bs, nkv, d):
+    kp = jax.random.normal(KEY, (nb, bs, nkv, d)) * 0.5
     vp = jax.random.normal(jax.random.fold_in(KEY, 1),
-                           (slots, s_max, nkv, d)) * 0.5
+                           (nb, bs, nkv, d)) * 0.5
     kq, ks = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
     return (kq, ks, vq, vs,
@@ -100,27 +98,15 @@ def _quant_pools(slots, s_max, nkv, d):
 
 
 class TestQuantizedPagedDecode:
-    @pytest.mark.parametrize("use_pallas", [True, False])
-    def test_slot_variant_vs_dequantized_ref(self, use_pallas):
-        slots, s_max, nkv, d, b = 8, 128, 2, 32, 4
-        kq, ks, vq, vs, kd, vd = _quant_pools(slots, s_max, nkv, d)
-        q = jax.random.normal(jax.random.fold_in(KEY, 2), (b, nkv * 3, d))
-        slot_idx = jnp.asarray([5, 0, 7, 2], jnp.int32)
-        lengths = jnp.asarray([17, 0, 128, 64], jnp.int32)  # 0 = dead slot
-        got = paged_decode(q, kq, vq, slot_idx, lengths, k_scale=ks,
-                           v_scale=vs, block_kv=64, interpret=True,
-                           use_pallas=use_pallas)
-        want = paged_decode_ref(q, kd, vd, slot_idx, lengths)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=3e-5, rtol=3e-5)
-        assert np.all(np.asarray(got)[1] == 0.0)  # dead slot stays zero
-
     # block_kv=64 is a chunk of two blocks: length 100 crosses a chunk
-    # boundary and 128 fills two chunks; None takes the whole table at once
+    # boundary and 128 fills two chunks; 96 is a chunk of three blocks, so
+    # the last chunk of a 4-block table is partial; None takes the whole
+    # table at once
     @pytest.mark.parametrize("use_pallas,block_kv", [
         pytest.param(True, None, id="True"),
         pytest.param(False, None, id="False"),
-        pytest.param(True, 64, id="True-chunk_boundary")])
+        pytest.param(True, 64, id="True-chunk_boundary"),
+        pytest.param(True, 96, id="True-partial_chunk")])
     def test_blocktable_variant_vs_dequantized_ref(self, use_pallas,
                                                    block_kv):
         nb, bs, nkv, d, b, max_blocks = 12, 32, 2, 32, 3, 4
@@ -136,19 +122,39 @@ class TestQuantizedPagedDecode:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=3e-5, rtol=3e-5)
 
+    @pytest.mark.parametrize("use_pallas", [True, False])
+    def test_blocktable_dead_row_permuted_vs_dequantized_ref(self,
+                                                             use_pallas):
+        """A dead row (length 0) among rows whose tables name the pool's
+        blocks out of order, one block shared by two rows."""
+        nb, bs, nkv, d, b = 12, 32, 2, 32, 4
+        kq, ks, vq, vs, kd, vd = _quant_pools(nb, bs, nkv, d)
+        q = jax.random.normal(jax.random.fold_in(KEY, 4), (b, nkv * 3, d))
+        tables = jnp.asarray([[9, 2, 5, 11], [0, 0, 0, 0], [6, 10, 1, 3],
+                              [2, 8, 7, 4]], jnp.int32)
+        lengths = jnp.asarray([97, 0, 128, 33], jnp.int32)   # 0 = dead row
+        got = np.asarray(paged_decode_blocktable(
+            q, kq, vq, tables, lengths, k_scale=ks, v_scale=vs,
+            interpret=True, use_pallas=use_pallas))
+        want = paged_decode_blocktable_ref(q, kd, vd, tables, lengths)
+        np.testing.assert_allclose(got, np.asarray(want), atol=3e-5,
+                                   rtol=3e-5)
+        assert np.all(got[1] == 0.0)        # dead row stays zero
+
     def test_quant_close_to_float_pool(self):
         """End-to-end quantization noise on attention outputs stays small."""
-        slots, s_max, nkv, d, b = 4, 64, 2, 32, 2
-        kq, ks, vq, vs, _, _ = _quant_pools(slots, s_max, nkv, d)
-        kp = jax.random.normal(KEY, (slots, s_max, nkv, d)) * 0.5
+        nb, bs, nkv, d, b = 8, 16, 2, 32, 2
+        kq, ks, vq, vs, _, _ = _quant_pools(nb, bs, nkv, d)
+        kp = jax.random.normal(KEY, (nb, bs, nkv, d)) * 0.5
         vp = jax.random.normal(jax.random.fold_in(KEY, 1),
-                               (slots, s_max, nkv, d)) * 0.5
+                               (nb, bs, nkv, d)) * 0.5
         q = jax.random.normal(jax.random.fold_in(KEY, 2), (b, nkv, d))
-        idx = jnp.asarray([0, 3], jnp.int32)
+        tables = jnp.asarray([[0, 1, 2, 3], [7, 6, 0, 0]], jnp.int32)
         lens = jnp.asarray([64, 32], jnp.int32)
-        got = np.asarray(paged_decode(q, kq, vq, idx, lens, k_scale=ks,
-                                      v_scale=vs, interpret=True))
-        want = np.asarray(paged_decode_ref(q, kp, vp, idx, lens))
+        got = np.asarray(paged_decode_blocktable(
+            q, kq, vq, tables, lens, k_scale=ks, v_scale=vs, interpret=True))
+        want = np.asarray(paged_decode_blocktable_ref(q, kp, vp, tables,
+                                                      lens))
         rel = np.abs(got - want).max() / np.abs(want).max()
         assert rel < 0.05
 
@@ -230,7 +236,7 @@ class TestEngineInt8KV:
                                   max_prompt=20, min_new=3, max_new=6,
                                   vocab=cfg.vocab_size, seed=23)
         eng = Engine(params, cfg, max_batch=4, max_prompt=32, max_new=8,
-                     use_paged_kernel=True, kv_dtype="int8")
+                     kv_dtype="int8")
         assert eng.cfg.attn_impl == "paged" and eng.cfg.kv_dtype == "int8"
         done, _ = eng.run(reqs)
         self._check(eng.cfg, params, reqs, done)

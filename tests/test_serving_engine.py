@@ -1,20 +1,19 @@
-"""Serving engine: token parity vs greedy_generate, paged kernel vs oracle,
-bucket policy invariants, scheduler behavior."""
+"""Serving engine: token parity vs greedy_generate, the one block-table
+pool (prefix_cache / use_paged_kernel False refused, per-row cache writes
+only through block tables), bucket policy invariants, scheduler behavior."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.registry import get_smoke_config
-from repro.kernels.flash_attention.ops import paged_decode
-from repro.kernels.flash_attention.ref import paged_decode_ref
-from repro.models import init_lm
+from repro.models import apply_lm, init_caches, init_lm
 from repro.serving.engine import (Engine, Request, RequestQueue,
                                   SamplingParams, make_policy,
                                   synthetic_requests)
 from repro.serving.serve_step import greedy_generate
-
-KEY = jax.random.PRNGKey(7)
 
 
 @pytest.fixture(scope="module")
@@ -23,35 +22,39 @@ def smoke_lm():
     return cfg, init_lm(jax.random.PRNGKey(0), cfg)
 
 
-class TestPagedDecodeKernel:
-    """Pallas paged decode vs the jnp oracle: slot gather, per-slot lengths,
-    dead slots, block sizes that do and don't divide the pool depth."""
+class TestOnePool:
+    """The engine serves from one block-table pool through one paged
+    decode kernel: prefix_cache=False and use_paged_kernel=False are
+    refused, and a per-row cache write needs block tables."""
 
-    @pytest.mark.parametrize("block_kv", [32, 64, 128, 200])
-    def test_vs_ref(self, block_kv):
-        slots, s_max, nkv, d, g, b = 8, 128, 2, 32, 3, 5
-        q = jax.random.normal(KEY, (b, nkv * g, d)) * 0.5
-        kp = jax.random.normal(jax.random.fold_in(KEY, 1),
-                               (slots, s_max, nkv, d)) * 0.5
-        vp = jax.random.normal(jax.random.fold_in(KEY, 2),
-                               (slots, s_max, nkv, d)) * 0.5
-        slot_idx = jnp.asarray([3, 0, 7, 5, 1], jnp.int32)  # permuted gather
-        lengths = jnp.asarray([17, 0, 128, 1, 64], jnp.int32)  # 0 = dead
-        got = paged_decode(q, kp, vp, slot_idx, lengths,
-                           block_kv=block_kv, interpret=True)
-        want = paged_decode_ref(q, kp, vp, slot_idx, lengths)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=3e-5, rtol=3e-5)
+    @pytest.mark.parametrize("flag", ["prefix_cache", "use_paged_kernel"])
+    def test_engine_refuses_removed_paths(self, smoke_lm, flag):
+        cfg, params = smoke_lm
+        with pytest.raises(ValueError, match="one block-table KV pool"):
+            Engine(params, cfg, max_batch=2, max_prompt=16, max_new=4,
+                   **{flag: False})
+        eng = Engine(params, cfg, max_batch=2, max_prompt=16, max_new=4,
+                     **{flag: True})
+        assert eng.cfg.attn_impl == "paged"
 
-    def test_dead_slot_is_zero(self):
-        slots, s_max, nkv, d = 4, 64, 1, 16
-        q = jax.random.normal(KEY, (2, 2, d))
-        kp = jax.random.normal(KEY, (slots, s_max, nkv, d))
-        vp = jax.random.normal(KEY, (slots, s_max, nkv, d))
-        out = paged_decode(q, kp, vp, jnp.asarray([0, 1], jnp.int32),
-                           jnp.asarray([0, 8], jnp.int32), interpret=True)
-        assert np.all(np.asarray(out)[0] == 0.0)
-        assert np.any(np.asarray(out)[1] != 0.0)
+    def test_vector_cache_index_needs_block_tables(self):
+        """A (b,) cache_index over a contiguous cache: GQA and MLA both
+        refuse it without block tables, and keep the scalar write."""
+        for arch in ("internlm2-1.8b", "deepseek-v3-671b"):
+            cfg = get_smoke_config(arch)
+            if cfg.num_experts:
+                cfg = dataclasses.replace(cfg, moe_dispatch="dropless")
+            params = init_lm(jax.random.PRNGKey(0), cfg)
+            caches = init_caches(cfg, 2, 16, jnp.float32)
+            tok = jnp.ones((2, 1), jnp.int32)
+            with pytest.raises(AssertionError, match="needs block_tables"):
+                apply_lm(params, tok, cfg, caches=caches,
+                         cache_index=jnp.asarray([3, 5], jnp.int32),
+                         decode=True)
+            # the scalar write (prefill, greedy_generate) still serves
+            logits, _, _ = apply_lm(params, tok, cfg, caches=caches,
+                                    cache_index=3, decode=True)
+            assert np.isfinite(np.asarray(logits)).all()
 
 
 class TestBucketPolicy:

@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-from repro.kernels.flash_attention.ops import (flash_attention, paged_decode,
+from repro.kernels.flash_attention.ops import (flash_attention,
                                                paged_decode_blocktable,
                                                paged_mla_decode)
 from repro.kernels.grouped_matmul.ops import grouped_matmul
@@ -72,23 +72,14 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
-def test_paged_decode(shape, kv_dtype):
-    rows, slots, s_max = 8, 8, 2048
-    pool = shape((slots, s_max, KV_HEADS, HEAD_DIM), jnp.dtype(kv_dtype))
-    args = [shape((rows, HEADS, HEAD_DIM)), pool, pool,
-            shape((rows,), jnp.int32), shape((rows,), jnp.int32)]
-    if kv_dtype == "int8":
-        args += [shape((slots, s_max, KV_HEADS), jnp.float32)] * 2
-
-    def fn(q, k, v, slot, lens, ks=None, vs=None):
-        return paged_decode(q, k, v, slot, lens, k_scale=ks, v_scale=vs,
-                            block_kv=128, interpret=False)
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
-
-
-@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
-def test_paged_decode_blocktable(shape, kv_dtype):
+# block_kv None is the default chunk of 256 tokens (16 pages); 64 is a
+# chunk of 4 pages
+@pytest.mark.parametrize("kv_dtype,block_kv", [
+    pytest.param("bfloat16", None, id="bfloat16"),
+    pytest.param("int8", None, id="int8"),
+    pytest.param("bfloat16", 64, id="bfloat16-chunk64"),
+    pytest.param("int8", 64, id="int8-chunk64")])
+def test_paged_decode_blocktable(shape, kv_dtype, block_kv):
     # the serving engine's bf16 block size for a 2048-deep pool is 16
     rows, block_size, max_blocks = 8, 16, 128
     blocks = shape((rows * max_blocks + 1, block_size, KV_HEADS, HEAD_DIM),
@@ -100,7 +91,8 @@ def test_paged_decode_blocktable(shape, kv_dtype):
 
     def fn(q, k, v, tables, lens, ks=None, vs=None):
         return paged_decode_blocktable(q, k, v, tables, lens, k_scale=ks,
-                                       v_scale=vs, interpret=False)
+                                       v_scale=vs, block_kv=block_kv,
+                                       interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 

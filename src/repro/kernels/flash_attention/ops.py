@@ -188,12 +188,24 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 
 # --- paged decode (serving engine) ---------------------------------------------------
 
+def _stack_of(x):
+    """(stack, layer) of a `models.layers.LayerOf`, or of a plain pool as a
+    stack of one layer."""
+    from ...models.layers import LayerOf
+    return (x.stack, x.index) if isinstance(x, LayerOf) else (x[None], 0)
+
+
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret",
                                              "use_pallas"))
-def _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths, k_scale,
-                  v_scale, *, block_kv: Optional[int], interpret: bool,
-                  use_pallas: bool):
+def _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths, layer,
+                  k_scale, v_scale, *, block_kv: Optional[int],
+                  interpret: bool, use_pallas: bool):
+    if k_scale is not None:
+        # the scales' device layout keeps the block axis minor-most, so a
+        # layer's scale pages are relaid out as rows for the kernel anyway
+        k_scale, v_scale = k_scale[layer], v_scale[layer]
     if not use_pallas:
+        k_blocks, v_blocks = k_blocks[layer], v_blocks[layer]
         if k_scale is not None:
             k_blocks = dequantize_kv(k_blocks, k_scale, q.dtype)
             v_blocks = dequantize_kv(v_blocks, v_scale, q.dtype)
@@ -201,7 +213,7 @@ def _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths, k_scale,
                                            v_blocks.astype(q.dtype),
                                            block_tables, lengths)
     return paged_decode_blocktable_pallas(q, k_blocks, v_blocks,
-                                          block_tables, lengths,
+                                          block_tables, lengths, layer,
                                           k_scale=k_scale, v_scale=v_scale,
                                           block_kv=block_kv,
                                           interpret=interpret)
@@ -217,13 +229,15 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
     """Block-table decode attention over a physical KV block pool.
 
     q: (b, a, d) — one query token per active request row; k_blocks,
-    v_blocks: (num_blocks, block_size, nkv, d); block_tables: (b,
-    max_blocks) row -> physical block ids; lengths: (b,) live kv entries
-    (0 = dead row -> zero output).  Returns (b, a, d).
+    v_blocks: (num_blocks, block_size, nkv, d), or `models.layers.LayerOf`
+    a stack of every layer's pool (read in place, no layer copied out);
+    block_tables: (b, max_blocks) row -> physical block ids; lengths: (b,)
+    live kv entries (0 = dead row -> zero output).  Returns (b, a, d).
 
     k_scale/v_scale: (num_blocks, block_size, nkv) f32 per-(token, kv_head)
-    scales for an int8 block pool; dequantized per chunk in-kernel on the
-    Pallas path, up front on the jnp path.
+    scales for an int8 block pool, `LayerOf` their stacks beside a stacked
+    pool; dequantized per chunk in-kernel on the Pallas path, up front on
+    the jnp path.
 
     block_kv is the Pallas kernel's chunk length in tokens, rounded up to
     whole blocks (None: `paged.CHUNK_TOKENS`); each row reads only its live
@@ -236,8 +250,11 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
     under op "paged_decode_blocktable_pool" for the engine to consult.
     Quantized pools key the lookup by the mixed dtype pair.
     """
+    (k_blocks, layer), (v_blocks, _) = _stack_of(k_blocks), _stack_of(v_blocks)
+    if k_scale is not None:
+        k_scale, v_scale = _stack_of(k_scale)[0], _stack_of(v_scale)[0]
     b, a, d = q.shape
-    nb, block_size, nkv, _ = k_blocks.shape
+    _, nb, block_size, nkv, _ = k_blocks.shape
     tuned_hit = None
     dtype = jnp.dtype(q.dtype).name
     if k_scale is not None:
@@ -257,7 +274,8 @@ def paged_decode_blocktable(q, k_blocks, v_blocks, block_tables, lengths, *,
                     "block_size": block_size} if use_pallas else None,
             tuned_hit=tuned_hit)
     return _paged_bt_jit(q, k_blocks, v_blocks, block_tables, lengths,
-                         k_scale, v_scale, block_kv=block_kv,
+                         jnp.asarray(layer, jnp.int32), k_scale, v_scale,
+                         block_kv=block_kv,
                          interpret=interpret_mode(interpret),
                          use_pallas=use_pallas)
 
@@ -292,10 +310,7 @@ def paged_mla_decode(q, latent_blocks, block_tables, lengths, *, v_dim: int,
     reads each row's live pages only; block_kv is its chunk in tokens,
     rounded up to whole pages.
     """
-    from ...models.layers import LayerOf
-    stack, layer = ((latent_blocks.stack, latent_blocks.index)
-                    if isinstance(latent_blocks, LayerOf)
-                    else (latent_blocks[None], 0))
+    stack, layer = _stack_of(latent_blocks)
     if obs.enabled():
         obs.record_dispatch(
             "paged_mla_decode", impl="pallas" if use_pallas else "jnp",

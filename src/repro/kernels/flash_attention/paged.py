@@ -6,11 +6,13 @@ size: K/V live in a pool of physical blocks (pages) of `block_size` tokens
 (`serving.engine.kv_pool.PagedPool`) and row b's logical page j is physical
 page `block_table[b, j]`.  One query token per row.  The grid is (b,), one
 step per row, so the work follows each row's live pages and not the
-table's width.  The table and lengths sit in SMEM (scalar prefetch); the
-pools stay in HBM (`memory_space=pl.ANY`).  A row loops over chunks of P
-pages (`blocktable_chunk_pages`: 256 tokens by default, `block_kv` tokens
-rounded up to whole pages when given) for ceil(lengths[b] / chunk)
-iterations -- none for a dead row, which writes zeros.  Each chunk's live
+table's width.  The table, lengths and layer index sit in SMEM (scalar
+prefetch); the pools stay in HBM (`memory_space=pl.ANY`), K and V as the
+stack of every layer's pool, read at the layer in place.  A row loops over
+chunks of P pages (`blocktable_chunk_pages`: 256 tokens by default,
+`block_kv` tokens rounded up to whole pages when given) for
+ceil(lengths[b] / chunk) iterations -- none for a dead row, which writes
+zeros.  Each chunk's live
 pages are gathered by one async copy per page and pool into a
 double-buffered VMEM scratch, the next chunk's copies started before this
 chunk is computed; pages past the live prefix (a released row's stale
@@ -142,12 +144,14 @@ def _finish(o_ref, carry):
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
-                     pages: int, block_size: int, quant: bool, scale: float):
-    # refs: the HBM pools (k, v[, k_scale, v_scale]), the output, the VMEM
-    # chunk buffers (one per pool) and the DMA semaphores
+def _paged_bt_kernel(table_ref, len_ref, layer_ref, q_ref, *refs,
+                     num_kv_heads: int, pages: int, block_size: int,
+                     quant: bool, scale: float):
+    # refs: the HBM pools (the k, v stacks[, k_scale, v_scale of the
+    # layer]), the output, the VMEM chunk buffers (one per pool) and the DMA
+    # semaphores; k and v are read at layer_ref[0] in place
     n_pools = 4 if quant else 2
-    pools_hbm = refs[:n_pools]
+    pools_hbm = [p.at[layer_ref[0]] for p in refs[:2]] + list(refs[2:n_pools])
     o_ref = refs[n_pools]
     bufs = refs[n_pools + 1:2 * n_pools + 1]
     sem = refs[2 * n_pools + 1]
@@ -198,20 +202,24 @@ def _paged_bt_kernel(table_ref, len_ref, q_ref, *refs, num_kv_heads: int,
 def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
                                    v_blocks: jax.Array,
                                    block_tables: jax.Array,
-                                   lengths: jax.Array, *,
+                                   lengths: jax.Array,
+                                   layer: jax.Array | int = 0, *,
                                    k_scale: jax.Array | None = None,
                                    v_scale: jax.Array | None = None,
                                    block_kv: int | None = None,
                                    scale: float | None = None,
                                    interpret: bool = False) -> jax.Array:
     """q: (b, a, d) one token per row; k_blocks, v_blocks: (num_blocks,
-    block_size, nkv, d) physical KV block pool; block_tables: (b,
-    max_blocks) int32 — row b's logical kv block j lives in physical block
-    `block_tables[b, j]`; lengths: (b,) live kv per row.
+    block_size, nkv, d) physical KV block pool, or (layers, num_blocks,
+    block_size, nkv, d) with `layer` naming the one to read (the pool is
+    read in place); block_tables: (b, max_blocks) int32 — row b's logical
+    kv block j lives in physical block `block_tables[b, j]`; lengths: (b,)
+    live kv per row.
 
     k_scale/v_scale: (num_blocks, block_size, nkv) f32 per-(token, kv_head)
-    dequant scales for an int8 block pool (both or neither); their pages
-    are copied beside the K/V pages and applied in VMEM.
+    dequant scales of that one layer, for an int8 block pool (both or
+    neither); their pages are copied beside the K/V pages and applied in
+    VMEM.
 
     block_kv is the chunk length in tokens (default CHUNK_TOKENS), rounded
     up to whole pages (`blocktable_chunk_pages`).  Only the first
@@ -221,30 +229,34 @@ def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
     """
     from jax.experimental.pallas import tpu as pltpu
     b, a, d = q.shape
-    nb, block_size, nkv, dk = k_blocks.shape
+    if k_blocks.ndim == 4:
+        k_blocks, v_blocks = k_blocks[None], v_blocks[None]
+    n_layers, nb, block_size, nkv, dk = k_blocks.shape
     bt_rows, max_blocks = block_tables.shape
     assert d == dk and a % nkv == 0 and bt_rows == b
     assert (k_scale is None) == (v_scale is None)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     # a page as (block_size * nkv, d) rows: a bitcast of the pool wherever
     # nkv fills the sublane tile, as it does at the engine's widths
-    pools = [k_blocks.reshape(nb, block_size * nkv, d),
-             v_blocks.reshape(nb, block_size * nkv, d)]
+    pools = [k_blocks.reshape(n_layers, nb, block_size * nkv, d),
+             v_blocks.reshape(n_layers, nb, block_size * nkv, d)]
     if k_scale is not None:
         assert k_scale.shape == (nb, block_size, nkv), k_scale.shape
         pools += [x.astype(jnp.float32).reshape(nb, block_size * nkv)
                   for x in (k_scale, v_scale)]
-    page_bytes = sum(x[0].size * x.dtype.itemsize for x in pools)
+    page_bytes = block_size * nkv * sum(
+        (d if x.ndim == 4 else 1) * x.dtype.itemsize for x in pools)
     pages = blocktable_chunk_pages(block_size, max_blocks, page_bytes,
                                    block_kv)
     rows = pages * block_size * nkv
-    row_spec = pl.BlockSpec((1, a, d), lambda bi, table, lens: (bi, 0, 0))
+    row_spec = pl.BlockSpec((1, a, d),
+                            lambda bi, table, lens, layer: (bi, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     bufs = [pltpu.VMEM((2, rows, d), x.dtype) for x in pools[:2]]
     bufs += [pltpu.VMEM((2, pages, block_size * nkv), jnp.float32)
              for _ in pools[2:]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[row_spec] + [hbm] * len(pools),
         out_specs=row_spec,
@@ -257,7 +269,8 @@ def paged_decode_blocktable_pallas(q: jax.Array, k_blocks: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, a, d), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *pools)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, *pools)
 
 
 def _paged_mla_kernel(table_ref, len_ref, layer_ref, q_ref, pool_hbm, o_ref,

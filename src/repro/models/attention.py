@@ -88,6 +88,35 @@ def _sdpa(q, k, v, causal: bool, q_pos=None, kv_len=None,
     return out.reshape(b, sq, a, v.shape[-1])  # v head dim may differ (MLA)
 
 
+def pool_write(cache, new, block_tables, ci):
+    """Write one token a row into the leaves of a block pool, in place.
+
+    cache: dict of leaves (num_blocks, block_size, ...), or of `LayerOf` the
+    stacks of every layer's pool (layers, num_blocks, block_size, ...);
+    new: the same keys, (b, 1, ...) each row's token; ci: (b,) each row's
+    write position, which lands at (block_tables[b, ci // bs], ci % bs) of
+    the layer.  Each live row's tail block is private by the pool's
+    copy-on-write discipline, so rows never collide (dead rows all write
+    the pool's garbage block, which is never read).  Returns the leaves as
+    given, `LayerOf` or plain."""
+    out = {}
+    for n, leaf in cache.items():
+        stack, layer = (leaf if isinstance(leaf, LayerOf)
+                        else LayerOf(leaf[None], 0))
+        blk = stack.shape[2]
+        phys = block_tables[jnp.arange(ci.shape[0]), ci // blk]
+        stack = stack.at[layer, phys, ci % blk].set(
+            new[n][:, 0].astype(stack.dtype))
+        out[n] = LayerOf(stack, layer) if isinstance(leaf, LayerOf) \
+            else stack[0]
+    return out
+
+
+def _layer(leaf):
+    """The one layer's pool a `pool_write` leaf holds."""
+    return leaf.stack[leaf.index] if isinstance(leaf, LayerOf) else leaf
+
+
 def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
               cache=None, cache_index=None, kv_input=None,
               block_tables=None):
@@ -101,14 +130,12 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
     kv_input: if set, keys/values come from this tensor (cross-attention).
     block_tables: (b, max_blocks) int32 — switches the cache to the
     serving engine's *block-pool* layout (k/v: (num_blocks, block_size, kv,
-    hd)): row b's logical kv block j lives in physical block
+    hd), or `LayerOf` the stack of every layer's pool, read and written in
+    place): row b's logical kv block j lives in physical block
     `block_tables[b, j]`.  Requires single-token decode with a (b,) vector
-    cache_index; the new token is scattered into (table[b, ci//bs], ci % bs)
-    — each live row's tail block is private by the pool's copy-on-write
-    discipline, so rows never collide (dead rows all write the pool's
-    garbage block, which is never read).  Attention then runs the Pallas
-    paged kernel when cfg.attn_impl == "paged", else the jnp reference over
-    each row's gathered blocks.
+    cache_index; the new token is written by `pool_write`.  Attention then
+    runs the Pallas paged kernel when cfg.attn_impl == "paged", else the
+    jnp reference over each row's gathered blocks.
     """
     b, s, h = x.shape
     a, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -138,41 +165,28 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, causal=True,
         ci = jnp.asarray(cache_index)
         assert s == 1 and ci.ndim == 1, \
             "block_tables requires single-token decode with vector cache_index"
-        blk = cache["k"].shape[1]  # physical block size (tokens)
-        rows = jnp.arange(b)
-        phys = block_tables[rows, ci // blk]
-        off = ci % blk
+        new = {"k": k, "v": v}
         if quant:
             from ..quant import dequantize_kv, quantize_kv
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            kc = cache["k"].at[phys, off].set(kq[:, 0])
-            vc = cache["v"].at[phys, off].set(vq[:, 0])
-            k_scale = cache["k_scale"].at[phys, off].set(ks[:, 0])
-            v_scale = cache["v_scale"].at[phys, off].set(vs[:, 0])
-            new_cache = {"k": kc, "v": vc,
-                         "k_scale": k_scale, "v_scale": v_scale}
-        else:
-            kc = cache["k"].at[phys, off].set(k[:, 0].astype(cache["k"].dtype))
-            vc = cache["v"].at[phys, off].set(v[:, 0].astype(cache["v"].dtype))
-            new_cache = {"k": kc, "v": vc}
+            (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+            new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        new_cache = pool_write(cache, new, block_tables, ci)
         lengths = (ci + 1).astype(jnp.int32)
         if cfg.attn_impl == "paged":
             from ..kernels.flash_attention.ops import paged_decode_blocktable
             out = paged_decode_blocktable(
-                q[:, 0], kc if quant else kc.astype(q.dtype),
-                vc if quant else vc.astype(q.dtype),
-                block_tables, lengths, k_scale=k_scale, v_scale=v_scale,
-                tuned=True)[:, None]
+                q[:, 0], new_cache["k"], new_cache["v"], block_tables,
+                lengths, k_scale=new_cache.get("k_scale"),
+                v_scale=new_cache.get("v_scale"), tuned=True)[:, None]
         else:
             from ..kernels.flash_attention.ref import gather_block_kv
-            kg = gather_block_kv(kc, block_tables)
-            vg = gather_block_kv(vc, block_tables)
+            kg = gather_block_kv(_layer(new_cache["k"]), block_tables)
+            vg = gather_block_kv(_layer(new_cache["v"]), block_tables)
             if quant:
-                kg = dequantize_kv(kg, gather_block_kv(k_scale, block_tables),
-                                   q.dtype)
-                vg = dequantize_kv(vg, gather_block_kv(v_scale, block_tables),
-                                   q.dtype)
+                kg = dequantize_kv(kg, gather_block_kv(
+                    _layer(new_cache["k_scale"]), block_tables), q.dtype)
+                vg = dequantize_kv(vg, gather_block_kv(
+                    _layer(new_cache["v_scale"]), block_tables), q.dtype)
             out = _sdpa(q, kg.astype(q.dtype), vg.astype(q.dtype),
                         causal=causal, q_pos=positions, kv_len=lengths)
         out = linear(out.reshape(b, s, a * hd), p["wo"], impl=impl)
@@ -369,7 +383,8 @@ def apply_mla(p, x, cfg: ModelConfig, *, positions, cache=None,
 
     cache: dict(latent=(b, s_max, width)) with a scalar cache_index (a
     write at [ci, ci+s)); with block_tables the cache is a latent block
-    pool (num_blocks, block_size, width), row b's logical block j is
+    pool (num_blocks, block_size, width) or `LayerOf` the stack of every
+    layer's pool (written by `pool_write`), row b's logical block j is
     physical block block_tables[b, j], and a (b,) cache_index gives each
     row's write position for one-token decode.  One-token decode runs the
     absorbed form (`_mla_decode`); prefill and training the decompressed
@@ -386,20 +401,11 @@ def apply_mla(p, x, cfg: ModelConfig, *, positions, cache=None,
     if block_tables is not None:
         assert s == 1 and ci.ndim == 1, \
             "block_tables requires single-token decode with vector cache_index"
-        # the pool may be `LayerOf` the stack of every layer's pool: the
-        # token is written in place and the kernel reads the layer in place
-        stack, layer = (pool if isinstance(pool, LayerOf)
-                        else LayerOf(pool[None], 0))
-        blk = stack.shape[2]
-        phys = block_tables[jnp.arange(b), ci // blk]
-        stack = stack.at[layer, phys, ci % blk].set(
-            lat[:, 0].astype(stack.dtype))
-        pool = LayerOf(stack, layer) if isinstance(pool, LayerOf) \
-            else stack[0]
-        out = _mla_decode(p, q_nope, q_rope, cfg, pool=pool,
+        new_cache = pool_write(cache, {"latent": lat}, block_tables, ci)
+        out = _mla_decode(p, q_nope, q_rope, cfg, pool=new_cache["latent"],
                           block_tables=block_tables,
                           lengths=(ci + 1).astype(jnp.int32))
-        return out, {"latent": pool}
+        return out, new_cache
     assert ci.ndim == 0, \
         "a per-row (vector) cache_index needs block_tables (block pool)"
     pool = jax.lax.dynamic_update_slice_in_dim(

@@ -275,6 +275,7 @@ def apply_stack(segments_params, cfg: ModelConfig, x, *, positions,
     caches: list aligned with segments (or None).
     block_tables: (b, max_blocks) block-pool indirection, shared by every
     layer (scan-closure captured — all layers' kv leaves use one table).
+    With it every cache leaf is a block pool stacked with layers first.
     token_mask: (b, s) rows holding a token (drop-free MoE).
     Returns (x, new_caches, total_aux), and the layers' merged MoE stats
     last with return_stats.
@@ -282,11 +283,13 @@ def apply_stack(segments_params, cfg: ModelConfig, x, *, positions,
     total_aux = jnp.zeros((), jnp.float32)
     stats = no_moe_stats()
     new_caches = [] if caches is not None else None
-    # the MLA latent block pool rides whole in the scan's carry, and the
-    # drop-free MoE layers' expert weights stay whole outside it: their
-    # kernels read layer i in place (`LayerOf`), where the scan's slices
-    # would copy each layer's pool and weights out and the pool back in
-    carry_pool = block_tables is not None and cfg.attn_type == "mla"
+    # a block pool (GQA k/v and int8 scales, or MLA's latent) rides whole in
+    # the scan's carry, and the drop-free MoE layers' expert weights stay
+    # whole outside it: layer i's token is written into the stack in place
+    # and the kernels read layer i in place (`LayerOf`), where the scan's
+    # slices would copy each layer's pool and weights out, the pool back
+    # into a fresh stack, and that stack once more onto the donated pool
+    carry_pool = block_tables is not None
 
     for si, (kind, sp) in enumerate(segments_params):
         seg_cache = None if caches is None else caches[si]
@@ -303,13 +306,15 @@ def apply_stack(segments_params, cfg: ModelConfig, x, *, positions,
                 p_l = {**p_l, "moe": {**p_l["moe"], **{
                     k: LayerOf(v, i) for k, v in _whole.items()}}}
             if pool is not None:
-                c_l = {"latent": LayerOf(pool, i)}
+                c_l = jax.tree.map(lambda t: LayerOf(t, i), pool)
             h, nc, a, s_l = _apply_core(
                 p_l, h, cfg, _kind, positions=positions, cache=c_l,
                 cache_index=cache_index, shared=shared, decode=decode,
                 block_tables=block_tables, token_mask=token_mask)
             if pool is not None:
-                pool, nc = nc["latent"].stack, None
+                pool, nc = jax.tree.map(
+                    lambda t: t.stack, nc,
+                    is_leaf=lambda t: isinstance(t, LayerOf)), None
             return (h, aux + a, merge_moe_stats(st, s_l), pool), nc
 
         if remat == "full":
@@ -320,13 +325,12 @@ def apply_stack(segments_params, cfg: ModelConfig, x, *, positions,
                 policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
         n = jax.tree.leaves(sp)[0].shape[0]
-        pool = seg_cache["latent"] if carry_pool else None
+        pool = seg_cache if carry_pool else None
         xs = (sp, None if carry_pool else seg_cache, jnp.arange(n))
         (x, total_aux, stats, pool), seg_new_cache = jax.lax.scan(
             body, (x, total_aux, stats, pool), xs)
         if new_caches is not None:
-            new_caches.append({"latent": pool} if carry_pool
-                              else seg_new_cache)
+            new_caches.append(pool if carry_pool else seg_new_cache)
     if return_stats:
         return x, new_caches, total_aux, stats
     return x, new_caches, total_aux

@@ -25,7 +25,8 @@ class LayerOf(NamedTuple):
     """Layer `index` of an array stacked with layers first, handed over
     whole: a Pallas kernel then reads the layer in place, where a slice of
     the stack taken inside the layer scan would be copied out first (the
-    latent pool and the held experts' weights, every layer of every step)."""
+    GQA k/v block pool, the MLA latent pool and the held experts' weights,
+    every layer of every step)."""
     stack: jax.Array
     index: jax.Array
 

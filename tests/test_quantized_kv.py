@@ -159,6 +159,61 @@ class TestQuantizedPagedDecode:
         assert rel < 0.05
 
 
+def _layer_pools(kv_dtype, layers, nb, bs, nkv, d):
+    """(k, v, k_scale, v_scale) of each layer, a distinct pool a layer:
+    int8 with its scales, or bf16 with none."""
+    out = []
+    for i in range(layers):
+        kv = jax.random.normal(jax.random.fold_in(KEY, 10 + i),
+                               (2, nb, bs, nkv, d)) * 0.5
+        if kv_dtype == "int8":
+            (kq, ks), (vq, vs) = quantize_kv(kv[0]), quantize_kv(kv[1])
+            out.append((kq, vq, ks, vs))
+        else:
+            kv = kv.astype(jnp.bfloat16)
+            out.append((kv[0], kv[1], None, None))
+    return out
+
+
+class TestLayerStackPagedDecode:
+    @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+    @pytest.mark.parametrize("use_pallas", [True, False])
+    def test_layer_of_stack_vs_layer_and_ref(self, kv_dtype, use_pallas):
+        """The kernel on `LayerOf(stack, i)` reads layer i of a 3-layer
+        stack in place: the same output as on the sliced stack[i] and
+        within the reference's tolerance of it, a dead row zero."""
+        from repro.models.layers import LayerOf
+        layers, nb, bs, nkv, d, b = 3, 12, 32, 2, 32, 4
+        per_layer = _layer_pools(kv_dtype, layers, nb, bs, nkv, d)
+        stacks = [None if per_layer[0][n] is None
+                  else jnp.stack([p[n] for p in per_layer])
+                  for n in range(4)]
+        q = jax.random.normal(jax.random.fold_in(KEY, 5), (b, nkv * 2, d))
+        tables = jnp.asarray([[9, 2, 5, 11], [0, 0, 0, 0], [6, 10, 1, 3],
+                              [2, 8, 7, 4]], jnp.int32)
+        lengths = jnp.asarray([97, 0, 128, 33], jnp.int32)   # 0 = dead row
+        for i in range(layers):
+            kb, vb, ks, vs = per_layer[i]
+            at = [None if x is None else LayerOf(x, jnp.int32(i))
+                  for x in stacks]
+            got = np.asarray(paged_decode_blocktable(
+                q, at[0], at[1], tables, lengths, k_scale=at[2],
+                v_scale=at[3], interpret=True, use_pallas=use_pallas))
+            one = np.asarray(paged_decode_blocktable(
+                q, kb, vb, tables, lengths, k_scale=ks, v_scale=vs,
+                interpret=True, use_pallas=use_pallas))
+            if ks is not None:
+                kb = dequantize_kv(kb, ks, jnp.float32)
+                vb = dequantize_kv(vb, vs, jnp.float32)
+            want = paged_decode_blocktable_ref(
+                q, kb.astype(jnp.float32), vb.astype(jnp.float32), tables,
+                lengths)
+            np.testing.assert_array_equal(got, one)
+            np.testing.assert_allclose(got, np.asarray(want), atol=3e-5,
+                                       rtol=3e-5)
+            assert np.all(got[1] == 0.0)        # dead row stays zero
+
+
 # -- model-level greedy parity -----------------------------------------------
 
 def _greedy(params, cfg, toks, n_new):

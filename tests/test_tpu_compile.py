@@ -3,7 +3,9 @@ internlm2-1.8b widths (16 query heads, 8 KV heads, head_dim 128, d_model
 2048, d_ff 8192, bf16), and the deepseek-v3-671b.ep32 serving cell's two
 kernels at its shapes (the paged MLA decode kernel over a 7-layer latent
 pool of 96 rows x 4096 tokens; the grouped expert product of 8 held
-experts, 7168 x 2048, at decode and prefill rows).
+experts, 7168 x 2048, at decode and prefill rows), and the serving engine's
+decode program at internlm2-1.8b widths, which must read its k/v block pool
+in place.
 
 No chip is attached: the TPU compiler runs against a `v5e:2x2` topology
 description and raises whatever the chip's compiler would raise (illegal
@@ -16,7 +18,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +98,55 @@ def test_paged_decode_blocktable(shape, kv_dtype, block_kv):
                                        v_scale=vs, block_kv=block_kv,
                                        interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
+def _pool_instructions(text: str, dims) -> list:
+    """(name, opcode) of each compiled instruction whose result is a block
+    pool of `dims` (num_blocks, block_size, nkv, hd): a stack of them, one
+    layer's, or either with a page as (block_size * nkv, hd) rows."""
+    nb, bs, nkv, hd = dims
+    tails = (f"{nb},{bs},{nkv},{hd}]", f"{nb},{bs * nkv},{hd}]")
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                         r"([\w-]+)\(", text, flags=re.M):
+        if (m.group(2) + "]").endswith(tails):
+            found.append((m.group(1), m.group(3)))
+    return found
+
+
+def test_engine_decode_reads_pool_in_place(shape, monkeypatch):
+    """The engine's decode program at internlm2-1.8b widths (2 of 24
+    layers; 32 rows, 1,664 + 1 blocks of 16 as the chat cell runs) carries
+    the k/v block pool whole through the layer scan: no copy or slice of
+    the pool or of a layer of it, temporaries below one layer's K pool, and
+    the block-table kernel reads each layer in place."""
+    from repro.kernels.flash_attention import ops as fops
+    from repro.configs.registry import get_config
+    from repro.models import init_caches, init_lm
+    from repro.models.linear import cast_linear_params
+    from repro.serving.engine.engine import MOE_COUNTERS, _make_decode_bt
+    monkeypatch.setattr(fops, "interpret_mode", lambda interpret=None: False)
+    rows, blocks, block_size, layers = 32, 1664 + 1, 16, 2
+    cfg = dataclasses.replace(get_config("internlm2-1.8b"), num_layers=layers,
+                              dtype="bfloat16", attn_impl="paged")
+    params = jax.eval_shape(lambda: cast_linear_params(
+        init_lm(jax.random.PRNGKey(0), cfg), BF16, tied=cfg.tie_embeddings))
+    caches = jax.eval_shape(lambda: init_caches(cfg, blocks, block_size, BF16))
+    as_chip = lambda x: shape(x.shape, x.dtype)
+    compiled = _make_decode_bt(cfg).lower(
+        jax.tree.map(as_chip, params), shape((rows, 1), jnp.int32),
+        jax.tree.map(as_chip, caches), shape((rows,), jnp.int32),
+        shape((rows, 4608 // block_size), jnp.int32),
+        shape((len(MOE_COUNTERS),), jnp.int32)).compile()
+    text = compiled.as_text()
+    moved = [(name, op) for name, op in _pool_instructions(
+        text, (blocks, block_size, KV_HEADS, HEAD_DIM))
+        if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+        or re.search(r"copy|dynamic-slice|dynamic-update-slice", name)]
+    assert not moved, moved
+    layer_k_bytes = blocks * block_size * KV_HEADS * HEAD_DIM * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
+    assert "tpu_custom_call" in text and "_paged_bt_jit" in text
 
 
 def test_flash_forward_with_residuals(shape):
